@@ -412,7 +412,9 @@ def main():
         "a cell broke the one-decode-dispatch-per-group bound"
 
     # ---- sharded fleet parity (child process: 4 virtual devices) ------
-    env = dict(os.environ, SMOKE_SHARD_CHILD="1",
+    # a CPU parity check: the child is held to the CPU, since on a chip
+    # host this (JAX-holding) parent already owns the accelerator
+    env = dict(os.environ, SMOKE_SHARD_CHILD="1", JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     child = subprocess.run([sys.executable, os.path.abspath(__file__)],
                            capture_output=True, text=True, env=env,

@@ -3,7 +3,8 @@ from repro.configs.base import (  # noqa: F401
     REGISTRY, SHAPES, ArchConfig, ShapeConfig, applicable_shapes, get_config,
 )
 
-# Assigned architectures (10) — importing registers each into REGISTRY.
+# Assigned architectures (10) — importing registers each into REGISTRY
+# (and any chip share cut from one into SHARES).
 from repro.configs import mistral_nemo_12b    # noqa: F401
 from repro.configs import qwen2_5_14b         # noqa: F401
 from repro.configs import command_r_35b       # noqa: F401

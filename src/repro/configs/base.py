@@ -35,6 +35,7 @@ class ArchConfig:
     norm_eps: float = 1e-5
     rope_theta: float = 1_000_000.0
     max_seq_len: int = 131_072
+    dtype: str = "bfloat16"          # weights and KV cache when served
     # --- MoE ---
     num_experts: int = 0
     num_experts_per_tok: int = 0
@@ -159,6 +160,7 @@ class ArchConfig:
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",
+            dtype="float32",
             num_layers=min(self.num_layers, 4 if self.family in ("ssm", "hybrid") else 2),
             d_model=128,
             num_heads=4 if self.num_heads else 0,
@@ -205,8 +207,11 @@ def applicable_shapes(cfg: ArchConfig) -> list:
     return out
 
 
-# Populated by repro.configs.__init__
+# Populated by repro.configs.__init__: the published architectures, and
+# the chip shares cut from them (kept apart so the published list stays the
+# published list)
 REGISTRY: dict = {}
+SHARES: dict = {}
 
 
 def register(cfg: ArchConfig) -> ArchConfig:
@@ -214,9 +219,21 @@ def register(cfg: ArchConfig) -> ArchConfig:
     return cfg
 
 
+def register_share(cfg: ArchConfig) -> ArchConfig:
+    SHARES[cfg.name] = cfg
+    return cfg
+
+
 def get_config(name: str) -> ArchConfig:
-    if name not in REGISTRY:
-        import repro.configs  # noqa: F401  (trigger registration)
-    if name not in REGISTRY:
-        raise KeyError(f"unknown arch {name!r}; have {sorted(REGISTRY)}")
-    return REGISTRY[name]
+    """A published architecture, a chip share, or ``<either>-reduced`` (the
+    tiny same-family CPU config, ``ArchConfig.reduced``)."""
+    import repro.configs  # noqa: F401  (trigger registration)
+    if name in REGISTRY:
+        return REGISTRY[name]
+    if name in SHARES:
+        return SHARES[name]
+    base = name.removesuffix("-reduced")
+    if base != name:
+        return get_config(base).reduced()
+    raise KeyError(f"unknown arch {name!r}; have "
+                   f"{sorted(REGISTRY) + sorted(SHARES)}")

@@ -28,15 +28,16 @@ def attention_ref(q, k, v, *, causal=True):
 
 
 def decode_attention_ref(q, k_cache, v_cache, pos):
-    """q: (B, Hq, d); caches: (B, Hkv, S, d); pos: scalar int (attend 0..pos)."""
+    """q: (B, Hq, d); caches: (B, Hkv, S, d); pos: scalar int or (B,) per-row
+    ints (row b attends 0..pos[b])."""
     B, Hq, d = q.shape
     Hkv, S = k_cache.shape[1], k_cache.shape[2]
     rep = Hq // Hkv
     k = jnp.repeat(k_cache, rep, axis=1).astype(jnp.float32)
     v = jnp.repeat(v_cache, rep, axis=1).astype(jnp.float32)
     s = jnp.einsum("bhd,bhtd->bht", q.astype(jnp.float32), k) / np.sqrt(d)
-    mask = jnp.arange(S) <= pos
-    s = jnp.where(mask[None, None, :], s, -1e30)
+    mask = jnp.arange(S)[None, :] <= jnp.reshape(jnp.asarray(pos), (-1, 1))
+    s = jnp.where(mask[:, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bht,bhtd->bhd", p, v).astype(q.dtype)
 

@@ -46,17 +46,6 @@ def set_host_device_count(n: int) -> None:
                 f"{_HOST_COUNT_FLAG}={n} in the environment")
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``AxisType`` landed after jax 0.4.37; Auto is that release's implicit
-    behavior, so on older jax we simply omit the kwarg."""
-    import jax
-
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: (data=16, model=16) = 256 chips.
     Multi-pod: (pod=2, data=16, model=16) = 512 chips (DCN over 'pod')."""
@@ -68,9 +57,10 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_mesh(shape, axes):
     """Elastic helper: any (shape, axes) over the available devices."""
     import jax
+    from jax.sharding import AxisType
 
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):

@@ -105,6 +105,11 @@ def _parse_timeout(spec: str):
 
 
 def run_control_loop(args, cfg, model, params, mesh=None):
+    """Drive the elastic frontend with the forecast -> balance -> scale loop
+    for ``args.ticks`` ticks, drain it, print the report and return the
+    frontend (its ``finished`` requests, ledger and kernel counters)."""
+    import jax.numpy as jnp
+
     from repro.configs.paper_cluster import ClusterConfig
     from repro.control import (CellController, CellRouter, ControlPlane,
                                GlobalPlanner, MultiCellBackend,
@@ -139,6 +144,7 @@ def run_control_loop(args, cfg, model, params, mesh=None):
         mb = int(rng.choice([max(2, args.max_batch // 2), args.max_batch]))
         return ReplicaEngine(model, params, max_batch=mb,
                              max_seq=args.max_seq, rid=rid, speed=speed,
+                             cache_dtype=jnp.dtype(cfg.dtype),
                              chunk_len=args.chunk_len, tiers=tiers,
                              attn_backend=args.attn_backend)
 
@@ -357,9 +363,12 @@ def run_control_loop(args, cfg, model, params, mesh=None):
                   f"goodput={row['ok']}/{n_rids} "
                   f"({row['ok'] / n_rids:.0%}) "
                   f"retries={row['retries']} abandoned={row['abandoned']}")
+    return fe
 
 
 def run_drain_mode(args, cfg, model, params):
+    import jax.numpy as jnp
+
     from repro.data.pipeline import prompt_workload
     from repro.serving.engine import (ClusterFrontend, ReplicaEngine,
                                       Request, total_prefill_traces)
@@ -372,6 +381,7 @@ def run_drain_mode(args, cfg, model, params):
 
     replicas = [ReplicaEngine(model, params, max_batch=args.max_batch,
                               max_seq=args.max_seq, rid=i,
+                              cache_dtype=jnp.dtype(cfg.dtype),
                               chunk_len=args.chunk_len,
                               attn_backend=args.attn_backend)
                 for i in range(args.replicas)]
@@ -408,9 +418,13 @@ def run_drain_mode(args, cfg, model, params):
           f"prefill retraces: {traces}")
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="granite-3-8b")
+    ap.add_argument("--arch", default="granite-3-8b-reduced",
+                    help="config to serve: a published architecture, a "
+                         "chip share such as 'granite-3-8b-1chip', or "
+                         "'<either>-reduced' (the tiny CPU config); weights "
+                         "and KV cache take the config's dtype")
     ap.add_argument("--policy", default="lc",
                     choices=["rr", "lc", "wrr", "fractions", "ours"])
     ap.add_argument("--autoscale", default=None,
@@ -523,7 +537,11 @@ def main():
                          "include a 'fleet' axis. Overrides --devices' "
                          "mesh shape but not its virtual-device setup")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
 
     # device-count setup MUST precede the first jax import: XLA reads
     # --xla_force_host_platform_device_count once at backend init
@@ -534,8 +552,12 @@ def main():
     if args.devices > 0:
         set_host_device_count(args.devices)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import jax
-    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
 
     from repro.configs import get_config
     from repro.models.model import make_model
@@ -549,9 +571,13 @@ def main():
         print(f"[serve] mesh: {dict(zip(mesh.axis_names, mesh.shape.values()))}"
               f" over {len(mesh.devices.ravel())} device(s)")
 
-    cfg = get_config(args.arch).reduced()
+    cfg = get_config(args.arch)
     model = make_model(cfg, tp=1)
-    params = model.init(jax.random.PRNGKey(args.seed), jnp.float32)
+    # under a mesh the weights are built replicated in place: no
+    # single-device original stays alive beside the fleet's copy
+    params = model.init(jax.random.PRNGKey(args.seed), cfg.dtype,
+                        sharding=None if mesh is None
+                        else NamedSharding(mesh, P()))
     print(f"[serve] arch={cfg.name} policy={args.policy}")
 
     # --cells/--hierarchy only exist in the control loop: requesting them

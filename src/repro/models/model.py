@@ -45,15 +45,13 @@ class Model:
     remat: str = "none"
 
     # ------------------------------------------------------------------ init
-    def init(self, key, dtype=jnp.float32):
-        c, d = self.cfg, self.dims
-        if c.family in ("dense", "moe", "vlm"):
-            return lm.init_lm(key, c, d, dtype)
-        if c.family in ("ssm", "hybrid"):
-            return ssm_lm.init_ssm_lm(key, c, d, dtype)
-        if c.family == "audio":
-            return encdec.init_encdec(key, c, d, dtype)
-        raise ValueError(c.family)
+    def init(self, key, dtype=jnp.float32, sharding=None):
+        """Random params from ``key``. Runs under ``jit``, so each float32
+        draw fuses into its cast and no full-size float32 temporary is
+        built; ``sharding`` places every leaf as it is created (e.g.
+        replicated over a serving mesh), so no single-device copy exists."""
+        return _init_params(self.cfg, self.dims, key, jnp.dtype(dtype),
+                            sharding)
 
     # --------------------------------------------------------------- forward
     def forward(self, params, batch, shard_fn=None, return_features=False):
@@ -220,6 +218,23 @@ class Model:
             "pos": sds((), jnp.int32),
             "state": state,
         }
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 3, 4))
+def _init_params(cfg: ArchConfig, dims: PaddedDims, key, dtype, sharding):
+    # keyed on (cfg, dims), not on a Model: a static Model would pin the
+    # serve kernels cached on it for the life of the process
+    if cfg.family in ("dense", "moe", "vlm"):
+        params = lm.init_lm(key, cfg, dims, dtype)
+    elif cfg.family in ("ssm", "hybrid"):
+        params = ssm_lm.init_ssm_lm(key, cfg, dims, dtype)
+    elif cfg.family == "audio":
+        params = encdec.init_encdec(key, cfg, dims, dtype)
+    else:
+        raise ValueError(cfg.family)
+    if sharding is not None:
+        params = jax.lax.with_sharding_constraint(params, sharding)
+    return params
 
 
 def make_model(cfg: ArchConfig, tp: int = 1, remat: str = "none") -> Model:

@@ -85,8 +85,8 @@ the KV pool is then stored int8 with per-(token, head) f32 absmax scales
 (``repro.serving.kv_quant``), roughly 3.6x the slot capacity of an fp32 pool
 for the same bytes. Non-f32 caches stay on single-shot prefill (the int8
 codec quantizes whole prompts at prefill end, and a bf16 pool would round
-the carried chunk state that single-shot keeps unrounded), so ``chunk_len``
-is ignored there.
+the carried chunk state that single-shot keeps unrounded), so a
+``chunk_len`` there is an error.
 
 **Async tick contract (overlapped serving).** In async mode (the elastic
 frontend's default) the fleet dispatch methods never block on the device:
@@ -816,6 +816,21 @@ class FleetGroup:
         mesh-sharded and one device-0-committed operand are an error)."""
         return jax.device_put(x, NamedSharding(self.mesh, P()))
 
+    def _zero_slab(self, cap: int):
+        """A zero slab of ``cap`` rows, allocated where it will live (the
+        fleet sharding under a mesh): no full-size staging copy lands on
+        the default device."""
+        per_replica = jax.eval_shape(lambda: self.model.init_serve_state(
+            self.max_batch, self.max_seq, self.cache_dtype))
+        shapes = jax.tree.map(
+            lambda c: jax.ShapeDtypeStruct((cap,) + c.shape, c.dtype),
+            per_replica)
+        if self.mesh is None:
+            return jax.tree.map(lambda c: jnp.zeros(c.shape, c.dtype), shapes)
+        return jax.tree.map(
+            lambda c, sh: jnp.zeros(c.shape, c.dtype, device=sh),
+            shapes, fleet_slab_shardings(self.mesh, shapes))
+
     def _place_slab(self, slab):
         """Pin the slab's sharding: fleet axis over the mesh's fleet axis,
         per-replica cache dims under the serve-mode rules."""
@@ -835,30 +850,38 @@ class FleetGroup:
         row = len(self.members)
         if row >= self.cap:
             new_cap = self._cap_for(row + 1)
+            slab = self._zero_slab(new_cap)
             if self.slab is None:
-                self.slab = jax.tree.map(
-                    lambda c: jnp.zeros((new_cap,) + c.shape, c.dtype),
-                    eng.cache)
+                self.slab = slab
                 if self.async_mode:
                     self.ops = _init_ops(new_cap, self.max_batch)
             else:
-                grow = lambda s: jnp.concatenate(
-                    [s, jnp.zeros((new_cap - self.cap,) + s.shape[1:],
-                                  s.dtype)])
-                self.slab = jax.tree.map(grow, self.slab)
+                self.slab = jax.tree.map(lambda n, s: n.at[:self.cap].set(s),
+                                         slab, self.slab)
                 if self.async_mode:
+                    grow = lambda s: jnp.concatenate(
+                        [s, jnp.zeros((new_cap - self.cap,) + s.shape[1:],
+                                      s.dtype)])
                     self.ops = jax.tree.map(grow, self.ops)
             self.cap = new_cap
             if self.mesh is not None:
-                # re-pin after (re)allocation: zeros/concatenate land on the
-                # default device; the slab must carry the fleet sharding so
-                # GSPMD row-partitions every subsequent dispatch
+                # re-pin after (re)allocation: the eager copy above and the
+                # operands' concatenate need not keep the fleet sharding,
+                # and GSPMD must row-partition every subsequent dispatch
                 self.slab = self._place_slab(self.slab)
                 if self.async_mode:
                     self.ops = self._place_ops(self.ops)
-        cache = eng.cache if self.mesh is None else self._replicated(eng.cache)
-        self.slab = jax.tree.map(lambda s, c: s.at[row].set(c),
-                                 self.slab, cache)
+        if eng.cache is None:
+            # a fresh replica: its slots are empty, so its row only has to
+            # be cleared of a former member's state (never built whole
+            # elsewhere first)
+            self.slab = jax.tree.map(
+                lambda s: s.at[row].set(jnp.zeros((), s.dtype)), self.slab)
+        else:
+            cache = eng.cache if self.mesh is None \
+                else self._replicated(eng.cache)
+            self.slab = jax.tree.map(lambda s, c: s.at[row].set(c),
+                                     self.slab, cache)
         if self.async_mode:
             self._seed_ops_row(row, eng)
         eng.cache = None
@@ -895,9 +918,11 @@ class FleetGroup:
         if restore:
             eng.cache = jax.tree.map(lambda s: s[row], self.slab)
             if self.mesh is not None:
-                # hand the detached engine a plain single-device cache (the
-                # eager slice above is committed to the whole mesh)
-                eng.cache = jax.device_put(eng.cache, jax.devices()[0])
+                # the detached engine steps standalone against its own
+                # params, so its cache goes where they live (the eager
+                # slice above is committed to the whole mesh)
+                eng.cache = jax.device_put(
+                    eng.cache, jax.tree.leaves(eng.params)[0].sharding)
         last = self.members.pop()
         if last is not eng:          # backfill the hole with the last row
             backfill = lambda s: s.at[row].set(s[len(self.members)])
@@ -1363,7 +1388,9 @@ class ReplicaEngine:
         self.speed = speed            # relative decode speed (hetero hardware)
         self.min_bucket = min_bucket
         self.draining = False         # drained replicas admit nothing new
-        self.cache = model.init_serve_state(max_batch, max_seq, cache_dtype)
+        # device KV/state pool, built on first standalone use: a replica
+        # that joins a fleet keeps its state in the fleet slab instead
+        self.cache = None
         self.pos = np.zeros(max_batch, np.int32)       # next cache index
         self.last_tok = np.zeros(max_batch, np.int32)
         self.slots: list = [None] * max_batch
@@ -1388,7 +1415,11 @@ class ReplicaEngine:
         # the chunk-vs-single-shot exactness the parity oracle relies on.
         if chunk_len and (model.cfg.family not in _CHUNK_FAMILIES
                           or _dtype_name(cache_dtype) != "float32"):
-            chunk_len = 0
+            raise ValueError(
+                f"chunked prefill (chunk_len={chunk_len}) needs a "
+                f"{'/'.join(_CHUNK_FAMILIES)} model with a float32 cache; "
+                f"got family={model.cfg.family!r}, "
+                f"cache_dtype={_dtype_name(cache_dtype)!r}")
         self.chunk_len = int(chunk_len)
         self._kernels = get_serve_kernels(model, max_seq, cache_dtype,
                                           attn_backend)
@@ -1448,6 +1479,13 @@ class ReplicaEngine:
         return lost
 
     # ------------------------------------------------------------- plumbing
+    def _own_cache(self):
+        """The standalone device pool, allocated on first use."""
+        if self.cache is None:
+            self.cache = self.model.init_serve_state(
+                self.max_batch, self.max_seq, self.cache_dtype)
+        return self.cache
+
     def _insert_slot(self, slot: int, small_state, row: int, prompt_len: int,
                      first_tok: int, req: Request):
         if self._fleet is not None:
@@ -1456,7 +1494,7 @@ class ReplicaEngine:
         else:
             def put(big, small):
                 return big.at[:, slot].set(small[:, row])
-            self.cache = jax.tree.map(put, self.cache, small_state)
+            self.cache = jax.tree.map(put, self._own_cache(), small_state)
         self.pos[slot] = prompt_len
         self.last_tok[slot] = first_tok
         self.slots[slot] = req
@@ -1672,7 +1710,8 @@ class ReplicaEngine:
         for i, (slot, *_rest) in enumerate(rows):
             slots[i] = slot
         first, pos, self.cache = self._kernels.chunk(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(offs),
+            self.params, self._own_cache(), jnp.asarray(toks),
+            jnp.asarray(offs),
             jnp.asarray(lens), jnp.asarray(fresh), jnp.asarray(slots))
         self.prefill_dispatches += 1
         first, pos = _timed_get(self, (first, pos))
