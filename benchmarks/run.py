@@ -8,7 +8,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
   serve/*                 — elastic request-level engine (tok/s, TTFT,
                             prefill retraces) -> results/BENCH_serve.json
   roofline/*              — per (arch x shape) roofline terms (§Roofline)
-  kernel/*                — kernel microbenches
 
 Artifacts land under results/ (CSVs + JSON).
 """
@@ -42,9 +41,6 @@ def main() -> None:
     if all_ or "roofline" in args:
         from benchmarks.roofline import main as roofline_main
         rows += roofline_main()
-    if all_ or "kernels" in args:
-        from benchmarks.kernels_bench import main as kernels_main
-        rows += kernels_main()
 
     print("name,us_per_call,derived")
     for name, us, derived in rows:

@@ -18,13 +18,17 @@ the request-level elastic engine (``repro.launch.serve``) unchanged.
 
 With the elastic backend's (default) overlapped async tick, step 3 returns
 after ONE blocking host sync: the forecast -> balance -> scale work of this
-loop runs while the accelerator computes the tick's decode, so a faster
-control cadence comes for free (``metrics()['sync_wait_s']`` is the only
-blocked time). The metrics the plane observes then describe the device
-state as of one tick earlier — scaling rules tolerate that lag by design
-(production autoscalers poll far staler signals); the eager backend mode
-(``async_tick=False``) restores synchronous observation when exact
-sim-parity of the control trajectory matters.
+loop runs while the accelerator computes the tick's decode
+(``metrics()['sync_wait_s']`` is the engine's blocked time). The metrics the
+plane observes then describe the device state as of one tick earlier —
+scaling rules tolerate that lag by design (production autoscalers poll far
+staler signals); the eager backend mode (``async_tick=False``) restores
+synchronous observation when exact sim-parity of the control trajectory
+matters. The plane's own device results (forecast, balancer action, GPSO
+plan) are fetched through ``obs.timed_get`` and accounted in
+``sync_wait``/``syncs``: a fetch queued behind the previous tick's decode
+waits for it there. Each phase of ``step`` is a ``repro.plane.*`` profiler
+span (``repro.obs``).
 
 **Two-level hierarchy and crash tolerance** (PR 10, see
 ``control/hierarchy.py``): under ``PlaneSupervisor`` this plane is the
@@ -59,6 +63,7 @@ from repro.core.autoscaler import (GPSOAutoscaler, HPAAutoscaler,
                                    RBASAutoscaler, StaticAllocator)
 from repro.core.forecaster import forecast as nn_forecast
 from repro.core.forecaster import last_value_baseline
+from repro.obs import span, timed_get
 
 # (balancer, autoscaler) pairs for the paper's §4.2 comparison matrix.
 METHOD_SPECS = {
@@ -124,6 +129,10 @@ class ControlPlane:
         self._prev = None            # (obs, action, reward) for RL replay
         self._resid = np.zeros(64, np.float32)   # rolling forecast residuals
         self._prev_fc1 = None
+        # blocking device fetches of the plane's own work (forecast, actor,
+        # GPSO plan), accounted as the engine accounts its own
+        self.syncs = 0
+        self.sync_wait = 0.0
 
     # -------------------------------------------------- checkpoint/restore
     def state_dict(self) -> dict:
@@ -153,13 +162,14 @@ class ControlPlane:
     # ------------------------------------------------------------ forecast
     def _forecast(self, arrival_rate: float) -> np.ndarray:
         if self.forecaster_params is not None:
-            fc = np.asarray(_jit_forecast(
+            fc = _jit_forecast(
                 self.forecaster_params,
-                jnp.asarray(self.window[:, None] / self.forecast_scale)))[:, 0]
+                jnp.asarray(self.window[:, None] / self.forecast_scale))
         else:
-            fc = np.asarray(last_value_baseline(
+            fc = last_value_baseline(
                 jnp.asarray(self.window[:, None] / self.forecast_scale),
-                self.cfg.horizon))[:, 0]
+                self.cfg.horizon)
+        fc = np.asarray(timed_get(self, fc))[:, 0]
         fc = fc.astype(np.float32)
         # rolling 1-step forecast-error tracker -> volatility-aware headroom
         if self._prev_fc1 is not None:
@@ -186,7 +196,7 @@ class ControlPlane:
                              explore=self.explore)
         else:
             raise ValueError(self.balancer)
-        return np.asarray(fr)
+        return np.asarray(timed_get(self, fr))
 
     # --------------------------------------------------------------- scale
     def _scale(self, m: dict, fc: np.ndarray, arrival_rate: float):
@@ -218,7 +228,8 @@ class ControlPlane:
                 target = self.scaler.plan(node_demand, self.t, in_flight,
                                           node_speed=self.backend.node_speed,
                                           slo_pressure=m.get("tier_pressure"),
-                                          preempt_risk=m.get("preempt_risk"))
+                                          preempt_risk=m.get("preempt_risk"),
+                                          owner=self)
                 self.backend.scale_to(target)
             else:
                 # emergency path: instantaneous overload on a node triggers
@@ -237,30 +248,39 @@ class ControlPlane:
     # ---------------------------------------------------------------- tick
     def step(self, arrival_rate: float) -> dict:
         """One forecast -> balance -> advance -> (learn) -> scale tick."""
+        with span("plane.step"):
+            return self._step(arrival_rate)
+
+    def _step(self, arrival_rate: float) -> dict:
         cfg = self.cfg
-        fc = self._forecast(arrival_rate)
-        obs = self.backend.observe(fc)
-        up = self.backend.up_mask()
-        self.fractions = self._balance(obs, up, arrival_rate)
-        self.backend.route(self.fractions)
+        with span("plane.forecast"):
+            fc = self._forecast(arrival_rate)
+        with span("plane.observe"):
+            obs = self.backend.observe(fc)
+            up = self.backend.up_mask()
+        with span("plane.balance"):
+            self.fractions = self._balance(obs, up, arrival_rate)
+            self.backend.route(self.fractions)
         m = self.backend.tick(arrival_rate)
 
         if self.balancer == "rl":
-            # Eq.5, tier-weighted: backends serving tiered traffic report a
-            # weighted SLO violation level; untiered backends omit the key
-            # and the reward reduces to the original shape.
-            reward = bal.reward_fn(m["response_time"], m["mean_utilization"],
-                                   cfg.alpha, cfg.beta, m["overload"],
-                                   slo_cost=cfg.slo_gamma *
-                                   float(m.get("tier_slo_cost") or 0.0))
-            if self._prev is not None and self.train_rl:
-                self.rl.observe(self._prev[0], self._prev[1],
-                                float(self._prev[2]), obs, up)
-                if self.t % self.train_every == 0:
-                    self.rl.train_step()
-            self._prev = (obs, self.fractions, reward)
+            with span("plane.learn"):
+                # Eq.5, tier-weighted: backends serving tiered traffic
+                # report a weighted SLO violation level; untiered backends
+                # omit the key and the reward reduces to the original shape.
+                reward = bal.reward_fn(
+                    m["response_time"], m["mean_utilization"], cfg.alpha,
+                    cfg.beta, m["overload"], slo_cost=cfg.slo_gamma *
+                    float(m.get("tier_slo_cost") or 0.0))
+                if self._prev is not None and self.train_rl:
+                    self.rl.observe(self._prev[0], self._prev[1],
+                                    float(self._prev[2]), obs, up)
+                    if self.t % self.train_every == 0:
+                        self.rl.train_step()
+                self._prev = (obs, self.fractions, reward)
 
-        self._scale(m, fc, arrival_rate)
+        with span("plane.scale"):
+            self._scale(m, fc, arrival_rate)
 
         self.window = np.roll(self.window, -1)
         self.window[-1] = arrival_rate

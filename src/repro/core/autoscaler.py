@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.core.gpso import (ga_only_minimize, gpso_minimize,
                              preemption_risk_cost, slo_violation_cost)
+from repro.obs import timed_get
 
 
 def eq9_fitness(R, ctx):
@@ -105,7 +106,8 @@ class GPSOAutoscaler:
              current: np.ndarray,
              node_speed: Optional[np.ndarray] = None,
              slo_pressure: Optional[np.ndarray] = None,
-             preempt_risk: Optional[np.ndarray] = None) -> np.ndarray:
+             preempt_risk: Optional[np.ndarray] = None,
+             owner=None) -> np.ndarray:
         """node_demand: (N,) forecast peak demand per node -> replicas (N,).
 
         slo_pressure: optional (N,) tier-weighted backlog (the backends'
@@ -113,7 +115,9 @@ class GPSOAutoscaler:
         tiered Eq.9 objective. preempt_risk: optional (N,) spot-churn
         signal (``preempt_risk`` metric); when any node is at risk the
         objective gains the preemption-risk cost term. All-zero signals
-        keep the base objective — bit-parity with the pre-chaos planner."""
+        keep the base objective — bit-parity with the pre-chaos planner.
+        owner: where the plan's blocking fetch is accounted
+        (``obs.timed_get``); the control plane passes itself."""
         cfg = self.cluster_cfg
         n = node_demand.shape[0]
         if node_speed is None:
@@ -141,7 +145,9 @@ class GPSOAutoscaler:
             sub, fitness, node_demand.shape[0], cfg,
             lo=float(cfg.min_replicas_per_node),
             hi=float(cfg.max_replicas_per_node), ctx=ctx)
-        target = np.asarray(jnp.round(best), np.int32)
+        target = jnp.round(best)
+        target = np.asarray(target if owner is None
+                            else timed_get(owner, target), np.int32)
         # scale-down cooldown (flap damping)
         if (target < current).any():
             if tick - self._last_scale_down < cfg.cooldown:

@@ -126,15 +126,24 @@ failure. ``metrics()`` grows three always-on keys — ``goodput`` /
 and ``preempt_risk`` (per-node 0/1 notice-or-down signal the GPSO planner
 consumes as Eq.9 risk cost) — all zeros when chaos is off, so streams and
 planner behavior stay bit-identical to the pre-chaos stack.
+
+**Tracing.** ``tick`` is the profiler span ``repro.fe.tick`` with the
+children ``repro.fe.reconcile`` (each ``_reconcile_all``), ``repro.fe.route``
+(provisioning, chaos, arrivals, culls, routing, node dispatch),
+``repro.fe.admit`` and ``repro.fe.decode`` (see ``repro.obs``). A request
+accepted into ``pending`` is stamped ``submitted_s`` on the wall clock;
+admission stamps ``admitted_s`` (``ReplicaEngine.plan_admission``).
 """
 from __future__ import annotations
 
 import re
+import time
 from collections import deque
 from typing import Callable, Optional
 
 import numpy as np
 
+from repro.obs import span
 from repro.serving.engine import (FleetGroup, ReplicaEngine, Request,
                                   TieredQueue, normalize_fractions)
 from repro.workload.trace import DEFAULT_TIERS, TierSet
@@ -572,9 +581,10 @@ class ElasticClusterFrontend:
         """The per-tick reconcile point: flush every fleet group's pending
         device futures (one blocking sync per group) and collect the newly
         finished requests, plus any stashed by mid-tick churn flushes."""
-        out, self._async_stash = self._async_stash, []
-        for g in list(self._fleets.values()):
-            out.extend(g.reconcile())
+        with span("fe.reconcile"):
+            out, self._async_stash = self._async_stash, []
+            for g in list(self._fleets.values()):
+                out.extend(g.reconcile())
         return out
 
     @property
@@ -611,6 +621,7 @@ class ElasticClusterFrontend:
         if self.max_queue is not None and self._outstanding() >= self.max_queue:
             self.ledger.reject(req)
             return False
+        req.submitted_s = time.perf_counter()
         self.pending.append(req)
         return True
 
@@ -972,6 +983,7 @@ class ElasticClusterFrontend:
             self._req_id += 1
             req.arrival = float(self.t - 1)   # arrives as this tick begins
             self.ledger.register(req)         # fresh rid: always accepted
+            req.submitted_s = time.perf_counter()
             self.pending.append(req)
 
     def _cull_expired(self) -> list:
@@ -1032,6 +1044,10 @@ class ElasticClusterFrontend:
             eng.submit(node.queue.pop())
 
     def tick(self, arrival_rate: float = 0.0) -> dict:
+        with span("fe.tick"):
+            return self._tick(arrival_rate)
+
+    def _tick(self, arrival_rate: float) -> dict:
         self.t += 1
         prefill_before = self.prefill_dispatches()
         syncs_before = self.sync_count()
@@ -1040,26 +1056,27 @@ class ElasticClusterFrontend:
         # results (retires free their slots HERE, before admission planning,
         # so admission timing matches the eager oracle exactly)
         finished_now: list = self._reconcile_all()
-        self._advance_provisioning()
-        self._advance_chaos()     # scripted events + notice timers: their
-        self._inject_failures()   # hand-backs re-route this same tick
-        self._generate_arrivals(arrival_rate)
-        finished_now.extend(self._cull_expired())
-        self._reroute_stranded()
-        self._route_pending()
-        self._tick_dispatches = 0
-        stepping: list = []          # (engine, n_substeps) across ALL nodes
-        for node in self.nodes:
-            self._dispatch(node)
-            for eng in list(node.live) + list(node.draining):
-                node.credit[id(eng)] = node.credit.get(id(eng), 0.0) + \
-                    eng.speed * node.slow
-                n_sub = int(node.credit[id(eng)])
-                node.credit[id(eng)] -= n_sub
-                if n_sub <= 0:
-                    continue
-                eng.clock = float(self.t - 1)
-                stepping.append((eng, n_sub))
+        with span("fe.route"):
+            self._advance_provisioning()
+            self._advance_chaos()     # scripted events + notice timers:
+            self._inject_failures()   # their hand-backs re-route this tick
+            self._generate_arrivals(arrival_rate)
+            finished_now.extend(self._cull_expired())
+            self._reroute_stranded()
+            self._route_pending()
+            self._tick_dispatches = 0
+            stepping: list = []      # (engine, n_substeps) across ALL nodes
+            for node in self.nodes:
+                self._dispatch(node)
+                for eng in list(node.live) + list(node.draining):
+                    node.credit[id(eng)] = node.credit.get(id(eng), 0.0) \
+                        + eng.speed * node.slow
+                    n_sub = int(node.credit[id(eng)])
+                    node.credit[id(eng)] -= n_sub
+                    if n_sub <= 0:
+                        continue
+                    eng.clock = float(self.t - 1)
+                    stepping.append((eng, n_sub))
         # sub-step rounds: round r advances every engine with n_sub > r, so
         # a homogeneous-speed cluster runs exactly one round and each fleet
         # group issues ONE decode dispatch (plus, under fleet admission, one
@@ -1080,23 +1097,25 @@ class ElasticClusterFrontend:
                 finished_now.extend(self._reconcile_all())
             round_engines = [(e, n) for e, n in stepping if n > r]
             ids = {id(e) for e, _ in round_engines}
-            for eng, n in round_engines:
-                finished_now.extend(eng.begin_step(
-                    dt=1.0 / n,
-                    admit=eng._fleet is None or not self.fleet_prefill))
-            if self.fleet_prefill:
+            with span("fe.admit"):
+                for eng, n in round_engines:
+                    finished_now.extend(eng.begin_step(
+                        dt=1.0 / n,
+                        admit=eng._fleet is None or not self.fleet_prefill))
+                if self.fleet_prefill:
+                    for g in self._fleets.values():
+                        finished_now.extend(g.admit_round(ids))
+            with span("fe.decode"):
                 for g in self._fleets.values():
-                    finished_now.extend(g.admit_round(ids))
-            for g in self._fleets.values():
-                before = g.dispatches
-                finished_now.extend(g.decode_round(
-                    ids, allow_block=allow_block))
-                self._tick_dispatches += g.dispatches - before
-            for eng, _ in round_engines:     # engines outside any fleet
-                if eng._fleet is None:
-                    if eng.n_decoding:
-                        self._tick_dispatches += 1
-                    finished_now.extend(eng.finish_step())
+                    before = g.dispatches
+                    finished_now.extend(g.decode_round(
+                        ids, allow_block=allow_block))
+                    self._tick_dispatches += g.dispatches - before
+                for eng, _ in round_engines:     # engines outside any fleet
+                    if eng._fleet is None:
+                        if eng.n_decoding:
+                            self._tick_dispatches += 1
+                        finished_now.extend(eng.finish_step())
         for node in self.nodes:
             for eng in list(node.draining):   # retire drained replicas
                 if eng.load == 0:

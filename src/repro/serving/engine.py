@@ -164,6 +164,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import fleet_slab_shardings
 from repro.models.model import Model
+from repro.obs import timed_get
 from repro.workload.trace import DEFAULT_TIERS, TierSet
 
 # families whose prefill accepts per-row ``lengths`` (bucketed prompts are
@@ -238,17 +239,6 @@ class _ServeKernels:
 def _dtype_name(cache_dtype) -> str:
     return cache_dtype if isinstance(cache_dtype, str) else \
         np.dtype(cache_dtype).name
-
-
-def _timed_get(owner, arrays):
-    """Blocking fetch of device ``arrays``, accounted on ``owner``: bumps
-    ``owner.syncs`` and adds the blocked wall time to ``owner.sync_wait``
-    (the host-vs-device tick breakdown the serve bench reports)."""
-    t0 = time.perf_counter()
-    out = jax.device_get(arrays)
-    owner.sync_wait += time.perf_counter() - t0
-    owner.syncs += 1
-    return out
 
 
 def _init_ops(cap: int, batch: int) -> dict:
@@ -1027,7 +1017,7 @@ class FleetGroup:
             self.params, self.slab, jnp.asarray(toks), jnp.asarray(lens),
             jnp.asarray(rows), jnp.asarray(slots))
         self.prefill_dispatches += 1
-        first, plen = _timed_get(self, (first, plen))
+        first, plen = timed_get(self, (first, plen))
         first, plen = np.asarray(first), np.asarray(plen)
         for i, (e, slot, req, p) in enumerate(entries):
             e.commit_admit([slot], [req], first[i:i + 1], plen[i:i + 1],
@@ -1077,7 +1067,7 @@ class FleetGroup:
                 jnp.asarray(lens), jnp.asarray(fresh), jnp.asarray(rows),
                 jnp.asarray(slots))
             self.prefill_dispatches += 1
-            first, pos = _timed_get(self, (first, pos))
+            first, pos = timed_get(self, (first, pos))
             first, pos = np.asarray(first), np.asarray(pos)
             for i, (e, slot, t, off, ln, fr, fin) in enumerate(items):
                 e.commit_chunk(slot, first[i], pos[i], fin, finished)
@@ -1139,7 +1129,7 @@ class FleetGroup:
             nxt, done, self.slab = self._kernels.fleet_masked(
                 self.params, self.slab, toks, pos, rem, eos, active, rows)
         self.dispatches += 1
-        nxt, done = _timed_get(self, (nxt, done))   # ONE small host sync
+        nxt, done = timed_get(self, (nxt, done))   # ONE small host sync
         nxt, done = np.asarray(nxt), np.asarray(done)
         finished: list = []
         for e in movers:
@@ -1236,7 +1226,7 @@ class FleetGroup:
         if not self.pending or (self._block_credit > 0 and not force):
             return finished
         pend, self.pending = self.pending, []
-        fetched = _timed_get(self, [p.arrays for p in pend])
+        fetched = timed_get(self, [p.arrays for p in pend])
         for p, vals in zip(pend, fetched):
             if p.kind == "decode":
                 self._apply_decode(vals, p.meta, finished)
@@ -1317,6 +1307,10 @@ class Request:
     output: list = dataclasses.field(default_factory=list)
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
+    # wall clock (``time.perf_counter()``): when the frontend accepted the
+    # request, and when admission last took it into a slot
+    submitted_s: Optional[float] = None
+    admitted_s: Optional[float] = None
 
     @property
     def done(self) -> bool:
@@ -1361,6 +1355,13 @@ class Request:
         self.output = []
         self.first_token_time = None
         self.finish_time = None
+        self.admitted_s = None
+
+
+def _admitted(req: Request) -> Request:
+    """Stamp the wall clock at which admission takes ``req`` into a slot."""
+    req.admitted_s = time.perf_counter()
+    return req
 
 
 class ReplicaEngine:
@@ -1528,7 +1529,7 @@ class ReplicaEngine:
                 batch.update({k: jnp.asarray(v) for k, v in extras.items()})
             logits, small, plen = self._prefill(self.params, batch)
         self.prefill_dispatches += 1
-        first, plen = _timed_get(self, (jnp.argmax(logits, axis=-1), plen))
+        first, plen = timed_get(self, (jnp.argmax(logits, axis=-1), plen))
         first = np.asarray(first)
         plen = np.atleast_1d(np.asarray(plen)).astype(np.int32)
         for i, (slot, req) in enumerate(zip(slots, reqs)):
@@ -1598,7 +1599,7 @@ class ReplicaEngine:
                 if len(free) == 1 and self.queue.higher_waiting(tier_idx):
                     deferred.add(tier_idx)    # leave the slot for premium
                     continue
-                req = self.queue.pop(deferred)
+                req = _admitted(self.queue.pop(deferred))
                 slot = free.pop(0)
                 self.slots[slot] = req
                 self._chunks[slot] = _ChunkCursor(
@@ -1607,7 +1608,8 @@ class ReplicaEngine:
             if not self.bucket_prompts or getattr(head, "extras", None):
                 # exact-length single admit (audio / extras-carrying
                 # requests, and moe replicas by default)
-                plans.singles.append((free.pop(0), self.queue.pop(deferred)))
+                plans.singles.append(
+                    (free.pop(0), _admitted(self.queue.pop(deferred))))
                 continue
             group = []
             while len(group) < len(free):
@@ -1616,7 +1618,7 @@ class ReplicaEngine:
                         or self._chunkable(nxt[1]) \
                         or nxt[1].out_of_time(self.clock):
                     break
-                group.append(self.queue.pop(deferred))
+                group.append(_admitted(self.queue.pop(deferred)))
             plans.bucketed.append(([free.pop(0) for _ in group], group))
         return plans
 
@@ -1714,7 +1716,7 @@ class ReplicaEngine:
             jnp.asarray(offs),
             jnp.asarray(lens), jnp.asarray(fresh), jnp.asarray(slots))
         self.prefill_dispatches += 1
-        first, pos = _timed_get(self, (first, pos))
+        first, pos = timed_get(self, (first, pos))
         first, pos = np.asarray(first), np.asarray(pos)
         for i, (slot, t, off, ln, fr, fin) in enumerate(rows):
             self.commit_chunk(slot, first[i], pos[i], fin, finished)
@@ -1754,7 +1756,7 @@ class ReplicaEngine:
                                               pos)
         self.steps += 1
         finished: list = []
-        next_toks = np.asarray(_timed_get(self, jnp.argmax(logits, axis=-1)))
+        next_toks = np.asarray(timed_get(self, jnp.argmax(logits, axis=-1)))
         for slot, req in enumerate(self.slots):
             if req is None or slot in self._chunks:
                 continue

@@ -100,36 +100,62 @@ def test_async_matches_eager_across_churn(arch):
     assert fe_a.sync_count() < fe_e.sync_count()
 
 
+def _arrivals_and_scaling(m, params, async_tick):
+    """Continuous arrivals, cold-start provisioning and scale-down/up
+    churn; returns the finished streams and finish ticks."""
+    def factory(rid):
+        return ReplicaEngine(m, params, max_batch=2, max_seq=MAX_SEQ,
+                             rid=rid)
+
+    def rf(rid, tick):
+        return Request(rid, [1 + rid % 50, 2, 3, 4], max_new_tokens=4)
+
+    fe = ElasticClusterFrontend(factory, 2, initial_replicas=1,
+                                provisioning_delay=2,
+                                request_factory=rf, seed=0,
+                                est_tokens=4, async_tick=async_tick)
+    for t in range(24):
+        fe.tick(1.6)
+        if t == 5:
+            fe.scale_to(np.array([2, 1]))
+        if t == 12:
+            fe.scale_to(np.array([2, 2]))
+        if t == 18:
+            fe.scale_to(np.array([1, 2]))
+    fe.run_until_drained()
+    return _snap_fe(fe)
+
+
 def test_async_matches_eager_with_arrivals_and_scaling(setup):
     """Continuous arrivals + cold-start provisioning + scale-down/up churn:
     the regression scenario where a mid-tick force-flush (drained-replica
     retirement) must not lose or reorder finishes."""
     c, m, params = setup
+    assert _arrivals_and_scaling(m, params, True) == \
+        _arrivals_and_scaling(m, params, False)
 
-    def factory(rid):
-        return ReplicaEngine(m, params, max_batch=2, max_seq=MAX_SEQ,
-                             rid=rid)
 
-    def run(async_tick):
-        def rf(rid, tick):
-            return Request(rid, [1 + rid % 50, 2, 3, 4], max_new_tokens=4)
+def test_streams_unchanged_under_profiler(setup, tmp_path):
+    """With the profiler recording the frontend's spans, token streams and
+    finish ticks are bit-identical to a run without it, and the trace holds
+    every ``repro.fe.*`` span and the blocking fetches."""
+    import glob
+    import os
 
-        fe = ElasticClusterFrontend(factory, 2, initial_replicas=1,
-                                    provisioning_delay=2,
-                                    request_factory=rf, seed=0,
-                                    est_tokens=4, async_tick=async_tick)
-        for t in range(24):
-            fe.tick(1.6)
-            if t == 5:
-                fe.scale_to(np.array([2, 1]))
-            if t == 12:
-                fe.scale_to(np.array([2, 2]))
-            if t == 18:
-                fe.scale_to(np.array([1, 2]))
-        fe.run_until_drained()
-        return _snap_fe(fe)
+    from jax.profiler import ProfileData
 
-    assert run(True) == run(False)
+    c, m, params = setup
+    plain = _arrivals_and_scaling(m, params, True)
+    with jax.profiler.trace(str(tmp_path)):
+        traced = _arrivals_and_scaling(m, params, True)
+    assert traced == plain
+    path = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                  "*.xplane.pb"))[0]
+    names = {e.name for p in ProfileData.from_file(path).planes
+             if p.name.startswith("/host:") for line in p.lines
+             for e in line.events}
+    assert {"repro.fe.tick", "repro.fe.reconcile", "repro.fe.route",
+            "repro.fe.admit", "repro.fe.decode", "repro.fetch"} <= names
 
 
 def test_moe_single_admit_async_parity():
