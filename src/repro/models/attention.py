@@ -202,20 +202,21 @@ def project_decode_qkv(params, x, dims: PaddedDims, pos, rope_theta):
     return q, k_new, v_new
 
 
-def write_kv(k_cache, v_cache, k_new, v_new, pos):
-    """Write one token's k/v at `pos` into (B,S,G,hd) caches — in-place under
-    jit (the caches should be loop carries / donated)."""
+def write_token(cache, x, pos):
+    """Write one token's (B,1,...) leaf ``x`` at ``pos`` (scalar or (B,))
+    into a (B,S,...) cache leaf — in-place under jit (the cache should be a
+    loop carry or donated)."""
     pos = jnp.asarray(pos, jnp.int32)
+    x = x.astype(cache.dtype)
     if pos.ndim == 1:
-        rows = jnp.arange(k_cache.shape[0])
-        k_cache = k_cache.at[rows, pos].set(k_new[:, 0].astype(k_cache.dtype))
-        v_cache = v_cache.at[rows, pos].set(v_new[:, 0].astype(v_cache.dtype))
-    else:
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, k_new.astype(k_cache.dtype), (0, pos, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, v_new.astype(v_cache.dtype), (0, pos, 0, 0))
-    return k_cache, v_cache
+        return cache.at[jnp.arange(cache.shape[0]), pos].set(x[:, 0])
+    return jax.lax.dynamic_update_slice(
+        cache, x, (0, pos) + (0,) * (cache.ndim - 2))
+
+
+def write_kv(k_cache, v_cache, k_new, v_new, pos):
+    """Write one token's k/v at `pos` into (B,S,G,hd) caches."""
+    return write_token(k_cache, k_new, pos), write_token(v_cache, v_new, pos)
 
 
 def decode_attend(params, q, k_cache, v_cache, pos, dims: PaddedDims,

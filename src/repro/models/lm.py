@@ -209,64 +209,84 @@ def lm_init_cache(cfg, dims, batch: int, max_len: int, dtype=jnp.bfloat16):
 
 
 def lm_decode(params, cache, tokens, pos, cfg: ArchConfig, dims: PaddedDims, *,
-              shard_fn=None, attn_backend=None):
+              shard_fn=None, attn_backend=None, commit=None):
     """One decode step. tokens: (B,1) int32; pos: scalar int32 or (B,) int32
     (cache write index, counting any VLM patch prefix).
     ``attn_backend="pallas"`` reads the cache through the flash-decode
     kernel instead of the dense einsum (see ``attention.decode_attend``).
+    ``commit`` (scalar bool) False computes the step but leaves the cache
+    as it was.
 
-    The full stacked cache (L,B,S,G,hd) is the scan CARRY with in-place
-    single-token writes — no per-layer cache stacking copies (the caches
-    should be donated by the caller for true in-place update). An int8
-    quantized cache (``k_q``/``v_q``/``k_s``/``v_s`` leaves) is detected
-    from its structure: new tokens quantize on write, reads dequantize on
-    the fly (the HBM stream is the int8 bytes + scales).
+    The stacked cache (L,B,S,G,hd) is read-only inside the layer scan,
+    whose carry is the hidden state alone: each layer writes its new token
+    into its own slice for its attention and returns the token as a scan
+    output, and one scatter after the scan writes them all at [layer, row,
+    pos[row]] (donate the cache for an in-place update). A cache carried
+    through the scan would be rewritten slice by slice and, under the fleet
+    ``vmap``, relaid layer-major on the way in and out.
+    An int8 quantized cache (``k_q``/``v_q``/``k_s``/``v_s`` leaves) is
+    detected from its structure: new tokens quantize on write, reads
+    dequantize on the fly (the HBM stream is the int8 bytes + scales).
     """
     quant = "k_q" in cache
+    if quant:
+        from repro.serving.kv_quant import dequantize, quantize_kv
     backend = attn_backend or "einsum"
     h = params["embed"][tokens]                              # (B,1,d)
     me = cfg.moe_every if "moe_layers" in params else 1
     n_groups = cfg.num_layers // me
 
-    def sublayer(h, lp, layer_idx, cache):
+    def sublayer(h, lp, layer_idx):
         x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         q, k_new, v_new = attn.project_decode_qkv(lp["attn"], x, dims, pos,
                                                   cfg.rope_theta)
-        lc = {k: jax.lax.dynamic_index_in_dim(v, layer_idx, 0, False)
-              for k, v in cache.items()}
         if quant:
-            from repro.serving.kv_quant import dequantize, write_kv_quant
-            lc = write_kv_quant(lc, k_new, v_new, pos)
+            new = quantize_kv(k_new, v_new)
+        else:
+            new = {"k": k_new.astype(cache["k"].dtype),
+                   "v": v_new.astype(cache["v"].dtype)}
+        lc = {n: attn.write_token(
+                  jax.lax.dynamic_index_in_dim(c, layer_idx, 0, False),
+                  new[n], pos)
+              for n, c in cache.items()}
+        if quant:
             kc = dequantize(lc["k_q"], lc["k_s"]).astype(q.dtype)
             vc = dequantize(lc["v_q"], lc["v_s"]).astype(q.dtype)
         else:
-            kc, vc = attn.write_kv(lc["k"], lc["v"], k_new, v_new, pos)
-            lc = {"k": kc, "v": vc}
-        cache = {k: jax.lax.dynamic_update_index_in_dim(cache[k], lc[k],
-                                                        layer_idx, 0)
-                 for k in cache}
+            kc, vc = lc["k"], lc["v"]
         y = attn.decode_attend(lp["attn"], q, kc, vc, pos, dims,
                                backend=backend)
         h = h + y
         h, _ = _ffn_sublayer(lp, h, cfg, shard_fn)
-        return h, cache
+        return h, {n: t[:, 0] for n, t in new.items()}
 
-    def body(carry, xs):
-        h, cache = carry
+    def body(h, xs):
         lps, g = xs
+        toks = []
         for j in range(me):
             lp = lps if me == 1 else (
                 lps[0] if j == 0
                 else jax.tree.map(lambda x: x[j - 1], lps[1]))
-            h, cache = sublayer(h, lp, g * me + j, cache)
-        return (h, cache), None
+            h, t = sublayer(h, lp, g * me + j)
+            toks.append(t)
+        return h, jax.tree.map(lambda *t: jnp.stack(t), *toks)
 
     if me == 1:
         xs = (params["layers"], jnp.arange(n_groups))
     else:
         xs = ((params["moe_layers"], params["dense_layers"]),
               jnp.arange(n_groups))
-    (h, new_cache), _ = jax.lax.scan(body, (h, cache), xs)
+    h, toks = jax.lax.scan(body, h, xs)         # toks: (groups, me, B, ...)
+    B, S = tokens.shape[0], jax.tree.leaves(cache)[0].shape[2]
+    rows = jnp.arange(B)
+    wpos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    if commit is not None:
+        wpos = jnp.where(commit, wpos, S)       # out of bounds: dropped
+    new_cache = {
+        n: c.at[:, rows, wpos].set(
+            toks[n].reshape((cfg.num_layers,) + toks[n].shape[2:]),
+            mode="drop")
+        for n, c in cache.items()}
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     head = params.get("lm_head")
     logits = h @ head if head is not None else h @ params["embed"].T

@@ -177,22 +177,30 @@ class Model:
         raise ValueError(f"chunked prefill unsupported for {c.family!r}")
 
     def decode(self, params, state, tokens, pos, shard_fn=None,
-               attn_backend=None):
+               attn_backend=None, commit=None):
         """``attn_backend="pallas"`` (dense/moe/vlm only) decodes through
         the flash-decode kernel; None/"einsum" keeps the dense reference
-        path. SSM/audio families carry no KV decode loop and ignore it."""
+        path. SSM/audio families carry no KV decode loop and ignore it.
+        ``commit`` (scalar bool) False computes the step but returns
+        ``state`` as it was: the KV families drop their token writes, the
+        others select the old state back."""
         c, d = self.cfg, self.dims
         if c.family in ("dense", "moe", "vlm"):
             return lm.lm_decode(params, state, tokens, pos, c, d,
                                 shard_fn=shard_fn,
-                                attn_backend=attn_backend)
+                                attn_backend=attn_backend, commit=commit)
         if c.family in ("ssm", "hybrid"):
-            return ssm_lm.ssm_decode(params, state, tokens, pos, c, d,
-                                     shard_fn=shard_fn)
-        if c.family == "audio":
-            return encdec.encdec_decode(params, state, tokens, pos, c, d,
-                                        shard_fn=shard_fn)
-        raise ValueError(c.family)
+            logits, new = ssm_lm.ssm_decode(params, state, tokens, pos, c, d,
+                                            shard_fn=shard_fn)
+        elif c.family == "audio":
+            logits, new = encdec.encdec_decode(params, state, tokens, pos, c,
+                                               d, shard_fn=shard_fn)
+        else:
+            raise ValueError(c.family)
+        if commit is not None:
+            new = jax.tree.map(lambda o, n: jnp.where(commit, n, o), state,
+                               new)
+        return logits, new
 
     # ------------------------------------------------------------ dry-run IO
     def input_specs(self, shape: ShapeConfig, act_dtype=jnp.bfloat16,

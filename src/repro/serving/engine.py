@@ -290,8 +290,9 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
         return model.prefill(p, batch, cache_len=max_seq,
                              cache_dtype=cache_dtype)
 
-    def _decode(p, st, tok, pos):
-        return model.decode(p, st, tok, pos, attn_backend=attn_backend)
+    def _decode(p, st, tok, pos, commit=None):
+        return model.decode(p, st, tok, pos, attn_backend=attn_backend,
+                            commit=commit)
 
     def _decode_fn(p, st, tok, pos):
         _count("decode")
@@ -323,24 +324,20 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
         ((hrows, hslots) index vectors for mid-chunk-prefill slots) keeps
         those slots' state bit-for-bit by gather-before / scatter-after —
         touching K slot rows, NOT select-copying the whole slab; with
-        ``rows`` (F,) only those fleet rows advance at all (hetero speeds).
+        ``rows`` (F,) only those fleet rows advance at all (hetero speeds):
+        the others' step commits nothing (``Model.decode``'s ``commit``).
         Each mask combination is its own kernel variant so the common
         all-decode path keeps the pure donated in-place update."""
         if held is not None:
             hrows, hslots = held
             kept = jax.tree.map(lambda s: s[hrows, :, hslots], slab)
         logits, new_slab = jax.vmap(
-            lambda c, t, q: _decode(p, c, t, q))(slab, toks[..., None], pos)
+            lambda c, t, q, r: _decode(p, c, t, q, r))(
+                slab, toks[..., None], pos, rows)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         done = active & ((rem <= 1) | (nxt == eos)
                          | (pos + 1 >= max_seq - 1))
-
         if rows is not None:
-            def sel(old, new):
-                rm = rows.reshape((rows.shape[0],) + (1,) * (old.ndim - 1))
-                return jnp.where(rm, new, old)
-
-            new_slab = jax.tree.map(sel, slab, new_slab)
             done = done & rows[:, None]
         if held is not None:
             new_slab = jax.tree.map(

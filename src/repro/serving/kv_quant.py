@@ -15,6 +15,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.models.attention import write_token
+
 
 def quantize(x, axis=-1):
     """x: (..., d) -> (int8 values, f32 scales with `axis` reduced)."""
@@ -39,27 +41,18 @@ def init_quant_kv_cache(batch: int, max_len: int, n_kv: int, head_dim: int):
     }
 
 
+def quantize_kv(k_new, v_new) -> dict:
+    """One token's k/v (B, 1, G, d) as the quantized cache's four leaves."""
+    kq, ks = quantize(k_new)
+    vq, vs = quantize(v_new)
+    return {"k_q": kq, "v_q": vq, "k_s": ks, "v_s": vs}
+
+
 def write_kv_quant(cache, k_new, v_new, pos):
     """Write one token's k/v (B, 1, G, d) at `pos` (scalar, or (B,) for the
     per-slot vector-``pos`` serving path)."""
-    kq, ks = quantize(k_new)
-    vq, vs = quantize(v_new)
-    pos = jnp.asarray(pos, jnp.int32)
-    if pos.ndim == 1:
-        rows = jnp.arange(cache["k_q"].shape[0])
-        return {
-            "k_q": cache["k_q"].at[rows, pos].set(kq[:, 0]),
-            "v_q": cache["v_q"].at[rows, pos].set(vq[:, 0]),
-            "k_s": cache["k_s"].at[rows, pos].set(ks[:, 0]),
-            "v_s": cache["v_s"].at[rows, pos].set(vs[:, 0]),
-        }
-    upd = jax.lax.dynamic_update_slice
-    return {
-        "k_q": upd(cache["k_q"], kq, (0, pos, 0, 0)),
-        "v_q": upd(cache["v_q"], vq, (0, pos, 0, 0)),
-        "k_s": upd(cache["k_s"], ks, (0, pos, 0)),
-        "v_s": upd(cache["v_s"], vs, (0, pos, 0)),
-    }
+    new = quantize_kv(k_new, v_new)
+    return {n: write_token(cache[n], new[n], pos) for n in cache}
 
 
 def decode_attend_quant(q, cache, pos):

@@ -243,6 +243,147 @@ def test_int8_fleet_parity(setup):
     assert run(True) == run(False)
 
 
+# ------------------------------------------------------ decode token writes
+def _dequant(slab):
+    """Float K/V leaves of a float or int8 slab."""
+    if "k_q" in slab:
+        return {n: np.asarray(slab[n + "_q"], np.float32)
+                * np.asarray(slab[n + "_s"])[..., None] for n in ("k", "v")}
+    return {n: np.asarray(slab[n], np.float32) for n in ("k", "v")}
+
+
+def _changed(before, after):
+    """(F, B, S) mask of the cache cells any layer or leaf changed."""
+    ch = None
+    for b, a in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
+        d = np.asarray(b) != np.asarray(a)
+        d = d.reshape(d.shape[:4] + (-1,)).any(axis=(1, 4))    # (F, B, S)
+        ch = d if ch is None else ch | d
+    return ch
+
+
+@pytest.mark.parametrize("cache_dtype", ["bfloat16", "int8"])
+def test_afleet_writes_only_each_steps_token(setup, cache_dtype):
+    """Async fleet decode against independent oracles: each step changes
+    the slab only at every stepping slot's write position; after the steps
+    a slot's K/V at [0, pos) equals a prefill of the same tokens; held
+    slots (``afleet_hold``) and rows that do not step (``afleet_masked``)
+    stay bit-for-bit as they were."""
+    from repro.serving.engine import _init_ops, get_serve_kernels
+    c, m, params = setup
+    cd = "int8" if cache_dtype == "int8" else jnp.dtype(cache_dtype)
+    F, B, n_steps = 2, 2, 4
+    kern = get_serve_kernels(m, MAX_SEQ, cd)
+    rng = np.random.default_rng(5)
+    # the slab holds earlier occupants' state
+    slab = jax.tree.map(
+        lambda t: jnp.asarray(rng.integers(-100, 100, (F,) + t.shape)
+                              if t.dtype == jnp.int8 else
+                              rng.uniform(0.1, 2.0, (F,) + t.shape),
+                              t.dtype),
+        m.init_serve_state(B, MAX_SEQ, cd))
+    lens = np.array([5, 7, 3, 6], np.int32)
+    toks = np.zeros((F * B, 8), np.int32)
+    prompts = [rng.integers(1, 400, n) for n in lens]
+    for i, p in enumerate(prompts):
+        toks[i, :len(p)] = p
+    rows, slots = np.repeat(np.arange(F), B), np.tile(np.arange(B), F)
+    first, slab, ops = kern.afleet_prefill(
+        params, slab, _init_ops(F, B), jnp.asarray(toks), jnp.asarray(lens),
+        jnp.asarray(rows, np.int32), jnp.asarray(slots, np.int32),
+        jnp.full((F * B,), 50, jnp.int32), jnp.full((F * B,), -1, jnp.int32))
+    seqs = [list(p) + [int(t)] for p, t in zip(prompts, np.asarray(first))]
+
+    def step(fn, *extra):
+        nonlocal slab, ops
+        before = jax.tree.map(np.asarray, slab)
+        pos = np.asarray(ops["pos"])
+        nxt, _, stepped, slab, ops = fn(params, slab, ops, *extra)
+        return before, pos, np.asarray(nxt), np.asarray(stepped)
+
+    at_pos = np.arange(MAX_SEQ)[None, None, :]
+    for _ in range(n_steps):
+        before, pos, nxt, stepped = step(kern.afleet)
+        assert stepped.all()
+        np.testing.assert_array_equal(_changed(before, slab),
+                                      at_pos == pos[..., None])
+        for i, (f, b) in enumerate(zip(rows, slots)):
+            seqs[i].append(int(nxt[f, b]))
+
+    pos = np.asarray(ops["pos"])
+    got = _dequant(slab)
+    for i, (f, b) in enumerate(zip(rows, slots)):
+        n = int(pos[f, b])
+        assert n == lens[i] + n_steps
+        _, ref, _ = m.prefill(params, {"tokens": jnp.asarray([seqs[i][:n]])},
+                              cache_len=MAX_SEQ, cache_dtype=cd)
+        ref = _dequant(ref)
+        for name in ("k", "v"):
+            want, have = ref[name][:, 0, :n], got[name][f, :, b, :n]
+            err = np.abs(have - want).max() / np.abs(want).max()
+            assert err < 0.03, (cache_dtype, name, i, err)
+
+    # held slot (row 0, slot 1): its state is kept bit-for-bit
+    hrows, hslots = jnp.asarray([0, F], jnp.int32), jnp.asarray([1, 0],
+                                                                jnp.int32)
+    before, pos, _, _ = step(kern.afleet_hold, hrows, hslots)
+    want = at_pos == pos[..., None]
+    want[0, 1] = False
+    np.testing.assert_array_equal(_changed(before, slab), want)
+    # masked: only fleet row 1 steps; row 0 keeps its state bit-for-bit
+    before, pos, _, stepped = step(kern.afleet_masked,
+                                   jnp.asarray([False, True]))
+    assert not stepped[0].any() and stepped[1].all()
+    want = at_pos == pos[..., None]
+    want[0] = False
+    np.testing.assert_array_equal(_changed(before, slab), want)
+
+
+def _scan_carry_shapes(jaxpr):
+    """Shapes of every ``scan`` carry in ``jaxpr`` and its sub-jaxprs."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+            out += [v.aval.shape for v in eqn.invars[nc:nc + nk]]
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                if isinstance(sub, ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, Jaxpr):
+                    out += _scan_carry_shapes(sub)
+    return out
+
+
+@pytest.mark.parametrize("kernel", ["afleet", "decode"])
+@pytest.mark.parametrize("cache_dtype", ["bfloat16", "int8"])
+def test_decode_scan_carries_no_cache(setup, kernel, cache_dtype):
+    """The decode's layer scan carries the hidden state, never the KV
+    cache: a cache carried through the scan is rewritten one layer slice
+    per layer, and under the fleet ``vmap`` relaid layer-major in and out
+    (the TPU compile test sees that as slab-sized copies)."""
+    from repro.serving.engine import _init_ops, get_serve_kernels
+    c, m, params = setup
+    cd = "int8" if cache_dtype == "int8" else jnp.dtype(cache_dtype)
+    F, B = 2, 2
+    kern = get_serve_kernels(m, MAX_SEQ, cd)
+    state = jax.eval_shape(lambda: m.init_serve_state(B, MAX_SEQ, cd))
+    slab = jax.tree.map(
+        lambda t: jax.ShapeDtypeStruct((F,) + t.shape, t.dtype), state)
+    if kernel == "afleet":
+        ops = jax.eval_shape(lambda: _init_ops(F, B))
+        jaxpr = jax.make_jaxpr(kern.afleet)(params, slab, ops)
+    else:
+        jaxpr = jax.make_jaxpr(kern.decode)(
+            params, state, jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32))
+    carries = _scan_carry_shapes(jaxpr.jaxpr)
+    assert carries                                   # the layer scan
+    cache = {t.shape for t in jax.tree.leaves(state)} | \
+        {t.shape for t in jax.tree.leaves(slab)}
+    assert not cache & set(carries), carries
+
 # ------------------------------------------------------ measured service rate
 def test_service_rate_ema_feeds_gpso_planner(setup):
     """The elastic backend measures per-replica req/tick from finished

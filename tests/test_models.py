@@ -55,22 +55,26 @@ def test_smoke_forward_and_train_step(arch, key):
 
 @pytest.mark.parametrize("arch", ARCH_NAMES)
 def test_prefill_decode_consistency(arch, key):
-    """prefill(S) == forward(S) last logits; decode(S+1th) == forward(S+1)."""
+    """prefill(S) == forward(S) last logits; decode(S+1th) == forward(S+1),
+    and a second decode, reading the state the first one wrote, ==
+    forward(S+2)."""
     c = reduced_no_drop(get_config(arch))
     m = make_model(c, tp=1)
     params = m.init(key, jnp.float32)
     B, S = 2, 32
-    toks = jax.random.randint(key, (B, S + 1), 0, c.vocab_size)
+    toks = jax.random.randint(key, (B, S + 2), 0, c.vocab_size)
     batch = _batch(c, key, B, S, full_tokens=toks[:, :S])
     full, _ = m.forward(params, batch)
     pre, state, pos = m.prefill(params, batch, cache_len=64,
                                 cache_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(full[:, -1]), np.asarray(pre),
                                atol=2e-4, rtol=2e-4)
-    full2, _ = m.forward(params, dict(batch, tokens=toks))
-    dec, _ = m.decode(params, state, toks[:, S:S + 1], jnp.int32(pos))
-    np.testing.assert_allclose(np.asarray(full2[:, -1]), np.asarray(dec),
-                               atol=2e-4, rtol=2e-4)
+    for t in (S, S + 1):
+        full2, _ = m.forward(params, dict(batch, tokens=toks[:, :t + 1]))
+        dec, state = m.decode(params, state, toks[:, t:t + 1],
+                              jnp.int32(pos + t - S))
+        np.testing.assert_allclose(np.asarray(full2[:, -1]), np.asarray(dec),
+                                   atol=2e-4, rtol=2e-4)
 
 
 def test_head_padding_equivalence(key):

@@ -9,6 +9,7 @@ library.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,8 @@ from repro.serving.engine import _init_ops, get_serve_kernels
 HBM_BYTES = 16e9           # one TPU v5e
 # granite-3-8b decode: the one-chip fleet of 2 nodes x 2 replicas x 4 slots
 FLEET, SLOTS, MAX_SEQ = 4, 4, 1024
+# the benchmark's granite-chat cell: 2 nodes x 4 replicas x 4 slots of 512
+CELL_FLEET, CELL_SLOTS, CELL_SEQ = 8, 4, 512
 
 
 @pytest.fixture(scope="module")
@@ -59,22 +62,30 @@ def test_flash_decode_compiles_to_a_tpu_kernel(one_chip, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _granite_fleet(sharding, fleet, slots, max_seq):
+    """The one-chip granite share (20 layers, bf16) as abstract params, a
+    fleet slab of ``fleet`` x ``slots`` x ``max_seq``, its operands and
+    serve kernels."""
+    cfg = get_config("granite-3-8b-1chip")
+    model = make_model(cfg, tp=1)
+    dtype = jnp.dtype(cfg.dtype)
+    params = _on(sharding, jax.eval_shape(
+        lambda k: model.init(k, dtype), jax.random.PRNGKey(0)))
+    slab = _on(sharding, jax.tree.map(
+        lambda c: jax.ShapeDtypeStruct((fleet,) + c.shape, c.dtype),
+        jax.eval_shape(lambda: model.init_serve_state(slots, max_seq,
+                                                      dtype))))
+    ops = _on(sharding, jax.eval_shape(lambda: _init_ops(fleet, slots)))
+    return params, slab, ops, get_serve_kernels(model, max_seq, dtype)
+
+
 @pytest.mark.parametrize("variant", ["afleet", "afleet_prefill"])
 def test_serve_kernel_fits_one_chip(one_chip, variant):
     """The einsum-backend async fleet decode and one fleet prefill bucket
     of the one-chip granite share (20 layers, bf16) compile for the chip
     with arguments + temporaries inside its 16 GB."""
-    cfg = get_config("granite-3-8b-1chip")
-    model = make_model(cfg, tp=1)
-    dtype = jnp.dtype(cfg.dtype)
-    params = _on(one_chip, jax.eval_shape(
-        lambda k: model.init(k, dtype), jax.random.PRNGKey(0)))
-    slab = _on(one_chip, jax.tree.map(
-        lambda c: jax.ShapeDtypeStruct((FLEET,) + c.shape, c.dtype),
-        jax.eval_shape(lambda: model.init_serve_state(SLOTS, MAX_SEQ,
-                                                      dtype))))
-    ops = _on(one_chip, jax.eval_shape(lambda: _init_ops(FLEET, SLOTS)))
-    kernels = get_serve_kernels(model, MAX_SEQ, dtype)
+    params, slab, ops, kernels = _granite_fleet(one_chip, FLEET, SLOTS,
+                                                MAX_SEQ)
     if variant == "afleet":
         lowered = kernels.afleet.lower(params, slab, ops)
     else:
@@ -87,3 +98,21 @@ def test_serve_kernel_fits_one_chip(one_chip, variant):
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes > 8e9       # the weights are real
     assert used < HBM_BYTES, (variant, used)
+
+
+def test_fleet_decode_moves_no_slab(one_chip):
+    """The benchmark cell's fleet decode writes each step's new tokens into
+    the donated slab and moves nothing else of it: no copy or transpose the
+    size of a slab leaf (a cache carried through the layer scan is relaid
+    layer-major in and out, four slab copies per step), and temporaries
+    far below one slab (1.34 GB at these shapes)."""
+    params, slab, ops, kernels = _granite_fleet(one_chip, CELL_FLEET,
+                                                CELL_SLOTS, CELL_SEQ)
+    compiled = kernels.afleet.lower(params, slab, ops).compile()
+    leaves = {",".join(map(str, c.shape)) for c in jax.tree.leaves(slab)}
+    moved = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\{[^}]*\} (?:copy|transpose)\(",
+        compiled.as_text()) if m.group(1) in leaves]
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
