@@ -4,7 +4,10 @@ reading, and the comparison with the reference that decides ``correct``.
 Everything a cell needs is found by name: its entry in ``BENCHMARK.json``
 names a configuration (``bench/configs/<config>.json``), a traffic mix
 (``bench/traffic/<traffic>.json``) and metrics (``bench/metrics/<name>.py``,
-each with ``read(ctx) -> float | None``).
+each with ``read(ctx) -> float | None``). The configuration's ``"model"``
+names its model (``bench/models/<model>/``, by the contract in
+``modelkit.py``): the weights, the reference, the work counts and the sizes
+the program is checked against all come from there.
 
 The window drives the system's own loop: ``ControlPlane.step`` (forecast →
 balance → scale) over an ``ElasticClusterFrontend`` whose replicas decode
@@ -22,16 +25,18 @@ import importlib.util
 import json
 import math
 import os
+import sys
 import time
 from contextlib import nullcontext
 
 import numpy as np
 
-import flops
+import modelkit
 from generator import Traffic, seed_words
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
+MODELS = os.path.join(BENCH, "models")
 TRACE_SECONDS = 4.0        # the traced span at the start of a --trace 1 window
 SAVED_TRACE_S = 0.25       # the part of it ``--save-trace`` keeps
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -72,12 +77,22 @@ def load_cell(name: str) -> dict:
                      [m for m in bench["per_layer"] if _applies(m, name)])
 
 
+def load_config(path: str) -> dict:
+    """A configuration file, which has to name its model."""
+    cfg = load_json(path)
+    if "model" not in cfg:
+        raise SystemExit(f"{path}: no \"model\" key naming a directory "
+                         f"under {MODELS}")
+    return cfg
+
+
 def cell_from(workload: dict, end_to_end: list, per_layer: list) -> dict:
     """A cell from a workload entry (``config``, ``traffic``, ``chips``) and
     the metric entries it reports."""
     return {
         "workload": workload,
-        "config": load_json(BENCH, "configs", workload["config"] + ".json"),
+        "config": load_config(os.path.join(BENCH, "configs",
+                                           workload["config"] + ".json")),
         "traffic": load_json(BENCH, "traffic", workload["traffic"] + ".json"),
         "end_to_end": end_to_end,
         "per_layer": per_layer,
@@ -93,23 +108,35 @@ def load_reader(metric: str):
     return mod.read
 
 
+_models: dict = {}
+
+
+def load_model(cfg: dict):
+    """The package ``<MODELS>/<cfg["model"]>/``, loaded by path once per
+    directory under a module name of its own, so that two models in one
+    process never shadow each other's modules."""
+    path = os.path.join(MODELS, cfg["model"])
+    if path not in _models:
+        name = f"bench_model_{len(_models)}"
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(path, "__init__.py"),
+            submodule_search_locations=[path])
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        spec.loader.exec_module(mod)
+        _models[path] = mod
+    return _models[path]
+
+
 def arch_config(cfg: dict):
     """The program's ``ArchConfig`` for a config file: the registered
     published architecture with the file's ``arch`` overrides, checked
-    against the file's sizes."""
+    against the file's sizes (the model's ``program_sizes``)."""
     from repro.configs import get_config
 
     arch = dataclasses.replace(get_config(cfg["registered"]),
                                name=cfg["name"], **cfg["arch"])
-    want = {"d_model": cfg["hidden_size"], "d_ff": cfg["intermediate_size"],
-            "num_layers": cfg["num_hidden_layers"],
-            "num_heads": cfg["num_attention_heads"],
-            "num_kv_heads": cfg["num_key_value_heads"],
-            "resolved_head_dim": cfg["head_dim"],
-            "vocab_size": cfg["vocab_size"],
-            "tie_embeddings": cfg["tie_word_embeddings"],
-            "rope_theta": cfg["rope_theta"], "norm_eps": cfg["rms_norm_eps"],
-            "dtype": cfg["torch_dtype"]}
+    want = load_model(cfg).program_sizes(cfg)
     got = {k: getattr(arch, k) for k in want}
     if got != want:
         raise SystemExit(f"{cfg['name']}: the program's config {got} is not "
@@ -118,20 +145,16 @@ def arch_config(cfg: dict):
 
 
 def reduced(cell: dict, max_seq: int = 64) -> dict:
-    """The cell at the program's tiny CPU size (``<arch>-reduced``, float32),
-    with the traffic's lengths cut to fit ``max_seq``: for tests off the
-    chip."""
+    """The cell at the program's tiny CPU size (``<arch>-reduced``, float32;
+    the model's ``reduced_config``), with the traffic's lengths cut to fit
+    ``max_seq``: for tests off the chip."""
     from repro.configs import get_config
 
     cfg = cell["config"]
-    a = get_config(cfg["registered"] + "-reduced")
-    small = dict(cfg, registered=cfg["registered"] + "-reduced", arch={},
-                 hidden_size=a.d_model, intermediate_size=a.d_ff,
-                 num_hidden_layers=a.num_layers,
-                 num_attention_heads=a.num_heads,
-                 num_key_value_heads=a.num_kv_heads,
-                 head_dim=a.resolved_head_dim, vocab_size=a.vocab_size,
-                 torch_dtype=a.dtype, fleet=dict(cfg["fleet"], max_seq=max_seq))
+    reg = cfg["registered"] + "-reduced"
+    small = dict(load_model(cfg).reduced_config(cfg, get_config(reg)),
+                 registered=reg, arch={},
+                 fleet=dict(cfg["fleet"], max_seq=max_seq))
     tr = cell["traffic"]
     half = max_seq // 2
 
@@ -479,10 +502,8 @@ def set_up(cell: dict, seed: int, trace: bool, *, log,
                              f" in peaks.json")
         peaks = table[used[0].device_kind]
 
-    import weights
-
     arch = arch_config(cfg)
-    params = jax.block_until_ready(weights.make_params(
+    params = jax.block_until_ready(load_model(cfg).make_params(
         cfg, seed, dtype=jax.numpy.dtype(arch.dtype)))
     log(f"{cfg['name']}: weights from seed {seed} on "
         f"{chips} x {used[0].device_kind}")
@@ -550,7 +571,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         f"replicas per node {[len(n.live) for n in fe.nodes]}")
     memory_peak = _peak_bytes(used)
 
-    ctx = {"cfg": cfg, "peaks": system.peaks, "flops": flops,
+    ctx = {"cfg": cfg, "peaks": system.peaks, "flops": load_model(cfg),
            "records": drv.records, "window": (w0, w1), "setup_s": setup_s,
            "counters": counters, "trace": None}
     result_device = {"platform": system.platform, "kind": used[0].device_kind,
@@ -643,10 +664,9 @@ def check_outputs(cfg: dict, seed: int, finished: list, max_seq: int,
                   tokens: int, *, control: bool = False) -> dict:
     """The widest gap, over the sampled requests' served tokens, by which a
     served token's reference logit lies below the reference's best (in
-    units of that row's standard deviation), and the count of token ids
-    outside the vocabulary."""
-    import reference
-
+    units of that row's standard deviation) under the model's reference,
+    and the count of token ids outside the vocabulary."""
+    logits = load_model(cfg).logits
     picked_reqs = sample(finished, seed, tokens)
     vocab = cfg["vocab_size"]
     bad = sum(not 0 <= t < vocab for r in picked_reqs for t in r.req.output)
@@ -667,9 +687,9 @@ def check_outputs(cfg: dict, seed: int, finished: list, max_seq: int,
             picked[b, p - 1:p - 1 + n] = seq[p:]
             mask[b, p - 1:p - 1 + n] = True
             served += n
-        gaps = reference.served_gaps(cfg, seed, toks, picked,
-                                     control=control,
-                                     dtype=np.dtype(cfg["torch_dtype"]))
+        gaps = modelkit.served_gaps(logits, cfg, seed, toks, picked,
+                                    control=control,
+                                    dtype=np.dtype(cfg["torch_dtype"]))
         widest = max(widest, float(np.max(np.where(mask, gaps, 0.0))))
     limit = float(cfg["limits"]["logit_gap"])
     return {"logit_gap": {"value": widest, "limit": limit},

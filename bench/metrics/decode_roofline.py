@@ -2,8 +2,9 @@
 chip needs for the decode work in the traced window over the programs'
 device time. The work is the weights once per execution on each chip, and
 per generated token its matrix products, attention over the filled cache,
-the filled cache read and its new K/V written (``flops.py``). Sums are
-taken before the larger bound is chosen, which can only lower the share."""
+the filled cache read and its new K/V written (the model's counts,
+``ctx["flops"]``). Sums are taken before the larger bound is chosen, which
+can only lower the share."""
 
 
 def read(ctx):

@@ -32,22 +32,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import weights
+from modelkit import root_key, round_f8
 
-F8 = jnp.float8_e4m3fn
-F8_MAX = 448.0
-
-
-def _round_f8(x):
-    """x rounded to float8 e4m3 under a per-tensor absmax scale, back in
-    float32."""
-    scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / F8_MAX
-    return (x / scale).astype(F8).astype(jnp.float32) * scale
+from . import weights
 
 
 def _mm(spec, a, b, lower):
     if lower:
-        a, b = _round_f8(a), _round_f8(b)
+        a, b = round_f8(a), round_f8(b)
     return jnp.einsum(spec, a, b, precision="highest")
 
 
@@ -129,7 +121,7 @@ def logits(cfg: dict, seed: int, tokens: np.ndarray, *, lower=False,
     (B, S): causal, so the logits at real positions do not see the padding.
     The weights are drawn again from ``seed`` one layer at a time."""
     items = _items(cfg)
-    key = weights.root_key(seed)
+    key = root_key(seed)
     outer = _outer_weights(items, key, dtype)
     h = outer["embed"][jnp.asarray(tokens)].astype(jnp.float32)
     for i in range(cfg["num_hidden_layers"]):
@@ -137,28 +129,3 @@ def logits(cfg: dict, seed: int, tokens: np.ndarray, *, lower=False,
         h = _layer(h, w, cfg_items=items, lower=lower)
         del w
     return _head(h, outer, cfg_items=items, lower=lower)
-
-
-@jax.jit
-def _gap(ref, picked):
-    """How far below the reference's best each picked token's logit lies,
-    in units of the reference row's standard deviation."""
-    best = jnp.max(ref, axis=-1)
-    got = jnp.take_along_axis(ref, picked[..., None], axis=-1)[..., 0]
-    return (best - got) / jnp.std(ref, axis=-1)
-
-
-def served_gaps(cfg: dict, seed: int, tokens: np.ndarray,
-                picked: np.ndarray, *, control=False,
-                dtype=jnp.bfloat16) -> np.ndarray:
-    """Gaps (B, S) at each position of ``tokens``: of the ``picked`` tokens
-    (the served stream shifted by one), or, with ``control``, of the token
-    that the float8 control puts first there."""
-    ref = logits(cfg, seed, tokens, dtype=dtype)
-    if control:
-        low = logits(cfg, seed, tokens, lower=True, dtype=dtype)
-        picked = jnp.argmax(low, axis=-1).astype(jnp.int32)
-        del low
-    out = np.asarray(_gap(ref, jnp.asarray(picked, jnp.int32)))
-    del ref
-    return out

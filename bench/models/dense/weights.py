@@ -15,25 +15,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from generator import seed_words
+from modelkit import normal, root_key
 
 NORM_STD = 0.1
 EMBED_STD = 0.02
-
-
-def root_key(seed: int):
-    words = seed_words(seed)
-    key = jax.random.PRNGKey(words[0])
-    for w in words[1:]:
-        key = jax.random.fold_in(key, w)
-    return key
-
-
-def _normal(key, shape, fan_in, dtype):
-    scale = np.float32(1.0 / np.sqrt(fan_in))
-    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 
 
 def layer(cfg: dict, key, index, dtype=jnp.bfloat16) -> dict:
@@ -45,16 +31,16 @@ def layer(cfg: dict, key, index, dtype=jnp.bfloat16) -> dict:
     return {
         "attn_norm": NORM_STD * jax.random.normal(ks[0], (d,), jnp.float32),
         "attn": {
-            "wq": _normal(ks[1], (d, h, hd), d, dtype),
-            "wk": _normal(ks[2], (d, kv, hd), d, dtype),
-            "wv": _normal(ks[3], (d, kv, hd), d, dtype),
-            "wo": _normal(ks[4], (h, hd, d), h * hd, dtype),
+            "wq": normal(ks[1], (d, h, hd), d, dtype),
+            "wk": normal(ks[2], (d, kv, hd), d, dtype),
+            "wv": normal(ks[3], (d, kv, hd), d, dtype),
+            "wo": normal(ks[4], (h, hd, d), h * hd, dtype),
         },
         "ffn_norm": NORM_STD * jax.random.normal(ks[5], (d,), jnp.float32),
         "mlp": {
-            "w_gate": _normal(ks[6], (d, ff), d, dtype),
-            "w_up": _normal(ks[7], (d, ff), d, dtype),
-            "w_down": _normal(ks[8], (ff, d), ff, dtype),
+            "w_gate": normal(ks[6], (d, ff), d, dtype),
+            "w_up": normal(ks[7], (d, ff), d, dtype),
+            "w_down": normal(ks[8], (ff, d), ff, dtype),
         },
     }
 
@@ -69,7 +55,7 @@ def outer(cfg: dict, key, dtype=jnp.bfloat16) -> dict:
         "final_norm": NORM_STD * jax.random.normal(ks[1], (d,), jnp.float32),
     }
     if not cfg["tie_word_embeddings"]:
-        out["lm_head"] = _normal(ks[2], (d, v), d, dtype)
+        out["lm_head"] = normal(ks[2], (d, v), d, dtype)
     return out
 
 

@@ -268,7 +268,6 @@ def run(workload: str, seed: int, seconds: float, *, log,
 
     import jax
 
-    import flops
     import harness
 
     cell = cell or harness.load_cell(workload)
@@ -290,7 +289,8 @@ def run(workload: str, seed: int, seconds: float, *, log,
         devtrace.save(planes, save_trace, harness.SAVED_TRACE_S)
     red = reduce(planes, int(cell["workload"]["chips"]))
     del planes
-    ctx = {"cfg": s.cfg, "peaks": s.peaks, "flops": flops,
+    ctx = {"cfg": s.cfg, "peaks": s.peaks,
+           "flops": harness.load_model(s.cfg),
            "records": s.drv.records, "window": (tw0, tw1), "setup_s": None,
            "counters": counters, "trace": red}
     out = {"workload": workload, "seed": seed,

@@ -155,6 +155,73 @@ def test_control_reads_above_the_limit():
     assert out["correct"] is True and ctl["correct"] is False
 
 
+CONTRACT = ("make_params", "logits", "weight_bytes", "decode_token_flops",
+            "decode_token_bytes", "prefill_flops", "prefill_bytes",
+            "model_flops_per_token", "program_sizes", "reduced_config")
+
+SPY = """
+CALLS = []
+DENSE = None          # the dense model package, set by the test
+
+
+def _spy(name):
+    def call(*args, **kwargs):
+        CALLS.append(name)
+        return getattr(DENSE, name)(*args, **kwargs)
+    return call
+
+
+for _name in {names!r}:
+    globals()[_name] = _spy(_name)
+"""
+
+
+def test_second_model_drives_a_cell(tmp_path, monkeypatch):
+    """A model directory the harness has never seen, named by the cell's
+    configuration, drives a whole reduced cell: its sizes, weights,
+    reference and work counts all come from that directory."""
+    cell = _cell("granite-chat")
+    dense = harness.load_model(cell["config"])
+    (tmp_path / "spy").mkdir()
+    (tmp_path / "spy" / "__init__.py").write_text(SPY.format(names=CONTRACT))
+    monkeypatch.setattr(harness, "MODELS", str(tmp_path))
+    cell["config"] = dict(cell["config"], model="spy")
+    spy = harness.load_model(cell["config"])
+    assert spy is not dense and spy.__name__ != dense.__name__
+    spy.DENSE = dense
+    ctxs, read = [], harness.load_reader
+    monkeypatch.setattr(harness, "load_reader", lambda name: (
+        lambda ctx: ctxs.append(ctx) or read(name)(ctx)))
+    out = harness.run_cell("granite-chat", SEED, 2.0, False,
+                           t_start=time.perf_counter(), log=lambda m: None,
+                           require_tpu=False, cell=harness.reduced(cell))
+    _check_line(out, "granite-chat")
+    assert {"reduced_config", "program_sizes", "make_params",
+            "logits"} <= set(spy.CALLS)
+    # the model step's readers take their counts from the cell's model
+    ctx = ctxs[0]
+    assert ctx["flops"] is spy
+    busy = {"device_s": 1.0, "count": 1}
+    ctx.update(peaks={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+               trace={"classes": {"decode": busy, "prefill": busy}})
+    for name in ("decode_roofline", "prefill_roofline", "mfu"):
+        assert read(name)(ctx) > 0
+    assert {"weight_bytes", "decode_token_flops", "decode_token_bytes",
+            "prefill_flops", "prefill_bytes"} <= set(spy.CALLS)
+
+
+def test_config_without_model_names_its_file(tmp_path, monkeypatch):
+    cfg = dict(_cell("granite-chat")["config"])
+    del cfg["model"]
+    (tmp_path / "configs").mkdir()
+    path = tmp_path / "configs" / "no-model.json"
+    path.write_text(json.dumps(cfg))
+    monkeypatch.setattr(harness, "BENCH", str(tmp_path))
+    work = {"name": "w", "config": "no-model", "traffic": "chat", "chips": 1}
+    with pytest.raises(SystemExit, match="no-model.json"):
+        harness.cell_from(work, [], [])
+
+
 def test_traffic_same_work_for_every_seed():
     spec = harness.load_cell("granite-chat")["traffic"]
     a, b = Traffic(spec, 49155, 1), Traffic(spec, 49155, 2 ** 33 + 1)

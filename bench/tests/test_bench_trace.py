@@ -92,8 +92,6 @@ def test_readers_silent_without_readings():
 
 def test_step_mfu_beside_prefill_roofline():
     """``mfu.ttft`` is the whole step's share of the peak, as ``mfu``."""
-    import flops
-
     harness, _ = _readers()
     cfg = harness.load_json(BENCH, "configs", "granite-3-8b-1chip.json")
     c = harness.Counters()
@@ -101,7 +99,7 @@ def test_step_mfu_beside_prefill_roofline():
     red = devtrace.reduce(_hand_trace(), chips=1)
     ctx = {"cfg": cfg, "peaks": {"bf16_flops": 197e12,
                                  "hbm_bytes_per_s": 819e9},
-           "flops": flops, "counters": c, "trace": red}
+           "flops": harness.load_model(cfg), "counters": c, "trace": red}
     got = harness.load_reader("mfu.ttft")(ctx)
     assert got == harness.load_reader("mfu")(ctx) and 0 < got < 100
 
