@@ -7,6 +7,9 @@ Families:
   dense   — decoder-only transformer (GQA, SwiGLU)
   moe     — decoder-only transformer with top-k mixture-of-experts FFNs
   hybrid  — Mamba-2 backbone with a shared attention block every `attn_every` layers
+  hybrid_moe — per-layer Mamba-2 or attention mixer (``layer_types``), each
+            followed by a held-expert MoE FFN with a shared expert
+            (granite-4.0-h)
   ssm     — pure Mamba-2 (attention-free)
   vlm     — dense LM backbone consuming stub patch embeddings + text tokens
   audio   — encoder-decoder transformer (Whisper-style); conv/mel frontend stubbed
@@ -21,7 +24,7 @@ from typing import Optional
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                      # dense | moe | hybrid | ssm | vlm | audio
+    family: str      # dense | moe | hybrid | hybrid_moe | ssm | vlm | audio
     num_layers: int
     d_model: int
     num_heads: int                   # query heads (0 for attention-free)
@@ -43,6 +46,8 @@ class ArchConfig:
     moe_every: int = 1               # MoE FFN every k-th layer (others dense)
     moe_shared_expert: bool = False  # always-on shared expert on MoE layers
     capacity_factor: float = 1.25
+    moe_held: int = 0                # experts this chip holds (0 -> all)
+    moe_shared_d_ff: int = 0         # shared expert's own width (hybrid_moe)
     # --- SSM (Mamba-2) ---
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -52,6 +57,13 @@ class ArchConfig:
     ssm_chunk: int = 256
     # --- hybrid ---
     attn_every: int = 0              # shared attn block every k layers (0 = never)
+    # --- hybrid_moe: the mixer of each layer, "mamba" or "attention" ---
+    layer_types: tuple = ()
+    # --- muP scalars (hybrid_moe; 1 and 0 leave a block as it was) ---
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0  # softmax scale (0 -> 1/sqrt(head_dim))
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0      # logits are divided by it
     # --- encoder-decoder (audio) ---
     is_encoder_decoder: bool = False
     encoder_layers: int = 0
@@ -88,11 +100,30 @@ class ArchConfig:
     def uses_moe(self) -> bool:
         return self.num_experts > 0
 
+    @property
+    def experts_held(self) -> int:
+        return self.moe_held or self.num_experts
+
+    @property
+    def mixers(self) -> tuple:
+        """The mixer of each of the ``num_layers`` layers (hybrid_moe)."""
+        return tuple(self.layer_types[:self.num_layers])
+
+    @property
+    def period(self) -> int:
+        """Length of the shortest repeating unit of ``layer_types``."""
+        t = self.layer_types
+        return next(p for p in range(1, len(t) + 1)
+                    if all(t[i] == t[i % p] for i in range(len(t))))
+
     # ------------------------------------------------------------ param counts
     def param_count(self) -> int:
         """Analytic parameter count (logical, unpadded). Used by tests + roofline."""
         d, v = self.d_model, self.vocab_size
         emb = v * d * (1 if self.tie_embeddings else 2)
+        if self.family == "hybrid_moe":
+            return emb + d + sum(self._hybrid_moe_layer(m)
+                                 for m in self.mixers)
         if self.family in ("ssm", "hybrid"):
             n = emb
             di, N, G, H = self.d_inner, self.ssm_state, self.ssm_groups, self.ssm_heads
@@ -135,6 +166,23 @@ class ArchConfig:
                 n += dense_mlp
         return n
 
+    def _hybrid_moe_layer(self, mixer: str) -> int:
+        """One hybrid_moe layer: its mixer, its norms and its MoE FFN (all
+        ``num_experts``, the router and the shared expert)."""
+        d, hd = self.d_model, self.resolved_head_dim
+        if mixer == "mamba":
+            di, N, G, H = (self.d_inner, self.ssm_state, self.ssm_groups,
+                           self.ssm_heads)
+            conv_ch = di + 2 * G * N
+            mix = (d * (2 * di + 2 * G * N + H) + (self.ssm_conv_width + 1)
+                   * conv_ch + di * d + di + 3 * H)
+        else:
+            mix = 2 * d * (self.num_heads + self.num_kv_heads) * hd
+        e_ff = self.moe_d_ff or self.d_ff
+        moe = (d * self.num_experts + self.num_experts * 3 * d * e_ff
+               + 3 * d * self.moe_shared_d_ff)
+        return mix + moe + 2 * d
+
     @property
     def max_decoder_pos(self) -> int:
         # Learned decoder positions sized to the assigned shape set (the real
@@ -156,7 +204,19 @@ class ArchConfig:
 
     # ------------------------------------------------------------------ reduced
     def reduced(self) -> "ArchConfig":
-        """Tiny same-family config for CPU smoke tests."""
+        """Tiny same-family config for CPU smoke tests. A hybrid_moe config
+        keeps one whole period of its layer pattern and holds fewer experts
+        than its router routes over."""
+        if self.family == "hybrid_moe":
+            return dataclasses.replace(
+                self, name=self.name + "-reduced", dtype="float32",
+                num_layers=min(self.num_layers, self.period), d_model=128,
+                num_heads=4, num_kv_heads=2, head_dim=32, d_ff=256,
+                moe_d_ff=0, moe_shared_d_ff=384, vocab_size=512,
+                max_seq_len=512, num_experts=8,
+                num_experts_per_tok=min(self.num_experts_per_tok, 3),
+                moe_held=4, ssm_state=16, ssm_head_dim=32,
+                ssm_chunk=32)
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",

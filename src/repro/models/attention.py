@@ -64,9 +64,14 @@ def _mask_pad_heads(ctx, dims: PaddedDims):
     return ctx * m[None, None, :, :, None].astype(ctx.dtype)
 
 
-def _attend(q, k, v, q_pos, k_pos, causal: bool):
+def _scale(scale, head_dim: int):
+    """The softmax scale: ``scale``, or 1/sqrt(head_dim) when it is None."""
+    return 1.0 / np.sqrt(head_dim) if scale is None else scale
+
+
+def _attend(q, k, v, q_pos, k_pos, causal: bool, scale=None):
     """q: (B,Cq,G,qpg,hd); k,v: (B,T,G,hd); positions are int32 vectors."""
-    scale = 1.0 / np.sqrt(q.shape[-1])
+    scale = _scale(scale, q.shape[-1])
     scores = jnp.einsum("bsgqh,btgh->bgqst", q, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
@@ -77,7 +82,7 @@ def _attend(q, k, v, q_pos, k_pos, causal: bool):
     return ctx
 
 
-def _attend_maybe_chunked(q, k, v, positions, k_pos, causal):
+def _attend_maybe_chunked(q, k, v, positions, k_pos, causal, scale=None):
     """Query-chunked attention when S is long; exact either way.
 
     Non-multiple S is zero-padded on the query axis (padded rows are computed
@@ -85,7 +90,7 @@ def _attend_maybe_chunked(q, k, v, positions, k_pos, causal):
     are exact)."""
     B, S = q.shape[:2]
     if S < CHUNK_THRESHOLD:
-        return _attend(q, k, v, positions, k_pos, causal)
+        return _attend(q, k, v, positions, k_pos, causal, scale)
     S_pad = ((S + Q_CHUNK - 1) // Q_CHUNK) * Q_CHUNK
     if S_pad != S:
         pad = S_pad - S
@@ -97,15 +102,16 @@ def _attend_maybe_chunked(q, k, v, positions, k_pos, causal):
 
     def body(_, qc_pc):
         qc, pc = qc_pc
-        return None, _attend(qc, k, v, pc, k_pos, causal)
+        return None, _attend(qc, k, v, pc, k_pos, causal, scale)
 
     _, ctx = jax.lax.scan(body, None, (q_chunks, pos_chunks))
     return ctx.swapaxes(0, 1).reshape(B, S_pad, *q.shape[2:])[:, :S]
 
 
 def attention(params, x, dims: PaddedDims, *, positions=None, rope_theta=0.0,
-              causal=True, kv_x=None, shard_fn=None):
-    """Full-sequence (training / prefill) attention. Returns (B,S,d_model)."""
+              causal=True, kv_x=None, shard_fn=None, scale=None):
+    """Full-sequence (training / prefill) attention. Returns (B,S,d_model).
+    ``scale`` is the softmax scale (None: 1/sqrt(head_dim))."""
     B, S, _ = x.shape
     kv_x = x if kv_x is None else kv_x
     T = kv_x.shape[1]
@@ -118,7 +124,7 @@ def attention(params, x, dims: PaddedDims, *, positions=None, rope_theta=0.0,
         k = apply_rope(k, k_pos, rope_theta)
     if shard_fn is not None:
         q, k, v = shard_fn(q, "qkv"), shard_fn(k, "kv"), shard_fn(v, "kv")
-    ctx = _attend_maybe_chunked(q, k, v, positions, k_pos, causal)
+    ctx = _attend_maybe_chunked(q, k, v, positions, k_pos, causal, scale)
     ctx = _mask_pad_heads(ctx, dims)
     ctx = ctx.reshape(B, S, dims.n_q, -1)
     return jnp.einsum("bsnh,nhd->bsd", ctx, params["wo"])
@@ -133,8 +139,9 @@ def init_kv_cache(batch: int, max_len: int, dims: PaddedDims, head_dim: int,
 
 
 def prefill_attention(params, x, dims: PaddedDims, cache, *, rope_theta=0.0,
-                      shard_fn=None):
-    """Attention that also fills the KV cache for positions [0, S)."""
+                      shard_fn=None, scale=None):
+    """Attention that also fills the KV cache for positions [0, S).
+    ``scale`` is the softmax scale (None: 1/sqrt(head_dim))."""
     B, S, _ = x.shape
     q, k, v = _project_qkv(params, x, x, dims)
     positions = jnp.arange(S, dtype=jnp.int32)
@@ -145,14 +152,15 @@ def prefill_attention(params, x, dims: PaddedDims, cache, *, rope_theta=0.0,
         "k": jax.lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), 0, 1),
         "v": jax.lax.dynamic_update_slice_in_dim(cache["v"], v.astype(cache["v"].dtype), 0, 1),
     }
-    ctx = _attend_maybe_chunked(q, k, v, positions, positions, causal=True)
+    ctx = _attend_maybe_chunked(q, k, v, positions, positions, causal=True,
+                                scale=scale)
     ctx = _mask_pad_heads(ctx, dims)
     ctx = ctx.reshape(B, S, dims.n_q, -1)
     return jnp.einsum("bsnh,nhd->bsd", ctx, params["wo"]), cache
 
 
 def chunk_prefill_attention(params, x, dims: PaddedDims, cache, positions,
-                            lengths, *, rope_theta=0.0):
+                            lengths, *, rope_theta=0.0, scale=None):
     """Continue a prefill one chunk at a time against an existing KV cache.
 
     x: (B,C,d) chunk activations; ``positions`` (B,C) are per-row absolute
@@ -178,7 +186,7 @@ def chunk_prefill_attention(params, x, dims: PaddedDims, cache, positions,
     vc = cache["v"].at[rows, wpos].set(v.astype(cache["v"].dtype),
                                        mode="drop")
     k_pos = jnp.arange(S, dtype=jnp.int32)
-    scale = 1.0 / np.sqrt(q.shape[-1])
+    scale = _scale(scale, q.shape[-1])
     scores = jnp.einsum("bsgqh,btgh->bgqst", q, kc.astype(q.dtype),
                         preferred_element_type=jnp.float32) * scale
     mask = (k_pos[None, None, :] <= positions[:, :, None])[:, None, None]
@@ -220,7 +228,7 @@ def write_kv(k_cache, v_cache, k_new, v_new, pos):
 
 
 def decode_attend(params, q, k_cache, v_cache, pos, dims: PaddedDims,
-                  backend: str = "einsum"):
+                  backend: str = "einsum", scale=None):
     """Read-only attention of a single-token q over cache[0..pos].
 
     ``backend="pallas"`` routes through the flash-decode kernel
@@ -231,15 +239,19 @@ def decode_attend(params, q, k_cache, v_cache, pos, dims: PaddedDims,
     transpose here is the price of keeping one cache layout for both
     backends (a TPU deployment would store the pool kernel-native). Runs in
     interpret mode off-TPU so the CPU parity tests exercise the same code
-    path."""
+    path. ``scale`` is the softmax scale (None: 1/sqrt(head_dim), the only
+    one the kernel takes)."""
     if backend == "pallas":
+        if scale is not None:
+            raise ValueError("the flash-decode kernel scales by "
+                             "1/sqrt(head_dim) only")
         return _decode_attend_pallas(params, q, k_cache, v_cache, pos, dims)
     B = q.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     per_seq = pos.ndim == 1
     T = k_cache.shape[1]
     k_pos = jnp.arange(T, dtype=jnp.int32)
-    scale = 1.0 / np.sqrt(q.shape[-1])
+    scale = _scale(scale, q.shape[-1])
     scores = jnp.einsum("bsgqh,btgh->bgqst", q, k_cache.astype(q.dtype),
                         preferred_element_type=jnp.float32) * scale
     if per_seq:

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeConfig
-from repro.models import encdec, lm, ssm_lm
+from repro.models import encdec, hybrid_moe, lm, ssm_lm
 from repro.models.dims import PaddedDims, padded_dims
 from repro.models.layers import cross_entropy
 
@@ -62,6 +62,8 @@ class Model:
             return lm.lm_forward(params, batch, c, d, **kw)
         if c.family in ("ssm", "hybrid"):
             return ssm_lm.ssm_forward(params, batch, c, d, **kw)
+        if c.family == "hybrid_moe":
+            return hybrid_moe.hmoe_forward(params, batch, c, d, **kw)
         if c.family == "audio":
             return encdec.encdec_forward(params, batch, c, d, **kw)
         raise ValueError(c.family)
@@ -132,6 +134,9 @@ class Model:
             return lm.lm_init_cache(c, d, batch, cache_len, cache_dtype)
         if c.family in ("ssm", "hybrid"):
             return ssm_lm.ssm_init_state(c, d, batch, cache_len, cache_dtype)
+        if c.family == "hybrid_moe":
+            return hybrid_moe.hmoe_init_state(c, d, batch, cache_len,
+                                              cache_dtype)
         if c.family == "audio":
             return encdec.encdec_init_state(c, d, batch, cache_len, cache_dtype)
         raise ValueError(c.family)
@@ -148,12 +153,38 @@ class Model:
             return ssm_lm.ssm_prefill(params, batch, c, d, cache_len=cache_len,
                                       cache_dtype=cache_dtype,
                                       shard_fn=shard_fn)
+        if c.family == "hybrid_moe":
+            return hybrid_moe.hmoe_prefill(params, batch, c, d,
+                                           cache_len=cache_len,
+                                           cache_dtype=cache_dtype,
+                                           shard_fn=shard_fn)
         if c.family == "audio":
             return encdec.encdec_prefill(params, batch, c, d,
                                          cache_len=cache_len,
                                          cache_dtype=cache_dtype,
                                          shard_fn=shard_fn)
         raise ValueError(c.family)
+
+    def prefill_into(self, params, batch, slab, rows, slots, cache_len: int,
+                     cache_dtype=jnp.bfloat16):
+        """Prefill whose rows write their serve state straight into a fleet
+        slab (every leaf with a leading fleet axis before the per-replica
+        layout): row k's state goes to (fleet row ``rows[k]``, slot
+        ``slots[k]``), an out-of-bounds row's is dropped. Returns (logits,
+        slab, pos). ``hybrid_moe`` writes each layer's state as the layer
+        scan reaches it; the others prefill, then scatter."""
+        c, d = self.cfg, self.dims
+        if c.family == "hybrid_moe":
+            return hybrid_moe.hmoe_prefill_into(params, batch, slab, rows,
+                                                slots, c, d)
+        logits, small, pos = self.prefill(params, batch, cache_len=cache_len,
+                                          cache_dtype=cache_dtype)
+
+        def scatter(s, sm):
+            return s.at[rows, :, slots].set(
+                sm.swapaxes(0, 1).astype(s.dtype), mode="drop")
+
+        return logits, jax.tree.map(scatter, slab, small), pos
 
     def prefill_chunk(self, params, state, tokens, offsets, lengths,
                       shard_fn=None):
@@ -183,12 +214,22 @@ class Model:
         path. SSM/audio families carry no KV decode loop and ignore it.
         ``commit`` (scalar bool) False computes the step but returns
         ``state`` as it was: the KV families drop their token writes, the
-        others select the old state back."""
+        others select the old state back. ``hybrid_moe`` runs as a fleet of
+        one (``decode_fleet``)."""
         c, d = self.cfg, self.dims
         if c.family in ("dense", "moe", "vlm"):
             return lm.lm_decode(params, state, tokens, pos, c, d,
                                 shard_fn=shard_fn,
                                 attn_backend=attn_backend, commit=commit)
+        if c.family == "hybrid_moe":
+            B = tokens.shape[0]
+            rows = None if commit is None else jnp.reshape(commit, (1,))
+            logits, new, _ = hybrid_moe.hmoe_decode(
+                params, jax.tree.map(lambda t: t[None], state),
+                tokens[None],
+                jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))[None],
+                c, d, rows=rows)
+            return logits[0], jax.tree.map(lambda t: t[0], new)
         if c.family in ("ssm", "hybrid"):
             logits, new = ssm_lm.ssm_decode(params, state, tokens, pos, c, d,
                                             shard_fn=shard_fn)
@@ -201,6 +242,25 @@ class Model:
             new = jax.tree.map(lambda o, n: jnp.where(commit, n, o), state,
                                new)
         return logits, new
+
+    def decode_fleet(self, params, slab, tokens, pos, rows=None,
+                     attn_backend=None):
+        """One decode step of a stacked fleet: every leaf of ``slab`` has a
+        leading fleet axis F before the per-replica serve state; tokens
+        (F, B, 1); pos (F, B); ``rows`` (F,) bool marks the rows whose step
+        commits (None: all). Returns (logits (F, B, V), slab, counts), where
+        ``counts`` maps a name to a per-slot (F, B, ...) integer count of
+        what the step did (empty for families that count nothing).
+        ``hybrid_moe`` steps the stacked state directly; the others map
+        ``decode`` over the fleet axis."""
+        if self.cfg.family == "hybrid_moe":
+            return hybrid_moe.hmoe_decode(params, slab, tokens, pos,
+                                          self.cfg, self.dims, rows=rows)
+        logits, slab = jax.vmap(
+            lambda c, t, q, r: self.decode(params, c, t, q,
+                                           attn_backend=attn_backend,
+                                           commit=r))(slab, tokens, pos, rows)
+        return logits, slab, {}
 
     # ------------------------------------------------------------ dry-run IO
     def input_specs(self, shape: ShapeConfig, act_dtype=jnp.bfloat16,
@@ -236,6 +296,8 @@ def _init_params(cfg: ArchConfig, dims: PaddedDims, key, dtype, sharding):
         params = lm.init_lm(key, cfg, dims, dtype)
     elif cfg.family in ("ssm", "hybrid"):
         params = ssm_lm.init_ssm_lm(key, cfg, dims, dtype)
+    elif cfg.family == "hybrid_moe":
+        params = hybrid_moe.init_hybrid_moe(key, cfg, dims, dtype)
     elif cfg.family == "audio":
         params = encdec.init_encdec(key, cfg, dims, dtype)
     else:

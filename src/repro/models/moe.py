@@ -8,6 +8,11 @@ Switch/GShard semantics. Aux load-balance loss included.
 Sharding: tokens shard over the data axes, expert hidden dim over "model"
 (TP-in-expert). With ``expert_sharding='data'`` and E % |data| == 0 the expert
 dim itself shards over data (EP) — GSPMD inserts the all-to-all.
+
+``moe_held_apply`` is the serving layer of one chip's share of the experts:
+told which contiguous block it holds, it routes over all of them, computes
+only the (token, held expert) pairs by a grouped matmul and drops nothing, so
+a padded batch gives what the exact-length batch gives.
 """
 from __future__ import annotations
 
@@ -137,3 +142,76 @@ def moe_dense_oracle(params, x, *, num_experts: int, top_k: int,
     if "shared" in params:
         y = y + _dense_ffn(params["shared"], xt, activation).astype(jnp.float32)
     return y.reshape(B, S, d).astype(x.dtype)
+
+
+def moe_held_apply(params, x, *, num_experts: int, top_k: int,
+                   first: int = 0, activation: str = "swiglu",
+                   rows: int = 0):
+    """One chip's part of an MoE FFN. x: (T, d) -> (y (T, d), routed (T,
+    held) int32).
+
+    ``params["w_gate"]`` etc. hold experts ``first .. first + held - 1`` of
+    ``num_experts``; the router (d, num_experts) routes over all of them,
+    top ``top_k`` with the softmax taken over the chosen ones. Every (token,
+    held expert) pair is computed and none is dropped: the pairs are sorted
+    by expert and run through ``jax.lax.ragged_dot`` (a grouped matmul whose
+    rows past a pass's pairs are selected away: the TPU leaves them
+    unwritten, and a zero gate times a stale Inf is NaN), in passes of
+    ``rows`` sorted pairs
+    for as many passes as the pairs fill (0: one pass over ``T * min(top_k,
+    held)`` rows, the most the tokens can bring; a fixed shape, so it also
+    differentiates). A row's result does not depend on the other rows. A
+    shared expert (``params["shared"]``) is added for every token.
+    ``routed[t, e]`` is 1 where token t was routed to held expert e."""
+    T, d = x.shape
+    held = params["w_gate"].shape[0]
+    K = top_k
+    logits = x.astype(jnp.float32) @ params["router"].astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)                        # (T,E)
+    top_p, top_i = jax.lax.top_k(probs, K)                         # (T,K)
+    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    local = top_i - first
+    mine = (local >= 0) & (local < held)
+    key = jnp.where(mine, local, held).reshape(T * K)              # held: none
+    most = T * min(K, held)
+    C = min(rows or most, most)
+    passes = -(-most // C)
+    order = jnp.argsort(key, stable=True)
+    order = jnp.concatenate([order, jnp.zeros(passes * C - T * K
+                                              if passes * C > T * K else 0,
+                                              order.dtype)])[:passes * C]
+    gate_all = jnp.where(key[order] < held, top_p.reshape(T * K)[order], 0.0)
+    sizes = jnp.sum(jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+
+    def one_pass(c, out):
+        lo = c * C
+        idx = jax.lax.dynamic_slice_in_dim(order, lo, C)
+        gate = jax.lax.dynamic_slice_in_dim(gate_all, lo, C)
+        n = jnp.clip(ends - lo, 0, C) - jnp.clip(starts - lo, 0, C)
+        tok = idx // K
+        xs = x[tok]
+        g = jax.lax.ragged_dot(xs, params["w_gate"], n)
+        if activation == "swiglu":
+            h = silu(g) * jax.lax.ragged_dot(xs, params["w_up"], n)
+        else:
+            h = gelu(g)
+        y = jax.lax.ragged_dot(h.astype(x.dtype), params["w_down"], n)
+        y = jnp.where((jnp.arange(C) < jnp.sum(n))[:, None],
+                      y.astype(jnp.float32) * gate[:, None], 0.0)
+        return out.at[tok].add(y)
+
+    out = jnp.zeros((T, d), jnp.float32)
+    if passes == 1:
+        out = one_pass(0, out)
+    else:                              # only as many passes as pairs fill
+        out = jax.lax.while_loop(
+            lambda co: co[0] * C < ends[-1],
+            lambda co: (co[0] + 1, one_pass(co[0], co[1])), (0, out))[1]
+    if "shared" in params:
+        out = out + _dense_ffn(params["shared"], x, activation).astype(
+            jnp.float32)
+    routed = jnp.sum(jax.nn.one_hot(jnp.where(mine, local, held), held,
+                                    dtype=jnp.int32), axis=1)
+    return out.astype(x.dtype), routed

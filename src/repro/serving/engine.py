@@ -151,6 +151,7 @@ elastic frontend that plugs into the unified control plane lives in
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import time
 from collections import deque
@@ -170,10 +171,11 @@ from repro.workload.trace import DEFAULT_TIERS, TierSet
 # families whose prefill accepts per-row ``lengths`` (bucketed prompts are
 # exact). moe is deliberately absent: expert capacity scales with the padded
 # bucket, so drops can differ from the exact-length oracle (see module
-# docstring). audio prefill is driven by encoder frames and stays
-# exact-length; vlm requests carry patch-embed extras, which take the
-# single-admit path below (batching per-request extras is future work).
-_BUCKET_FAMILIES = ("dense", "ssm", "hybrid")
+# docstring); hybrid_moe's held-expert layer drops nothing, so it buckets.
+# audio prefill is driven by encoder frames and stays exact-length; vlm
+# requests carry patch-embed extras, which take the single-admit path below
+# (batching per-request extras is future work).
+_BUCKET_FAMILIES = ("dense", "ssm", "hybrid", "hybrid_moe")
 # families with a chunked-prefill continuation kernel (cache-offset attention
 # for dense, carried ssm/conv state for ssm/hybrid). moe is excluded by
 # default for the same capacity reason as bucketing.
@@ -316,8 +318,10 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
                     held=None):
         """One dispatch for a stacked fleet. slab: cache pytree with a
         leading fleet axis; toks/pos/rem/eos/active: (F, B). Returns the
-        next greedy token per slot, the fused retire mask, and the advanced
-        slab. The retire rule is the exact device twin of the host rule in
+        next greedy token per slot, the fused retire mask, the model's
+        step counts (``Model.decode_fleet``) summed over the slots that
+        stepped, and the advanced slab. The retire rule is the exact
+        device twin of the host rule in
         ``ReplicaEngine.finish_step``: after appending this token a slot is
         done when it reached max_new_tokens (rem <= 1), emitted EOS, or its
         next write index would hit the end of the cache. ``held``
@@ -331,19 +335,23 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
         if held is not None:
             hrows, hslots = held
             kept = jax.tree.map(lambda s: s[hrows, :, hslots], slab)
-        logits, new_slab = jax.vmap(
-            lambda c, t, q, r: _decode(p, c, t, q, r))(
-                slab, toks[..., None], pos, rows)
+        logits, new_slab, counts = model.decode_fleet(
+            p, slab, toks[..., None], pos, rows, attn_backend=attn_backend)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         done = active & ((rem <= 1) | (nxt == eos)
                          | (pos + 1 >= max_seq - 1))
+        stepped = active
         if rows is not None:
             done = done & rows[:, None]
+            stepped = active & rows[:, None]
+        counts = {n: jnp.sum(jnp.where(
+            stepped.reshape(stepped.shape + (1,) * (c.ndim - 2)), c, 0),
+            axis=(0, 1)) for n, c in counts.items()}
         if held is not None:
             new_slab = jax.tree.map(
                 lambda s, h: s.at[hrows, :, hslots].set(h, mode="drop"),
                 new_slab, kept)
-        return nxt, done, new_slab
+        return nxt, done, counts, new_slab
 
     def _fleet_fn(p, slab, toks, pos, rem, eos, active):
         _count("fleet")
@@ -371,11 +379,11 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
         """One async decode micro-step: the operands live on device (``ops``)
         and advance inside the dispatch — the device twin of
         ``ReplicaEngine.apply_decode``. Returns the small sync payload
-        (next token, fused retire mask, stepped mask) plus the advanced
-        slab and operands; nothing blocks on the host."""
-        nxt, done, slab = _fleet_core(p, slab, ops["toks"], ops["pos"],
-                                      ops["rem"], ops["eos"], ops["active"],
-                                      rows=rows, held=held)
+        (next token, fused retire mask, stepped mask, step counts) plus the
+        advanced slab and operands; nothing blocks on the host."""
+        nxt, done, counts, slab = _fleet_core(
+            p, slab, ops["toks"], ops["pos"], ops["rem"], ops["eos"],
+            ops["active"], rows=rows, held=held)
         stepped = ops["active"] if rows is None else \
             ops["active"] & rows[:, None]
         inc = stepped.astype(jnp.int32)
@@ -386,7 +394,7 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
             "eos": ops["eos"],
             "active": ops["active"] & ~done,
         }
-        return nxt, done, stepped, slab, ops
+        return nxt, done, stepped, counts, slab, ops
 
     def _afleet_fn(p, slab, ops):
         _count("afleet")
@@ -410,17 +418,18 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
             the async core (the retire rule is already the device twin of
             the host rule, so EOS/max-tokens/cache-full compose exactly —
             a slot retired at micro-step k is inactive for k+1..K-1). Syncs
-            one (K, F, B) token/retire/stepped block."""
+            one (K, F, B) token/retire/stepped block and K steps' counts."""
             _count("afleet_block")
 
             def micro(carry, _):
                 slab, ops = carry
-                nxt, done, stepped, slab, ops = _afleet_core(p, slab, ops)
-                return (slab, ops), (nxt, done, stepped)
+                nxt, done, stepped, counts, slab, ops = _afleet_core(
+                    p, slab, ops)
+                return (slab, ops), (nxt, done, stepped, counts)
 
-            (slab, ops), (nxt, done, stepped) = jax.lax.scan(
+            (slab, ops), (nxt, done, stepped, counts) = jax.lax.scan(
                 micro, (slab, ops), None, length=K)
-            return nxt, done, stepped, slab, ops
+            return nxt, done, stepped, counts, slab, ops
         return _afleet_block_fn
 
     def _ops_admit(ops, rows, slots, first, plen, rems, eoss):
@@ -443,16 +452,10 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
         admitted rows activate in the device operands so the same tick's
         decode dispatch consumes their first token without a host sync."""
         _count("afleet_prefill")
-        logits, small, plen = model.prefill(
-            p, {"tokens": toks, "lengths": lens}, cache_len=max_seq,
-            cache_dtype=cache_dtype)
+        logits, slab, plen = model.prefill_into(
+            p, {"tokens": toks, "lengths": lens}, slab, rows, slots,
+            cache_len=max_seq, cache_dtype=cache_dtype)
         first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-        def scatter(s, sm):
-            return s.at[rows, :, slots].set(
-                sm.swapaxes(0, 1).astype(s.dtype), mode="drop")
-
-        slab = jax.tree.map(scatter, slab, small)
         ops = _ops_admit(ops, rows, slots, first, plen.astype(jnp.int32),
                          rems, eoss)
         return first, slab, ops
@@ -488,17 +491,11 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
         Returns the greedy first token and per-row prompt length, (K,)
         each."""
         _count("fleet_prefill")
-        logits, small, plen = model.prefill(
-            p, {"tokens": toks, "lengths": lens}, cache_len=max_seq,
-            cache_dtype=cache_dtype)
+        logits, slab, plen = model.prefill_into(
+            p, {"tokens": toks, "lengths": lens}, slab, rows, slots,
+            cache_len=max_seq, cache_dtype=cache_dtype)
         first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-        def scatter(s, sm):
-            return s.at[rows, :, slots].set(
-                sm.swapaxes(0, 1).astype(s.dtype), mode="drop")
-
-        return first, plen.astype(jnp.int32), jax.tree.map(scatter, slab,
-                                                           small)
+        return first, plen.astype(jnp.int32), slab
 
     def _chunk_core(state, toks, offs, lens, fresh, p):
         """Shared chunk step on gathered per-slot state (leaves (L, K, ...)):
@@ -567,6 +564,17 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
     k._block_factory = _make_block_fn
     cache[key] = k
     return k
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _slab_put(slab, at, rows):
+    """Every slab leaf with ``rows`` (the leaf's trailing shape, any number
+    of leading rows) written from fleet row ``at`` on. Donated and jitted
+    so that the update is made in place: an eager ``.at[].set`` copies the
+    whole slab and holds both until it returns."""
+    return jax.tree.map(
+        lambda s, r: jax.lax.dynamic_update_slice_in_dim(
+            s, r.astype(s.dtype), at, 0), slab, rows)
 
 
 def _pack_chunk_rows(rows, chunk_len: int):
@@ -780,6 +788,10 @@ class FleetGroup:
         self.syncs = 0              # blocking host syncs performed
         self.sync_wait = 0.0        # seconds spent blocked on device results
         self._block_credit = 0      # ticks already covered by a decode block
+        # the model's decode step counts (``Model.decode_fleet``) summed
+        # over every stepped slot, at the sync that fetches the tokens:
+        # for hybrid_moe ``expert_tokens`` (held,) and ``routed_pairs``
+        self.decode_counts: dict = {}
         self._kernels = get_serve_kernels(model, max_seq, cache_dtype,
                                           attn_backend)
 
@@ -843,8 +855,7 @@ class FleetGroup:
                 if self.async_mode:
                     self.ops = _init_ops(new_cap, self.max_batch)
             else:
-                self.slab = jax.tree.map(lambda n, s: n.at[:self.cap].set(s),
-                                         slab, self.slab)
+                self.slab = _slab_put(slab, 0, self.slab)
                 if self.async_mode:
                     grow = lambda s: jnp.concatenate(
                         [s, jnp.zeros((new_cap - self.cap,) + s.shape[1:],
@@ -852,7 +863,7 @@ class FleetGroup:
                     self.ops = jax.tree.map(grow, self.ops)
             self.cap = new_cap
             if self.mesh is not None:
-                # re-pin after (re)allocation: the eager copy above and the
+                # re-pin after (re)allocation: the slab update above and the
                 # operands' concatenate need not keep the fleet sharding,
                 # and GSPMD must row-partition every subsequent dispatch
                 self.slab = self._place_slab(self.slab)
@@ -862,13 +873,13 @@ class FleetGroup:
             # a fresh replica: its slots are empty, so its row only has to
             # be cleared of a former member's state (never built whole
             # elsewhere first)
-            self.slab = jax.tree.map(
-                lambda s: s.at[row].set(jnp.zeros((), s.dtype)), self.slab)
+            cache = jax.tree.map(
+                lambda s: jnp.zeros((1,) + s.shape[1:], s.dtype), self.slab)
         else:
-            cache = eng.cache if self.mesh is None \
-                else self._replicated(eng.cache)
-            self.slab = jax.tree.map(lambda s, c: s.at[row].set(c),
-                                     self.slab, cache)
+            cache = jax.tree.map(lambda c: c[None], eng.cache if
+                                 self.mesh is None else
+                                 self._replicated(eng.cache))
+        self.slab = _slab_put(self.slab, row, cache)
         if self.async_mode:
             self._seed_ops_row(row, eng)
         eng.cache = None
@@ -912,10 +923,12 @@ class FleetGroup:
                     eng.cache, jax.tree.leaves(eng.params)[0].sharding)
         last = self.members.pop()
         if last is not eng:          # backfill the hole with the last row
-            backfill = lambda s: s.at[row].set(s[len(self.members)])
-            self.slab = jax.tree.map(backfill, self.slab)
+            n = len(self.members)
+            self.slab = _slab_put(self.slab, row, jax.tree.map(
+                lambda s: s[n:n + 1], self.slab))
             if self.async_mode:
-                self.ops = jax.tree.map(backfill, self.ops)
+                self.ops = jax.tree.map(lambda s: s.at[row].set(s[n]),
+                                        self.ops)
             last._fleet_row = row
             self.members[row] = last
         eng._fleet, eng._fleet_row = None, -1
@@ -1112,22 +1125,23 @@ class FleetGroup:
                 hrows[i], hslots[i] = f, s
         if len(movers) == len(self.members):
             if held:
-                nxt, done, self.slab = self._kernels.fleet_hold(
+                nxt, done, counts, self.slab = self._kernels.fleet_hold(
                     self.params, self.slab, toks, pos, rem, eos, active,
                     hrows, hslots)
             else:
-                nxt, done, self.slab = self._kernels.fleet(
+                nxt, done, counts, self.slab = self._kernels.fleet(
                     self.params, self.slab, toks, pos, rem, eos, active)
         elif held:
-            nxt, done, self.slab = self._kernels.fleet_masked_hold(
+            nxt, done, counts, self.slab = self._kernels.fleet_masked_hold(
                 self.params, self.slab, toks, pos, rem, eos, active, rows,
                 hrows, hslots)
         else:
-            nxt, done, self.slab = self._kernels.fleet_masked(
+            nxt, done, counts, self.slab = self._kernels.fleet_masked(
                 self.params, self.slab, toks, pos, rem, eos, active, rows)
         self.dispatches += 1
-        nxt, done = timed_get(self, (nxt, done))   # ONE small host sync
+        nxt, done, counts = timed_get(self, (nxt, done, counts))  # ONE sync
         nxt, done = np.asarray(nxt), np.asarray(done)
+        self._add_counts(counts)
         finished: list = []
         for e in movers:
             f = e._fleet_row
@@ -1162,13 +1176,13 @@ class FleetGroup:
         if (allow_block and K > 1 and full and not held and not self.pending
                 and not admitted
                 and all(not e._chunks for e in self.members)):
-            nxt, done, stepped, self.slab, self.ops = \
+            nxt, done, stepped, counts, self.slab, self.ops = \
                 self._kernels.block_kernel(K)(self.params, self.slab,
                                               self.ops)
             self.dispatches += 1
             self._block_credit = K - 1
-            self.pending.append(_Pending("block", (nxt, done, stepped),
-                                         meta))
+            self.pending.append(_Pending("block",
+                                         (nxt, done, stepped, counts), meta))
             return []
         if held:                     # pow2-padded OOB -> dropped on scatter
             hk = pow2_bucket(len(held))
@@ -1192,9 +1206,10 @@ class FleetGroup:
             else:
                 out = self._kernels.afleet_masked(self.params, self.slab,
                                                   self.ops, rows)
-        nxt, done, stepped, self.slab, self.ops = out
+        nxt, done, stepped, counts, self.slab, self.ops = out
         self.dispatches += 1
-        self.pending.append(_Pending("decode", (nxt, done, stepped), meta))
+        self.pending.append(_Pending("decode", (nxt, done, stepped, counts),
+                                     meta))
         return []
 
     # ----------------------------------------------------------- reconcile
@@ -1233,13 +1248,25 @@ class FleetGroup:
                 self._apply_admit(vals, p.meta, finished)
         return finished
 
+    def _add_counts(self, counts: dict):
+        """Sum a decode dispatch's step counts (a block's over its steps)
+        into ``decode_counts``."""
+        for n, c in counts.items():
+            self.decode_counts[n] = self.decode_counts.get(n, 0) + \
+                np.asarray(c, np.int64)
+
     def _apply_decode(self, arrays, meta: list, finished: list):
+        *arrays, counts = arrays
+        self._add_counts(counts)
         nxt, done, stepped = (np.asarray(a) for a in arrays)
         for e, row, clock in meta:
             finished.extend(e.apply_decode(nxt[row], done[row], stepped[row],
                                            clock))
 
     def _apply_block(self, arrays, meta: list, finished: list):
+        *arrays, counts = arrays
+        self._add_counts({n: np.asarray(c).sum(axis=0)
+                          for n, c in counts.items()})
         nxt, done, stepped = (np.asarray(a) for a in arrays)  # (K, F, B)
         for k in range(nxt.shape[0]):        # micro-step k == tick clock+k
             for e, row, clock in meta:

@@ -10,7 +10,8 @@ from repro.configs import ARCH_NAMES, get_config
 from repro.models import make_model
 
 DECODER_ARCHS = [a for a in ARCH_NAMES
-                 if get_config(a).family in ("dense", "moe", "ssm", "hybrid")]
+                 if get_config(a).family in ("dense", "moe", "ssm", "hybrid",
+                                             "hybrid_moe")]
 
 
 @pytest.mark.parametrize("arch", DECODER_ARCHS)

@@ -298,7 +298,7 @@ def test_afleet_writes_only_each_steps_token(setup, cache_dtype):
         nonlocal slab, ops
         before = jax.tree.map(np.asarray, slab)
         pos = np.asarray(ops["pos"])
-        nxt, _, stepped, slab, ops = fn(params, slab, ops, *extra)
+        nxt, _, stepped, _, slab, ops = fn(params, slab, ops, *extra)
         return before, pos, np.asarray(nxt), np.asarray(stepped)
 
     at_pos = np.arange(MAX_SEQ)[None, None, :]

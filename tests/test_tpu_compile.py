@@ -22,6 +22,7 @@ from repro.models.model import make_model
 from repro.serving.engine import _init_ops, get_serve_kernels
 
 HBM_BYTES = 16e9           # one TPU v5e
+V5E_USABLE_BYTES = 15.75 * 2**30   # what its allocator hands out
 # granite-3-8b decode: the one-chip fleet of 2 nodes x 2 replicas x 4 slots
 FLEET, SLOTS, MAX_SEQ = 4, 4, 1024
 # the benchmark's granite-chat cell: 2 nodes x 4 replicas x 4 slots of 512
@@ -116,3 +117,65 @@ def test_fleet_decode_moves_no_slab(one_chip):
     assert not moved, moved
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
 
+
+
+def _hsmall_fleet(sharding):
+    """The one-chip granite-4.0-h-small share (20 layers: 18 Mamba-2 and 2
+    attention, 9 of 72 experts held, bf16) as abstract params, the
+    benchmark cell's fleet slab, its operands and serve kernels."""
+    cfg = get_config("granite-4.0-h-small-1chip")
+    model = make_model(cfg, tp=1)
+    dtype = jnp.dtype(cfg.dtype)
+    params = _on(sharding, jax.eval_shape(
+        lambda k: model.init(k, dtype), jax.random.PRNGKey(0)))
+    slab = _on(sharding, jax.tree.map(
+        lambda c: jax.ShapeDtypeStruct((CELL_FLEET,) + c.shape, c.dtype),
+        jax.eval_shape(lambda: model.init_serve_state(CELL_SLOTS, CELL_SEQ,
+                                                      dtype))))
+    ops = _on(sharding, jax.eval_shape(lambda: _init_ops(CELL_FLEET,
+                                                         CELL_SLOTS)))
+    return params, slab, ops, get_serve_kernels(model, CELL_SEQ, dtype)
+
+
+@pytest.mark.parametrize("variant", ["afleet", "afleet_prefill"])
+def test_hybrid_moe_serve_kernel_fits_one_chip(one_chip, variant):
+    """At the granite-h-small-chat cell's shapes (8.84 GB of weights, a slab
+    of 32 slots of 512 holding 2.58 GB of SSM, conv and K/V state) the
+    async fleet decode and the largest fleet prefill the cell compiles, all
+    32 slots at the 256-position bucket, compile for the chip with
+    arguments + temporaries inside what its allocator hands out, 15.75 GiB
+    (a prefill that returns every row's state of every layer beside the
+    slab needs 17.6 GB there)."""
+    params, slab, ops, kernels = _hsmall_fleet(one_chip)
+    if variant == "afleet":
+        lowered = kernels.afleet.lower(params, slab, ops)
+    else:
+        K, sb = CELL_FLEET * CELL_SLOTS, 256
+        ivec = jax.ShapeDtypeStruct((K,), jnp.int32, sharding=one_chip)
+        toks = jax.ShapeDtypeStruct((K, sb), jnp.int32, sharding=one_chip)
+        lowered = kernels.afleet_prefill.lower(params, slab, ops, toks,
+                                               *(ivec,) * 5)
+    mem = lowered.compile().memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes > 11e9      # weights and slab are real
+    assert used < V5E_USABLE_BYTES, (variant, used)
+
+
+def test_hybrid_moe_decode_moves_no_state(one_chip):
+    """The granite-h-small-chat decode steps the SSM and conv state in place
+    and holds no temporary of the slab's size: no copy or transpose the
+    size of those leaves (a state leaf scanned as the layer scan's inputs
+    and outputs, or mapped over the fleet, is relaid layer-major in and out:
+    two 2.42 GB copies and a slab-sized temporary), and temporaries under a
+    fifth of the 2.58 GB slab. The K/V leaves (33.5 MB each) are relaid
+    for the attention layers' dynamic index in the scan."""
+    params, slab, ops, kernels = _hsmall_fleet(one_chip)
+    compiled = kernels.afleet.lower(params, slab, ops).compile()
+    state = {",".join(map(str, slab[n].shape)) for n in ("ssm", "conv")}
+    moved = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\{[^}]*\} (?:copy|transpose)\(",
+        compiled.as_text()) if m.group(1) in state]
+    assert not moved, moved
+    slab_bytes = sum(c.size * c.dtype.itemsize for c in jax.tree.leaves(slab))
+    assert slab_bytes > 2.5e9
+    assert compiled.memory_analysis().temp_size_in_bytes < slab_bytes / 5
