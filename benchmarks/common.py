@@ -15,6 +15,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.configs.paper_cluster import ClusterConfig
 from repro.core import balancer as bal
+from repro.core import host_device
 from repro.core.forecaster import train_forecaster
 from repro.sim.experiment import run_episode, train_rl_balancer
 from repro.sim.service_rate import replica_request_rate
@@ -48,7 +49,7 @@ def get_controller(seed: int = 0, force: bool = False):
         with open(path, "rb") as f:
             blob = pickle.load(f)
         fp = blob["forecaster"]
-        rl.state = blob["ddpg"]
+        rl.state = jax.device_put(blob["ddpg"], host_device())
         return fp, rl
     ftrace = generate_trace(TraceConfig(ticks=2400), seed=97,
                             load_scale=TRAIN_LOAD)

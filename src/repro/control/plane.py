@@ -24,11 +24,14 @@ plane observes then describe the device state as of one tick earlier —
 scaling rules tolerate that lag by design (production autoscalers poll far
 staler signals); the eager backend mode (``async_tick=False``) restores
 synchronous observation when exact sim-parity of the control trajectory
-matters. The plane's own device results (forecast, balancer action, GPSO
-plan) are fetched through ``obs.timed_get`` and accounted in
-``sync_wait``/``syncs``: a fetch queued behind the previous tick's decode
-waits for it there. Each phase of ``step`` is a ``repro.plane.*`` profiler
-span (``repro.obs``).
+matters. The plane's own computations (forecast, balancer, RL update,
+GPSO/GA plan) run on ``core.host_device()``, the host's CPU, with every
+input committed there: none joins the accelerator's queue, so their fetches
+(through ``obs.timed_get``, accounted in ``sync_wait``/``syncs``) never
+wait for the decode in flight, and the forecast and balance of the next
+tick run while the accelerator still decodes this one. The frontend's
+reconcile is then the one place the host waits for the decode. Each phase
+of ``step`` is a ``repro.plane.*`` profiler span (``repro.obs``).
 
 **Two-level hierarchy and crash tolerance** (PR 10, see
 ``control/hierarchy.py``): under ``PlaneSupervisor`` this plane is the
@@ -59,6 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import balancer as bal
+from repro.core import host_device
 from repro.core.autoscaler import (GPSOAutoscaler, HPAAutoscaler,
                                    RBASAutoscaler, StaticAllocator)
 from repro.core.forecaster import forecast as nn_forecast
@@ -113,7 +117,9 @@ class ControlPlane:
         self.backend = backend
         self.balancer = balancer
         self.rl = rl
-        self.forecaster_params = forecaster_params
+        self.host = host_device()
+        self.forecaster_params = None if forecaster_params is None else \
+            jax.device_put(forecaster_params, self.host)
         self.forecast_scale = float(forecast_scale)
         self.train_rl = train_rl
         self.explore = explore
@@ -161,14 +167,12 @@ class ControlPlane:
 
     # ------------------------------------------------------------ forecast
     def _forecast(self, arrival_rate: float) -> np.ndarray:
+        window = jnp.asarray(self.window[:, None] / self.forecast_scale,
+                             device=self.host)
         if self.forecaster_params is not None:
-            fc = _jit_forecast(
-                self.forecaster_params,
-                jnp.asarray(self.window[:, None] / self.forecast_scale))
+            fc = _jit_forecast(self.forecaster_params, window)
         else:
-            fc = last_value_baseline(
-                jnp.asarray(self.window[:, None] / self.forecast_scale),
-                self.cfg.horizon)
+            fc = last_value_baseline(window, self.cfg.horizon)
         fc = np.asarray(timed_get(self, fc))[:, 0]
         fc = fc.astype(np.float32)
         # rolling 1-step forecast-error tracker -> volatility-aware headroom
@@ -182,18 +186,20 @@ class ControlPlane:
     # ------------------------------------------------------------- balance
     def _balance(self, obs, up, arrival_rate: float) -> np.ndarray:
         b = self.backend
+        obs, up = jax.device_put((obs, up), self.host)
         if self.balancer == "rr":
-            fr = bal.round_robin(jnp.asarray(obs), jnp.asarray(up))
+            fr = bal.round_robin(obs, up)
         elif self.balancer == "lc":
-            fr = bal.least_connections(
-                jnp.asarray(b.queue_depths()), jnp.asarray(up),
-                jnp.float32(arrival_rate * self.cfg.tick_seconds))
+            queue, mass = jax.device_put(
+                (b.queue_depths(),
+                 np.float32(arrival_rate * self.cfg.tick_seconds)),
+                self.host)
+            fr = bal.least_connections(queue, up, mass)
         elif self.balancer == "wrr":
-            fr = bal.weighted_capacity(jnp.asarray(obs), jnp.asarray(up),
-                                       jnp.asarray(b.capacity()))
+            fr = bal.weighted_capacity(
+                obs, up, jax.device_put(b.capacity(), self.host))
         elif self.balancer == "rl":
-            fr = self.rl.act(jnp.asarray(obs), jnp.asarray(up),
-                             explore=self.explore)
+            fr = self.rl.act(obs, up, explore=self.explore)
         else:
             raise ValueError(self.balancer)
         return np.asarray(timed_get(self, fr))

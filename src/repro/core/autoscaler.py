@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import host_device
 from repro.core.gpso import (ga_only_minimize, gpso_minimize,
                              preemption_risk_cost, slo_violation_cost)
 from repro.obs import timed_get
@@ -92,14 +93,17 @@ class GPSOAutoscaler:
 
     optimizer='ga' drops the PSO refinement (the paper's implicit ablation:
     GA-only at the same evaluation budget). ``plan(slo_pressure=...)``
-    switches to the tiered objective (Eq.9 + tier-weighted SLO cost)."""
+    switches to the tiered objective (Eq.9 + tier-weighted SLO cost).
+    The key and each plan's inputs are committed to ``host_device()``, so
+    the plan runs there."""
     cluster_cfg: "ClusterConfig"
     unit_capacity: float
     seed: int = 0
     optimizer: str = "gpso"          # "gpso" | "ga"
 
     def __post_init__(self):
-        self._key = jax.random.PRNGKey(self.seed)
+        self._key = jax.device_put(jax.random.PRNGKey(self.seed),
+                                   host_device())
         self._last_scale_down = -10**9
 
     def plan(self, node_demand: np.ndarray, tick: int,
@@ -123,28 +127,28 @@ class GPSOAutoscaler:
         if node_speed is None:
             node_speed = np.ones(n, np.float32)
         self._key, sub = jax.random.split(self._key)
-        ctx = (jnp.asarray(node_demand, jnp.float32),
-               jnp.asarray(self.unit_capacity * node_speed, jnp.float32),
-               jnp.float32(cfg.replica_cost), jnp.float32(cfg.lam),
-               jnp.float32(cfg.target_load))
+        ctx = (np.asarray(node_demand, np.float32),
+               np.asarray(self.unit_capacity * node_speed, np.float32),
+               np.float32(cfg.replica_cost), np.float32(cfg.lam),
+               np.float32(cfg.target_load))
         fitness = eq9_fitness
         if slo_pressure is not None and np.asarray(slo_pressure).any():
             p = np.asarray(slo_pressure, np.float64)
             p = p / max(p.sum(), 1e-9)       # per-node share, scale-free
             fitness = eq9_tiered_fitness
-            ctx = ctx + (jnp.float32(cfg.slo_lam),
-                         jnp.asarray(p, jnp.float32))
+            ctx = ctx + (np.float32(cfg.slo_lam), np.asarray(p, np.float32))
         if preempt_risk is not None and np.asarray(preempt_risk).any():
             fitness = eq9_tiered_risk_fitness \
                 if fitness is eq9_tiered_fitness else eq9_risk_fitness
-            ctx = ctx + (jnp.float32(getattr(cfg, "risk_lam", 1.0)),
-                         jnp.asarray(preempt_risk, jnp.float32))
+            ctx = ctx + (np.float32(getattr(cfg, "risk_lam", 1.0)),
+                         np.asarray(preempt_risk, np.float32))
         minimize = gpso_minimize if self.optimizer == "gpso" else \
             ga_only_minimize
         best, cost, _ = minimize(
             sub, fitness, node_demand.shape[0], cfg,
             lo=float(cfg.min_replicas_per_node),
-            hi=float(cfg.max_replicas_per_node), ctx=ctx)
+            hi=float(cfg.max_replicas_per_node),
+            ctx=jax.device_put(ctx, host_device()))
         target = jnp.round(best)
         target = np.asarray(target if owner is None
                             else timed_get(owner, target), np.int32)

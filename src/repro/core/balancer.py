@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import ddpg
+from repro.core import ddpg, host_device
 from repro.core.gcn import make_topology, normalize_adjacency
 
 
@@ -70,17 +70,22 @@ def least_connections(queue, up_mask, total_arrivals):
 
 @dataclasses.dataclass
 class RLBalancer:
-    """The paper's balancer: GCN+DDPG actor producing A_t from S_t."""
+    """The paper's balancer: GCN+DDPG actor producing A_t from S_t.
+
+    The actor's state and the adjacency are committed to ``host_device()``
+    when built, so acting and the DDPG update run there."""
     cluster_cfg: "ClusterConfig"
     feat_dim: int
     seed: int = 0
 
     def __post_init__(self):
         cfg = self.cluster_cfg
+        host = host_device()
         self.a_hat = jnp.asarray(normalize_adjacency(
-            make_topology(cfg.num_nodes, cfg.topology)))
-        key = jax.random.PRNGKey(self.seed)
-        self.state = ddpg.init_ddpg(key, self.feat_dim, cfg)
+            make_topology(cfg.num_nodes, cfg.topology)), device=host)
+        key = jax.device_put(jax.random.PRNGKey(self.seed), host)
+        self.state = jax.device_put(
+            ddpg.init_ddpg(key, self.feat_dim, cfg), host)
         self.buffer = ddpg.ReplayBuffer(cfg.buffer_size, cfg.num_nodes,
                                         self.feat_dim)
         self._rng = np.random.default_rng(self.seed)
@@ -91,7 +96,8 @@ class RLBalancer:
         noise = None
         if explore:
             noise = jnp.asarray(self._rng.normal(
-                0.0, self.cluster_cfg.noise_sigma, obs.shape[:-1]))
+                0.0, self.cluster_cfg.noise_sigma, obs.shape[:-1]),
+                device=host_device())
         return self._act(self.state.actor, self.a_hat, obs,
                          up_mask=up_mask, noise=noise)
 

@@ -119,6 +119,7 @@ def polyak(target, online, tau):
     return jax.tree.map(lambda t, o: (1 - tau) * t + tau * o, target, online)
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class DDPGState:
     actor: dict

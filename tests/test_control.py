@@ -3,9 +3,15 @@
 Covers the ClusterBackend contract both ways: operational semantics of the
 elastic engine (cold-start provisioning, drain-before-remove, failure
 re-queue, heterogeneous replicas), the bucketed-prefill retrace bound, the
-routing-fraction guard, straggler persistence in the fluid sim, and ranking
-parity between the fluid and request-level backends under the same plane.
+routing-fraction guard, straggler persistence in the fluid sim, ranking
+parity between the fluid and request-level backends under the same plane,
+and the plane's placement on the host device.
 """
+import json
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -395,3 +401,141 @@ def test_ours_stack_runs_on_elastic_backend(setup):
     assert (fe.in_flight() >= 1).all()
     fe.run_until_drained()
     assert all(r.done for r in fe.finished)
+
+
+# ------------------------------------------------------ host placement
+# Two virtual CPU devices need ``--xla_force_host_platform_device_count``
+# before jax initializes, so this runs in a subprocess. ``cpu:1`` is made
+# the default device and stands in for the accelerator; ``cpu:0`` is what
+# ``repro.core.host_device()`` returns.
+_PLACEMENT_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import json
+import jax
+import numpy as np
+
+from repro.configs.paper_cluster import ClusterConfig
+from repro.control import ControlPlane, SimBackend
+from repro.control import plane as plane_mod
+from repro.core import autoscaler, host_device
+from repro.core import balancer as bal
+from repro.core.forecaster import init_forecaster
+from repro.sim.cluster import ClusterSim
+
+host, accel = jax.devices("cpu")
+fetched = {"plane": [], "scale": []}
+
+
+def on_host(tree):
+    return all(x.committed and x.devices() == {host}
+               for x in jax.tree.leaves(tree))
+
+
+def recording(kind, get):
+    def timed_get(owner, arrays):
+        fetched[kind].append(on_host(arrays))
+        return get(owner, arrays)
+    return timed_get
+
+
+plane_mod.timed_get = recording("plane", plane_mod.timed_get)
+autoscaler.timed_get = recording("scale", autoscaler.timed_get)
+
+
+def run(default, gru):
+    jax.config.update("jax_default_device", default)
+    cfg = ClusterConfig(
+        num_nodes=2, horizon=4, forecast_window=8, provisioning_delay=2,
+        max_replicas_per_node=3, min_replicas_per_node=1, scale_interval=3,
+        cooldown=6, straggler_prob=0.0, node_mtbf=1e12, batch_size=4,
+        ga_pop=16, ga_elite=4, ga_generations=4, pso_iters=4)
+    sim = ClusterSim(cfg, 0.5, seed=0, failures=False, heterogeneous=False)
+    backend = SimBackend(sim)
+    targets = []
+    scale_to = backend.scale_to
+    backend.scale_to = lambda t: (targets.append(np.asarray(t).tolist()),
+                                  scale_to(t))[1]
+    rl = bal.RLBalancer(cfg, 4 + cfg.horizon, seed=0)
+    fp = init_forecaster(jax.random.PRNGKey(1), 1, 8, cfg.horizon) \
+        if gru else None
+    plane = ControlPlane(cfg, backend, balancer="rl", scaler="gpso",
+                         unit_capacity=0.5, rl=rl, forecaster_params=fp,
+                         train_rl=True, explore=True, seed=0,
+                         init_arrival=1.5)
+    steps = []
+    for a in 1.0 + 1.5 * np.random.default_rng(0).random(14):
+        m = plane.step(float(a))
+        steps.append([plane._prev_fc1, plane.fractions.tobytes().hex(),
+                      float(m["response_time"])])
+    state = [np.asarray(x).tobytes().hex() for x in jax.tree.leaves(rl.state)]
+    return {
+        "steps": steps, "targets": targets, "state": state,
+        "trained": rl.buffer.size >= cfg.batch_size,
+        "state_on_host": on_host((rl.state, rl.a_hat, plane.scaler._key,
+                                  plane.forecaster_params)),
+        "syncs": plane.syncs,
+    }
+
+
+out = {"host_device": str(host_device())}
+for gru in (False, True):
+    name = "gru" if gru else "last_value"
+    runs = {str(d): run(d, gru) for d in (accel, host)}
+    a, h = runs[str(accel)], runs[str(host)]
+    out[name] = {
+        "same_decisions": all(a[k] == h[k]
+                              for k in ("steps", "targets", "state")),
+        "state_on_host": a["state_on_host"], "trained": a["trained"],
+        "syncs": a["syncs"],
+    }
+out["forecast_and_fractions_on_host"] = all(fetched["plane"])
+out["plans_on_host"] = all(fetched["scale"])
+out["fetches"] = {k: len(v) for k, v in fetched.items()}
+print("RESULT " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def placement(tmp_path_factory):
+    script = tmp_path_factory.mktemp("placement") / "run.py"
+    script.write_text(_PLACEMENT_SCRIPT)
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=os.path.abspath(src))
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, str(script)], capture_output=True,
+                         text=True, env=env, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    line = [l for l in out.stdout.splitlines() if l.startswith("RESULT ")][-1]
+    return json.loads(line[len("RESULT "):])
+
+
+def test_plane_decisions_run_on_host_device(placement):
+    """With another device as the default (the accelerator's stand-in), the
+    forecast, the balancer's fractions and every GPSO target are computed
+    on ``host_device()`` and fetched from there."""
+    assert placement["host_device"] == "TFRT_CPU_0"
+    # 2 forecasters x 2 default devices x 14 ticks x (forecast + balance)
+    assert placement["fetches"] == {"plane": 112, "scale": 16}
+    assert placement["forecast_and_fractions_on_host"]
+    assert placement["plans_on_host"]
+
+
+@pytest.mark.parametrize("forecaster", ["last_value", "gru"])
+def test_plane_state_committed_to_host_device(placement, forecaster):
+    """The actor's state (after DDPG updates too), its adjacency, the GPSO
+    key and the forecaster's parameters stay committed to the host."""
+    run = placement[forecaster]
+    assert run["trained"]
+    assert run["state_on_host"]
+    # one fetch each for the forecast and the fractions, 4 GPSO plans
+    assert run["syncs"] == 14 * 2 + 4
+
+
+@pytest.mark.parametrize("forecaster", ["last_value", "gru"])
+def test_plane_decisions_independent_of_default_device(placement,
+                                                       forecaster):
+    """Every forecast, fraction, scale target and the trained actor are
+    bit-identical to the same plane run with the host as the default."""
+    assert placement[forecaster]["same_decisions"]
