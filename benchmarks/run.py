@@ -5,8 +5,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
   fig2_response/*         — paper Fig.2 (response time vs load)
   fig3_scaling/*          — paper Fig.3 (scaling efficiency vs load)
   claims/*                — the +35% / -28% headline validation
-  serve/*                 — elastic request-level engine (tok/s, TTFT,
-                            prefill retraces) -> results/BENCH_serve.json
   roofline/*              — per (arch x shape) roofline terms (§Roofline)
 
 Artifacts land under results/ (CSVs + JSON).
@@ -32,9 +30,6 @@ def main() -> None:
         rows += fig2_response_time(controller)
         rows += fig3_scaling_efficiency(controller)
         rows += paper_claims(controller)
-    if all_ or "serve" in args:
-        from benchmarks.serve_bench import main as serve_main
-        rows += serve_main()
     if all_ or "ablations" in args:
         from benchmarks.ablations import main as ablations_main
         rows += ablations_main()

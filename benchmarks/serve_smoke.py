@@ -6,7 +6,7 @@ Guards the admission-path invariants cheap enough for every PR:
     prompts must admit in <= (distinct bucket shapes) jitted prefill
     dispatches per tick, never one per replica (here: equal lengths + equal
     group sizes -> exactly ONE shape -> ONE dispatch, vs 2 for the
-    per-replica oracle);
+    per-replica loop, ``fleet_batch=False``, with identical streams);
   * **TTFT regression bound** — with chunked admission on, short requests
     sharing the cluster with near-``max_seq`` prompts must keep their TTFT
     p95 within the same small constant as a short-only run would give
@@ -16,11 +16,11 @@ Guards the admission-path invariants cheap enough for every PR:
     (b) still finish every batch-tier request (no starvation), and (c)
     keep the fleet dispatch bounds: tiering reorders which rows enter the
     one fleet prefill/decode per tick, it never adds dispatches;
-  * **async tick contract** — on the same 3-tier config the (default)
-    async tick must pay at most ONE blocking host sync per tick
+  * **fleet tick contract** — on the same 3-tier config the fleet tick
+    must pay at most ONE blocking host sync per tick
     (``metrics()['syncs'] <= 1``, admissions included) and produce token
-    streams bit-identical to the eager oracle; with ``decode_block=4`` the
-    fused windows must engage (total syncs / ticks < 1);
+    streams bit-identical to the per-replica loop; with ``decode_block=4``
+    the fused windows must engage (total syncs / ticks < 1);
   * **multi-cell chaos drill** — 2 elastic cells behind the fault-tolerant
     routing plane (``control.cells``) with a scripted ``cell_down`` under
     retrying clients: the single global ledger must balance with
@@ -77,13 +77,13 @@ def main():
     prompts = [rng.integers(1, cfg.vocab_size, 6).tolist()
                for _ in range(2 * MAX_BATCH)]
 
-    def burst_fe(fp):
+    def burst_fe(fleet):
         def mk(rid):
             return ReplicaEngine(model, params, max_batch=MAX_BATCH,
                                  max_seq=MAX_SEQ, rid=rid)
         fe = ElasticClusterFrontend(mk, 1, initial_replicas=2,
                                     max_replicas_per_node=2, seed=0,
-                                    fleet_prefill=fp)
+                                    fleet_batch=fleet)
         for i, p in enumerate(prompts):
             fe.submit(Request(i, list(p), max_new_tokens=3))
         return fe, fe.tick(0.0)
@@ -98,7 +98,7 @@ def main():
     assert m_on["prefill_dispatches"] <= distinct_shapes, \
         "fleet admission must cost <= one dispatch per distinct bucket shape"
     assert m_off["prefill_dispatches"] >= 2, \
-        "per-replica oracle should pay one dispatch per admitting replica"
+        "the per-replica loop should pay one dispatch per admitting replica"
     fe_on.run_until_drained()
     fe_off.run_until_drained()
     snap = lambda fe: sorted((r.rid, tuple(r.output)) for r in fe.finished)
@@ -137,13 +137,13 @@ def main():
                      TierSpec("batch", share=0.33, weight=1.0)])
     burst = [rng.integers(1, cfg.vocab_size, 6).tolist() for _ in range(24)]
 
-    def tier_burst(ts, async_tick=True, decode_block=1, n_new=3):
+    def tier_burst(ts, fleet=True, decode_block=1, n_new=3):
         def mk(rid):
             return ReplicaEngine(model, params, max_batch=MAX_BATCH,
                                  max_seq=MAX_SEQ, rid=rid, tiers=ts)
         fe = ElasticClusterFrontend(mk, 1, initial_replicas=2,
                                     max_replicas_per_node=2, seed=0,
-                                    async_tick=async_tick,
+                                    fleet_batch=fleet,
                                     decode_block=decode_block, tiers=ts)
         for i, p in enumerate(burst):
             req = Request(i, list(p), max_new_tokens=n_new)
@@ -158,7 +158,7 @@ def main():
             if m["decode_dispatches"]:
                 max_decode = max(max_decode, m["decode_dispatches"]
                                  / max(m["fleet_groups"], 1))
-            if async_tick:
+            if fleet:
                 max_syncs = max(max_syncs, m["syncs"])
             if not fe.pending and all(n.unfinished() == 0
                                       for n in fe.nodes):
@@ -190,21 +190,20 @@ def main():
     assert dec_t <= 1.0, \
         "tiering must keep ONE fleet decode dispatch per group per tick"
 
-    # ---- async tick: syncs_per_tick bound + eager stream parity -------
+    # ---- fleet tick: syncs_per_tick bound + per-replica stream parity --
     assert sync_t <= 1, \
-        "async tick must pay at most ONE blocking sync per tick"
-    fe_e, _, _, _, _ = tier_burst(tiers, async_tick=False)
-    snap_async = snap(fe_t)
-    snap_eager = snap(fe_e)
-    assert snap_async == snap_eager, \
-        "async tick changed token streams vs the eager oracle"
+        "the fleet tick must pay at most ONE blocking sync per tick"
+    fe_p, _, _, _, _ = tier_burst(tiers, fleet=False)
+    assert snap(fe_t) == snap(fe_p), \
+        "the fleet tick changed token streams vs the per-replica loop"
 
     # decode_block=4: longer outputs so fused windows engage once the
     # admission wave passes; total syncs must amortize below 1/tick
     fe_b, _, _, _, ticks_b = tier_burst(tiers, decode_block=4, n_new=16)
     fe_r, _, _, _, _ = tier_burst(tiers, decode_block=1, n_new=16)
     spt = fe_b.sync_count() / ticks_b
-    print(f"[smoke] async: max syncs/tick={sync_t} (streams == eager); "
+    print(f"[smoke] fleet tick: max syncs/tick={sync_t} "
+          f"(streams == per-replica); "
           f"decode_block=4: syncs/tick={spt:.2f} over {ticks_b} ticks")
     assert spt < 1.0, "decode_block=4 must amortize syncs below 1/tick"
     # finish ticks may lag <= K-1 inside fused windows; token content is

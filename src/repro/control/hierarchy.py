@@ -49,9 +49,8 @@ backend tick ``t`` (views start aging that tick); the supervisor observes
 ``plane_alive == False`` from the following ``step`` and suppresses the
 global plane until the tick after ``plane_up`` lands. Scale-reaction
 latency — ticks from a burst's onset to the first scale-up action — is
-the headline A/B stat (`benchmarks/serve_bench.py` ``plane_outage``):
-hierarchical control reacts during the outage, the centralized-frozen
-baseline cannot react until restore.
+the headline A/B stat: hierarchical control reacts during the outage, the
+centralized-frozen baseline cannot react until restore.
 """
 from __future__ import annotations
 

@@ -22,8 +22,8 @@ loop runs while the accelerator computes the tick's decode
 (``metrics()['sync_wait_s']`` is the engine's blocked time). The metrics the
 plane observes then describe the device state as of one tick earlier —
 scaling rules tolerate that lag by design (production autoscalers poll far
-staler signals); the eager backend mode (``async_tick=False``) restores
-synchronous observation when exact sim-parity of the control trajectory
+staler signals); the per-replica backend loop (``fleet_batch=False``)
+observes synchronously when exact sim-parity of the control trajectory
 matters. The plane's own computations (forecast, balancer, RL update,
 GPSO/GA plan) run on ``core.host_device()``, the host's CPU, with every
 input committed there: none joins the accelerator's queue, so their fetches

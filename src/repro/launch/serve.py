@@ -26,20 +26,18 @@ Both report prefill retrace counts: prompts are padded to power-of-two
 buckets so the engine compiles O(log max_seq) prefill variants total.
 
 Admission flags: ``--chunk-len N`` streams prompts longer than N in fixed
-chunks interleaved with decode (bounded TTFT/TBT tail); fleet mode batches
+chunks interleaved with decode (bounded TTFT/TBT tail); the fleet batches
 all same-bucket admits across replicas into one jitted prefill per distinct
-bucket shape per tick (``--no-fleet-prefill`` restores per-replica
-admission as the A/B oracle); ``--tiers premium:0.2:w5:4,standard:0.5:w2,
+bucket shape per tick; ``--tiers premium:0.2:w5:4,standard:0.5:w2,
 batch:0.3:w1`` serves an SLO-tiered mix (share : weighted-deficit weight :
 optional TTFT target) through tiered replica queues, per-tier metrics and
 the tier-weighted Eq.5/Eq.9 objectives — the default single tier is
 bit-identical to the untiered scheduler.
 
-Tick-overlap flags: the serve tick is asynchronous by default — fleet
-dispatches return device futures reconciled at ONE host sync per tick, so
-host bookkeeping and the control plane overlap the device's decode
-(``--no-async`` restores the eager blocking tick as the bit-exact parity
-oracle); ``--decode-block K`` fuses K decode micro-steps into one dispatch
+Tick-overlap flags: the serve tick is asynchronous — fleet dispatches
+return device futures reconciled at ONE host sync per tick, so host
+bookkeeping and the control plane overlap the device's decode;
+``--decode-block K`` fuses K decode micro-steps into one dispatch
 and one sync on ticks that admit nothing (saturated decode pays 1/K syncs
 per tick, trading up to K-1 ticks of admission lag under a full slab);
 ``--attn-backend pallas`` decodes attention through the flash-decode
@@ -62,9 +60,9 @@ under a crash-tolerant ``PlaneSupervisor`` (the ``ControlPlane`` runs
 forecast+balance only; scaling authority belongs to the leases).
 ``--cell-chaos 'plane_down@10:k6'`` crashes the GLOBAL plane: the
 centralized loop freezes (no planning, no balancing) while the
-hierarchical loop keeps autoscaling locally inside the last leases —
-the A/B that ``benchmarks/serve_bench.py`` measures as scale-reaction
-latency. ``slow@t:nI:xF`` in ``--chaos`` pins a deterministic straggler
+hierarchical loop keeps autoscaling locally inside the last leases, so
+its scale-reaction latency stays short through the outage.
+``slow@t:nI:xF`` in ``--chaos`` pins a deterministic straggler
 (node I at 1/F speed until ``x1`` clears it).
 
 Device scaling: ``--devices N`` shards every fleet group's slab over an
@@ -166,9 +164,7 @@ def run_control_loop(args, cfg, model, params, mesh=None):
             max_replicas_per_node=args.max_replicas,
             failure_rate=args.failure_rate, request_factory=request_factory,
             seed=args.seed, est_tokens=est_tokens,
-            fleet_batch=not args.no_fleet,
-            fleet_prefill=not args.no_fleet_prefill,
-            async_tick=not args.no_async, decode_block=args.decode_block,
+            decode_block=args.decode_block,
             tiers=tiers, mesh=mesh,
             preempt_notice=args.preempt_notice, chaos=cell_chaos)
 
@@ -373,11 +369,11 @@ def run_drain_mode(args, cfg, model, params):
     from repro.serving.engine import (ClusterFrontend, ReplicaEngine,
                                       Request, total_prefill_traces)
 
-    if args.no_async or args.decode_block > 1:
-        # the static ClusterFrontend always runs the eager blocking tick;
-        # don't let an A/B arm silently not differ
-        print("[serve] note: --no-async/--decode-block apply to the "
-              "control-loop mode only; drain mode always ticks eagerly")
+    if args.decode_block > 1:
+        # the static ClusterFrontend steps each replica on its own; don't
+        # let an A/B arm silently not differ
+        print("[serve] note: --decode-block applies to the control-loop "
+              "mode only; drain mode steps each replica on its own")
 
     replicas = [ReplicaEngine(model, params, max_batch=args.max_batch,
                               max_seq=args.max_seq, rid=i,
@@ -495,21 +491,11 @@ def build_parser() -> argparse.ArgumentParser:
                     help="disable adaptive cell routing (fixed uniform "
                          "split ignoring health/staleness/risk; the "
                          "multi-cell A/B baseline)")
-    ap.add_argument("--no-fleet", action="store_true",
-                    help="disable fleet-batched decode (per-replica jit "
-                         "dispatch loop; A/B baseline)")
-    ap.add_argument("--no-fleet-prefill", action="store_true",
-                    help="disable fleet-batched admission (per-replica "
-                         "prefill dispatches; A/B baseline)")
-    ap.add_argument("--no-async", action="store_true",
-                    help="disable the overlapped async tick (eager blocking "
-                         "syncs after every dispatch; bit-exact parity "
-                         "oracle)")
     ap.add_argument("--decode-block", type=int, default=1,
                     help="fuse K decode micro-steps into one dispatch+sync "
-                         "on ticks that admit nothing (async mode; 1 = one "
-                         "step per tick; >1 trades <= K-1 ticks of "
-                         "admission lag under a full slab)")
+                         "on ticks that admit nothing (1 = one step per "
+                         "tick; >1 trades <= K-1 ticks of admission lag "
+                         "under a full slab)")
     ap.add_argument("--attn-backend", default="einsum",
                     choices=["einsum", "pallas"],
                     help="decode attention backend: dense einsum reference "

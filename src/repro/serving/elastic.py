@@ -29,52 +29,53 @@ fluid backend.
 ``(model, params, max_batch, max_seq, cache_dtype)`` are stacked into
 ``FleetGroup``s — across node boundaries — so one tick advances every
 replica of a group with ONE jitted decode dispatch and one small batched
-host sync, instead of a Python-dispatched jit call + per-slot ``int()``
+host fetch, instead of a Python-dispatched jit call + per-slot ``int()``
 syncs per replica. Groups survive scale-up (slab rows grow in pow2 steps),
 graceful drain (a draining member keeps decoding in the fleet until empty)
 and failure (its row is dropped and backfilled). Heterogeneous speeds run
 as sub-step *rounds*: a round where only a subset of a group steps uses the
 masked fleet kernel so non-stepping rows' state is untouched. Set
-``fleet_batch=False`` to recover the per-replica ``step()`` loop (the
-parity oracle). ``metrics()['decode_dispatches']`` counts this tick's
+``fleet_batch=False`` to recover the per-replica ``step()`` loop — the
+reference the fleet tick is tested against: same token streams, same
+finish ticks. ``metrics()['decode_dispatches']`` counts this tick's
 dispatches; the fleet path also feeds the measured per-replica service-rate
 EMA (``metrics()['service_rate']``) that the control plane hands to the
 GPSO planner once warm.
 
-**Fleet-batched admission** (default with ``fleet_batch``): each round,
-every stepping member *plans* its admissions on the host and the group
-coordinator batches them — one jitted ``fleet_prefill`` per distinct pow2
-length bucket across ALL nodes, writing admit rows
-straight into the fleet slab, plus one ``fleet_chunk`` dispatch advancing
-every mid-chunk long prompt (``ReplicaEngine(chunk_len=...)``). Cold-queue
-admission cost is therefore O(distinct bucket shapes) per tick instead of
-O(replicas). ``metrics()['prefill_dispatches']`` counts this tick's
-admission dispatches (mirroring ``decode_dispatches``); set
-``fleet_prefill=False`` to keep per-replica admission as the A/B oracle.
+**Fleet-batched admission**: each round, every stepping fleet member
+*plans* its admissions on the host and the group coordinator batches them —
+one jitted ``afleet_prefill`` per distinct pow2 length bucket across ALL
+nodes, writing admit rows straight into the fleet slab, plus one
+``afleet_chunk`` dispatch advancing every mid-chunk long prompt
+(``ReplicaEngine(chunk_len=...)``). Cold-queue admission cost is therefore
+O(distinct bucket shapes) per tick instead of O(replicas).
+``metrics()['prefill_dispatches']`` counts this tick's admission dispatches
+(mirroring ``decode_dispatches``).
 
-**Overlapped async ticks** (default with fleet batching): the fleet
-dispatch methods stop blocking on the device — decode/prefill/chunk results
-stay on the accelerator as pending futures (with the decode operands
-persistent on device, see ``engine`` module docstring) and the deferred host
-bookkeeping applies at ONE reconcile sync at the next tick's start. The host
-half of tick *t* (metrics, queues, tier accounting, the control plane's
+**Overlapped ticks**: the fleet dispatch methods never block on the
+device — decode/prefill/chunk results stay on the accelerator as pending
+futures (with the decode operands persistent on device, see ``engine``
+module docstring) and the deferred host bookkeeping applies at ONE
+reconcile sync at the next tick's start. The host half of tick *t*
+(metrics, queues, tier accounting, the control plane's
 forecast→balance→scale) therefore overlaps the device computing tick *t*'s
 decode: steady-state cost is ``max(host, device)`` instead of their sum, at
 one blocking sync per fleet group per tick (``metrics()['syncs']``,
 mirroring ``decode_dispatches``; ``metrics()['sync_wait_s']`` is the wall
 time actually blocked — the host-vs-device tick breakdown). Token streams
-and finish ticks are bit-identical to ``async_tick=False`` (the eager parity
-oracle); only host-side *observation* — per-tick ``served``/latency metrics,
-drained detection — lags by one tick, and since retires reconcile before
-admission planning, a slot freed by tick *t*'s decode admits at *t+1*
-exactly like the eager path (admission lags device state by at most one
-tick under a full slab). Membership churn (drain retire, failure, scale-up)
-force-flushes pending futures before rows unstack. ``decode_block=K``
-additionally fuses K decode micro-steps into one dispatch+sync on ticks
-with no pending admissions or chunk cursors, dropping syncs/tick to 1/K in
-the saturated-decode regime — at the cost that a slot retiring mid-block
+and finish ticks are bit-identical to the per-replica loop; only host-side
+*observation* — per-tick ``served``/latency metrics, drained detection —
+lags by one tick, and since retires reconcile before admission planning, a
+slot freed by tick *t*'s decode admits at *t+1* exactly like the
+per-replica loop (admission lags device state by at most one tick under a
+full slab). Membership churn (drain retire, failure, scale-up) force-flushes
+pending futures before rows unstack. ``decode_block=K`` additionally fuses
+K decode micro-steps into one dispatch+sync on ticks with no pending
+admissions or chunk cursors, dropping syncs/tick to 1/K in the
+saturated-decode regime — at the cost that a slot retiring mid-block
 re-admits only at the block-end reconcile (admission lag <= K-1 ticks
-under a full slab; see the engine docstring).
+under a full slab; see the engine docstring). ``async_tick`` accepts only
+``True``; the per-replica loop is ``fleet_batch=False``.
 
 **Fleet-mesh sharding.** Pass ``mesh=`` (a mesh with a ``fleet`` axis,
 e.g. ``launch.mesh.make_fleet_mesh``) and every fleet group shards its
@@ -86,7 +87,7 @@ the shard count (pad rows masked inactive, excluded from dispatch/retire
 accounting), churn (scale-up growth, drain retire, failure row-drop)
 keeps live rows dense so they re-balance across contiguous shard blocks,
 and membership changes force-flush pending futures exactly like the
-unsharded async path. Params replicate across the fleet axis. On CPU the
+unsharded path. Params replicate across the fleet axis. On CPU the
 N devices are virtual (``--xla_force_host_platform_device_count``, set
 before jax initializes — see ``launch/serve.py --devices``).
 
@@ -113,7 +114,7 @@ a re-submitted rid is *suppressed* while an attempt is live (or already
 served/abandoned) and accepted as a retry only from ``timed_out`` /
 ``rejected``, guaranteeing at most one attempt-object per rid in the
 system. Deadlines (``Request.deadline_tick``) retire inside the existing
-fleet/afleet retire rule (see ``engine``); queued work whose deadline
+decode retire rule (see ``engine``); queued work whose deadline
 already passed is culled before it wastes a prefill — at the frontend
 sweep for ``pending`` + node queues and at the replica queue head in
 ``plan_admission``. Spot preemption takes whole nodes: ``preempt_node``
@@ -417,8 +418,7 @@ class ElasticClusterFrontend:
                  request_factory: Optional[Callable[[int, int], Request]] = None,
                  tick_seconds: float = 1.0, seed: int = 0,
                  est_tokens: float = 8.0, fleet_batch: bool = True,
-                 fleet_prefill: bool = True, async_tick: bool = True,
-                 decode_block: int = 1,
+                 async_tick: bool = True, decode_block: int = 1,
                  tiers: Optional[TierSet] = None, mesh=None,
                  preempt_notice: int = 0,
                  chaos: Optional[ChaosSchedule] = None,
@@ -435,19 +435,18 @@ class ElasticClusterFrontend:
         self.max_queue = max_queue        # admission cap -> 'rejected' rids
         self.request_factory = request_factory
         self.tick_seconds = tick_seconds
+        # ``async_tick`` is kept as a keyword for configs that still name it
+        if not async_tick:
+            raise ValueError(
+                "the fleet tick is always overlapped (async_tick=True); "
+                "pass fleet_batch=False for the per-replica loop")
         self.fleet_batch = fleet_batch
-        self.fleet_prefill = fleet_prefill and fleet_batch
         # serving mesh with a 'fleet' axis: fleet groups shard their slab's
         # fleet axis over it (N devices decode F replicas in parallel; see
         # FleetGroup's shard contract — capacity stays divisible by the
         # shard count, churn re-balances dense rows, params replicate)
         self.mesh = mesh if fleet_batch else None
-        # the async tick needs the fleet dispatch paths end to end: with
-        # either oracle mode (per-replica decode or per-replica admission)
-        # the tick falls back to eager, blocking syncs
-        self.async_tick = bool(async_tick) and self.fleet_prefill
-        self.decode_block = max(1, int(decode_block)) if self.async_tick \
-            else 1
+        self.decode_block = max(1, int(decode_block)) if fleet_batch else 1
         self.rng = np.random.default_rng(seed)
         self.nodes = [_Node(self.tiers) for _ in range(num_nodes)]
         self._rid = 0                # engine ids (replicas ever created)
@@ -511,7 +510,6 @@ class ElasticClusterFrontend:
                 g = self._fleets[eng.fleet_key] = FleetGroup(
                     eng.model, eng.params, max_batch=eng.max_batch,
                     max_seq=eng.max_seq, cache_dtype=eng.cache_dtype,
-                    async_mode=self.async_tick,
                     decode_block=self.decode_block,
                     attn_backend=eng.attn_backend, mesh=self.mesh)
             g.add(eng)
@@ -561,9 +559,10 @@ class ElasticClusterFrontend:
             sum(g.prefill_dispatches for g in self._fleets.values())
 
     def sync_count(self) -> int:
-        """Total blocking host syncs performed (group reconciles + eager
-        fetches), including retired engines and evicted groups — the async
-        tick's ``syncs`` currency, mirroring ``decode_dispatches``."""
+        """Total blocking host syncs performed (group reconciles plus the
+        engines' own fetches: exact-length admits and the per-replica
+        loop), including retired engines and evicted groups — the tick's
+        ``syncs`` currency, mirroring ``decode_dispatches``."""
         live = sum(e.syncs for n in self.nodes for e in n.live + n.draining)
         return self._retired_syncs + live + \
             sum(g.syncs for g in self._fleets.values())
@@ -1052,9 +1051,9 @@ class ElasticClusterFrontend:
         prefill_before = self.prefill_dispatches()
         syncs_before = self.sync_count()
         wait_before = self.sync_wait_s()
-        # async reconcile point: commit the previous tick's in-flight device
+        # reconcile point: commit the previous tick's in-flight device
         # results (retires free their slots HERE, before admission planning,
-        # so admission timing matches the eager oracle exactly)
+        # so admission timing matches the per-replica loop exactly)
         finished_now: list = self._reconcile_all()
         with span("fe.route"):
             self._advance_provisioning()
@@ -1079,8 +1078,8 @@ class ElasticClusterFrontend:
                     stepping.append((eng, n_sub))
         # sub-step rounds: round r advances every engine with n_sub > r, so
         # a homogeneous-speed cluster runs exactly one round and each fleet
-        # group issues ONE decode dispatch (plus, under fleet admission, one
-        # prefill dispatch per distinct bucket shape) for the whole tick.
+        # group issues ONE decode dispatch (plus one prefill dispatch per
+        # distinct bucket shape) for the whole tick.
         # Engines are independent within a tick (node queues were dispatched
         # above), so round interleaving matches stepping them one by one.
         max_sub = max((n for _, n in stepping), default=0)
@@ -1090,7 +1089,7 @@ class ElasticClusterFrontend:
         allow_block = (self.decode_block > 1 and max_sub == 1
                        and not self.pending)
         for r in range(max_sub):
-            if r > 0 and self.async_tick:
+            if r > 0:
                 # hetero sub-rounds: round r's admission may use slots the
                 # previous round's decode freed, so reconcile between rounds
                 # (homogeneous clusters run one round = one sync per tick)
@@ -1099,17 +1098,13 @@ class ElasticClusterFrontend:
             ids = {id(e) for e, _ in round_engines}
             with span("fe.admit"):
                 for eng, n in round_engines:
-                    finished_now.extend(eng.begin_step(
-                        dt=1.0 / n,
-                        admit=eng._fleet is None or not self.fleet_prefill))
-                if self.fleet_prefill:
-                    for g in self._fleets.values():
-                        finished_now.extend(g.admit_round(ids))
+                    finished_now.extend(eng.begin_step(dt=1.0 / n))
+                for g in self._fleets.values():
+                    finished_now.extend(g.admit_round(ids))
             with span("fe.decode"):
                 for g in self._fleets.values():
                     before = g.dispatches
-                    finished_now.extend(g.decode_round(
-                        ids, allow_block=allow_block))
+                    g.decode_round(ids, allow_block=allow_block)
                     self._tick_dispatches += g.dispatches - before
                 for eng, _ in round_engines:     # engines outside any fleet
                     if eng._fleet is None:

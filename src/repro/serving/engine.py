@@ -36,20 +36,21 @@ stepping replica *plans* admission from its queue without dispatching
 dense/ssm/hybrid, f32 cache) reserve a slot and a chunk cursor; requests
 carrying per-request extras (vlm patches, audio frames) become exact-length
 single admits; everything else groups into one pow2 ``(bucket_batch,
-bucket_len)`` prefill per replica. Execution then depends on the mode:
+bucket_len)`` prefill per replica. Execution then depends on where the
+replica's device state lives:
 
   * **standalone** — the replica dispatches its own bucketed prefill and one
     batched chunk step (``prefill_dispatches`` counts jitted admission
     dispatches per replica);
-  * **fleet-batched prefill** — a ``FleetGroup`` gathers every member's
-    bucketed groups, flattens rows of the same pow2 length bucket across ALL
-    members and runs ONE jitted ``fleet_prefill`` per *distinct bucket
-    shape* per tick: the batched prefill writes each admit row's KV/state
-    directly into the donated fleet slab on device (no host-side
-    ``write_slot`` copies), and all members' due chunk rows advance in ONE
-    ``fleet_chunk`` dispatch. Admission cost becomes O(distinct bucket
+  * **fleet member** — its ``FleetGroup`` gathers every member's bucketed
+    groups, flattens rows of the same pow2 length bucket across ALL members
+    and runs ONE jitted ``afleet_prefill`` per *distinct bucket shape* per
+    tick: the batched prefill writes each admit row's KV/state directly
+    into the donated fleet slab on device (no host-side ``write_slot``
+    copies), and all members' due chunk rows advance in ONE
+    ``afleet_chunk`` dispatch. Admission cost becomes O(distinct bucket
     shapes) per tick instead of O(replicas);
-    ``FleetGroup.prefill_dispatches`` mirrors ``decode_dispatches``.
+    ``FleetGroup.prefill_dispatches`` mirrors ``dispatches``.
 
 **Chunked prefill.** Prompts longer than ``chunk_len`` stream in fixed-size
 chunks, one per engine step, interleaved with decode rounds: dense chunks
@@ -67,18 +68,19 @@ dispatch per tick. Chunk-by-chunk equals single-shot prefill exactly.
 cache may live either on the engine (standalone) or stacked along a leading
 fleet axis inside a ``FleetGroup`` shared by every replica of the same
 ``(model, params, max_batch, max_seq, cache_dtype)``. A fleet group advances
-*all* member replicas with ONE jitted ``fleet_decode`` dispatch per tick:
-greedy argmax and per-slot retire decisions (max-tokens / EOS / cache-full)
-are fused into the jitted function and synced back as a single small
-``(fleet, batch)`` int/bool array pair — instead of one dispatch plus
+*all* member replicas with ONE jitted ``afleet`` dispatch per tick: greedy
+argmax and per-slot retire decisions (max-tokens / EOS / cache-full) are
+fused into the jitted function, so the host reads back one small
+``(fleet, batch)`` token/retire payload instead of one dispatch plus
 per-slot ``int()`` syncs per replica. Membership survives scale-up, drain
 and failure by stacking/unstacking cache rows (capacity grows in power-of-two
 steps so fleet-size churn retraces O(log F) times, and removed rows are
 backfilled swap-style in one device op).
 
-``ReplicaEngine.step()`` remains the standalone per-replica path (exact-length
-vlm/audio admission, heterogeneous ``max_seq``) and is the parity oracle for
-the fleet path.
+``ReplicaEngine.step()`` is the standalone per-replica path (exact-length
+vlm/audio admission, heterogeneous ``max_seq``, engines outside any fleet)
+and the reference the fleet tick is tested against: same requests, same
+token streams, same finish clocks.
 
 ``cache_dtype`` accepts the string ``"int8"`` for dense/moe/vlm replicas:
 the KV pool is then stored int8 with per-(token, head) f32 absmax scales
@@ -88,21 +90,21 @@ codec quantizes whole prompts at prefill end, and a bf16 pool would round
 the carried chunk state that single-shot keeps unrounded), so a
 ``chunk_len`` there is an error.
 
-**Async tick contract (overlapped serving).** In async mode (the elastic
-frontend's default) the fleet dispatch methods never block on the device:
-``decode_round``/``admit_round`` push their work onto the accelerator queue
-and record a ``_Pending`` entry — the small device outputs (next tokens,
-fused retire mask, stepped mask, prefill first-tokens) plus the host context
-captured at dispatch time (engines, slots, requests, clocks). The decode
-*operands* (``toks``/``pos``/``rem``/``eos``/``active``) are persistent
-device arrays living next to the slab and advanced inside the same jitted
-dispatch (``FleetGroup.ops``), so consecutive ticks chain on device without
-the host rebuilding or re-uploading operand arrays. All deferred host
-bookkeeping is applied at ONE reconcile point per tick
-(``FleetGroup.reconcile`` — a single ``jax.device_get`` over every pending
-record, counted by ``syncs``): the host work for tick *t* (queues, tiers,
-metrics, the control plane's forecast→balance→scale) therefore overlaps the
-device computing tick *t*'s decode. What is pending when:
+**The fleet tick contract (overlapped serving).** The fleet dispatch
+methods never block on the device: ``decode_round``/``admit_round`` push
+their work onto the accelerator queue and record a ``_Pending`` entry — the
+small device outputs (next tokens, fused retire mask, stepped mask, prefill
+first-tokens) plus the host context captured at dispatch time (engines,
+slots, requests, clocks). The decode *operands*
+(``toks``/``pos``/``rem``/``eos``/``active``) are persistent device arrays
+living next to the slab and advanced inside the same jitted dispatch
+(``FleetGroup.ops``), so consecutive ticks chain on device without the host
+rebuilding or re-uploading operand arrays. All deferred host bookkeeping is
+applied at ONE reconcile point per tick (``FleetGroup.reconcile`` — a
+single ``jax.device_get`` over every pending record, counted by ``syncs``):
+the host work for tick *t* (queues, tiers, metrics, the control plane's
+forecast→balance→scale) therefore overlaps the device computing tick *t*'s
+decode. What is pending when:
 
   * a request admitted at tick *t* is *reserved* in its slot immediately
     (occupancy, ``load`` and tier accounting are live) but its first token,
@@ -110,30 +112,31 @@ device computing tick *t*'s decode. What is pending when:
     opens tick *t+1*;
   * decode tokens/retires dispatched at tick *t* commit at tick *t+1*'s
     reconcile, stamped with tick *t*'s clock — token streams and finish
-    ticks are **bit-identical** to the eager oracle (``async_tick=False``),
-    only the host-side observation is one tick late;
+    ticks are **bit-identical** to the per-replica reference, only the
+    host-side observation is one tick late;
   * because retire/slot-free reconciles *before* admission planning, a slot
     freed by tick *t*'s decode is admittable at tick *t+1* — exactly like
-    the eager path, so admission lags the device state by **at most one
-    tick** under a full slab (and by zero ticks relative to the oracle);
+    the per-replica reference, so admission lags the device state by **at
+    most one tick** under a full slab (and by zero ticks relative to the
+    reference);
   * membership churn (drain retire, failure, scale-up joins) force-flushes
     pending futures first, so host mirrors are current before rows unstack.
 
 ``decode_block=K`` fuses K decode micro-steps into one dispatch via
 ``lax.scan`` (one ``(K, F, B)`` sync per block — K× fewer dispatches *and*
 syncs). A block only auto-engages on ticks with no admissions at all —
-fleet prefill/chunk dispatches (``pending``) and eager single admits
+fleet prefill/chunk dispatches (``pending``) and exact-length single admits
 (``_admitted``) both veto it — and no chunk cursors. Queued work behind a
 *full* slab does not block engagement; the trade is that any admission
 landing *inside* the fused window (a retire freeing a slot, or an arrival
 finding one) only starts decoding at the window's end — admission-to-
-first-decode may lag up to K-1 ticks (plain async K=1 keeps the <= 1-tick
-bound). One block counts as K ticks of decode (finish clocks inside the
-block are ``dispatch_clock + k``) and the reconcile is deferred until the
-block's ticks are spent.
+first-decode may lag up to K-1 ticks (K=1 keeps the <= 1-tick bound). One
+block counts as K ticks of decode (finish clocks inside the block are
+``dispatch_clock + k``) and the reconcile is deferred until the block's
+ticks are spent.
 
 **Fleet-mesh sharding.** A ``FleetGroup`` built with ``mesh=`` (a mesh
-carrying a ``fleet`` axis) lays its slab and async operands out
+carrying a ``fleet`` axis) lays its slab and decode operands out
 ``P('fleet', ...)`` over the N devices while params replicate, so GSPMD
 partitions the *same* jitted kernel families row-parallel: F replicas
 decode on N devices under the identical one-dispatch/one-sync tick, with
@@ -143,10 +146,10 @@ and invisible to dispatch/retire accounting) and the dense row packing
 that churn already maintains doubles as the cross-shard re-balance. See
 the ``FleetGroup`` class docstring for the full contract.
 
-``ClusterFrontend`` stitches several replicas together behind a balancer
-policy — the live counterpart of the fluid simulator. The node-structured
-elastic frontend that plugs into the unified control plane lives in
-``repro.serving.elastic``.
+``ClusterFrontend`` stitches several standalone replicas together behind a
+balancer policy — the static drain-mode counterpart of the fluid simulator.
+The node-structured elastic frontend that plugs into the unified control
+plane, and builds the fleet groups, lives in ``repro.serving.elastic``.
 """
 from __future__ import annotations
 
@@ -180,10 +183,9 @@ _BUCKET_FAMILIES = ("dense", "ssm", "hybrid", "hybrid_moe")
 # for dense, carried ssm/conv state for ssm/hybrid). moe is excluded by
 # default for the same capacity reason as bucketing.
 _CHUNK_FAMILIES = ("dense", "ssm", "hybrid")
-# kernel variants whose compilations count as prefill retraces (the async
-# admission twins included — same shapes, different sync contract)
-_PREFILL_VARIANTS = ("prefill", "fleet_prefill", "chunk", "fleet_chunk",
-                     "afleet_prefill", "afleet_chunk")
+# kernel variants whose compilations count as prefill retraces (standalone
+# and fleet admission)
+_PREFILL_VARIANTS = ("prefill", "chunk", "afleet_prefill", "afleet_chunk")
 
 
 def pow2_bucket(n: int, lo: int = 1) -> int:
@@ -200,22 +202,23 @@ class _ServeKernels:
     every cold start (a scale-up would otherwise stall the tick loop on XLA
     compilation of identical shapes). ``trace_counts`` counts actual
     compilations per kernel variant across every replica that shares this
-    object — one deduped accounting covering prefill, decode, the fleet
-    decode variants and the fleet/chunk prefill variants. ``fleet`` /
-    ``fleet_masked`` advance a whole stacked fleet of replicas in one
+    object — one deduped accounting covering the standalone prefill, chunk
+    and decode kernels and the fleet variants. ``prefill`` / ``chunk`` /
+    ``decode`` / ``decode_hold`` serve a standalone replica's own cache.
+    The ``afleet*`` variants serve a ``FleetGroup``: ``afleet`` /
+    ``afleet_masked`` advance a whole stacked fleet of replicas in one
     dispatch with sampling and retire decisions fused on device (the masked
     variant leaves non-stepping rows' cache untouched, for heterogeneous
-    replica speeds); ``fleet_prefill`` / ``fleet_chunk`` are the admission
-    twins writing prefill state straight into the fleet slab. The ``afleet*``
-    variants are the async twins: decode operands live on device and advance
-    inside the dispatch, so the host syncs nothing until the next reconcile
-    (``afleet_block`` fuses K micro-steps per dispatch via ``lax.scan``)."""
-    __slots__ = ("prefill", "decode", "decode_hold", "fleet", "fleet_hold",
-                 "fleet_masked", "fleet_masked_hold", "fleet_prefill",
-                 "chunk", "fleet_chunk", "afleet", "afleet_hold",
-                 "afleet_masked", "afleet_masked_hold", "afleet_prefill",
-                 "afleet_chunk", "afleet_block", "_block_factory",
-                 "trace_counts")
+    replica speeds; the ``_hold`` twins keep mid-chunk slots' state);
+    ``afleet_prefill`` / ``afleet_chunk`` write admission state straight
+    into the fleet slab. The decode operands live on device and advance
+    inside each dispatch, so the host syncs nothing until the next
+    reconcile (``afleet_block`` fuses K micro-steps per dispatch via
+    ``lax.scan``)."""
+    __slots__ = ("prefill", "decode", "decode_hold", "chunk", "afleet",
+                 "afleet_hold", "afleet_masked", "afleet_masked_hold",
+                 "afleet_prefill", "afleet_chunk", "afleet_block",
+                 "_block_factory", "trace_counts")
 
     def block_kernel(self, K: int):
         """The K-micro-step fused decode kernel (jitted on demand, cached
@@ -228,8 +231,8 @@ class _ServeKernels:
 
     @property
     def prefill_traces(self) -> int:
-        """Compilations of the prefill-side variants (bucketed, fleet,
-        chunked) — the retrace-bound currency."""
+        """Compilations of the prefill-side variants (standalone and fleet,
+        bucketed and chunked) — the retrace-bound currency."""
         return sum(self.trace_counts.get(v, 0) for v in _PREFILL_VARIANTS)
 
     @property
@@ -244,7 +247,7 @@ def _dtype_name(cache_dtype) -> str:
 
 
 def _init_ops(cap: int, batch: int) -> dict:
-    """Fresh device-resident decode operands for an async fleet slab:
+    """Fresh device-resident decode operands for a fleet slab:
     per-slot next-token / cache-position / remaining-budget / eos-id /
     active-mask arrays, (cap, batch) each. Inactive rows are never read
     through (``active`` masks them), so zero init is fine."""
@@ -314,31 +317,34 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
             lambda t, h: t.at[:, hslots].set(h, mode="drop"), new, held)
         return logits, new
 
-    def _fleet_core(p, slab, toks, pos, rem, eos, active, rows=None,
-                    held=None):
-        """One dispatch for a stacked fleet. slab: cache pytree with a
-        leading fleet axis; toks/pos/rem/eos/active: (F, B). Returns the
-        next greedy token per slot, the fused retire mask, the model's
-        step counts (``Model.decode_fleet``) summed over the slots that
-        stepped, and the advanced slab. The retire rule is the exact
-        device twin of the host rule in
-        ``ReplicaEngine.finish_step``: after appending this token a slot is
-        done when it reached max_new_tokens (rem <= 1), emitted EOS, or its
-        next write index would hit the end of the cache. ``held``
-        ((hrows, hslots) index vectors for mid-chunk-prefill slots) keeps
-        those slots' state bit-for-bit by gather-before / scatter-after —
-        touching K slot rows, NOT select-copying the whole slab; with
-        ``rows`` (F,) only those fleet rows advance at all (hetero speeds):
-        the others' step commits nothing (``Model.decode``'s ``commit``).
-        Each mask combination is its own kernel variant so the common
-        all-decode path keeps the pure donated in-place update."""
+    def _afleet_core(p, slab, ops, rows=None, held=None):
+        """One decode micro-step for a stacked fleet. slab: cache pytree
+        with a leading fleet axis; ``ops``: the device-resident operands
+        toks/pos/rem/eos/active, (F, B) each, advanced inside the dispatch
+        — the device twin of ``ReplicaEngine.apply_decode``. Returns the
+        next greedy token per slot, the fused retire mask, the stepped
+        mask, the model's step counts (``Model.decode_fleet``) summed over
+        the slots that stepped, and the advanced slab and operands; nothing
+        blocks on the host. The retire rule is the exact device twin of the
+        host rule in ``ReplicaEngine.finish_step``: after appending this
+        token a slot is done when it reached max_new_tokens (rem <= 1),
+        emitted EOS, or its next write index would hit the end of the
+        cache. ``held`` ((hrows, hslots) index vectors for
+        mid-chunk-prefill slots) keeps those slots' state bit-for-bit by
+        gather-before / scatter-after — touching K slot rows, NOT
+        select-copying the whole slab; with ``rows`` (F,) only those fleet
+        rows advance at all (hetero speeds): the others' step commits
+        nothing (``Model.decode``'s ``commit``). Each mask combination is
+        its own kernel variant so the common all-decode path keeps the pure
+        donated in-place update."""
+        toks, pos, active = ops["toks"], ops["pos"], ops["active"]
         if held is not None:
             hrows, hslots = held
             kept = jax.tree.map(lambda s: s[hrows, :, hslots], slab)
-        logits, new_slab, counts = model.decode_fleet(
+        logits, slab, counts = model.decode_fleet(
             p, slab, toks[..., None], pos, rows, attn_backend=attn_backend)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        done = active & ((rem <= 1) | (nxt == eos)
+        done = active & ((ops["rem"] <= 1) | (nxt == ops["eos"])
                          | (pos + 1 >= max_seq - 1))
         stepped = active
         if rows is not None:
@@ -348,51 +354,16 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
             stepped.reshape(stepped.shape + (1,) * (c.ndim - 2)), c, 0),
             axis=(0, 1)) for n, c in counts.items()}
         if held is not None:
-            new_slab = jax.tree.map(
+            slab = jax.tree.map(
                 lambda s, h: s.at[hrows, :, hslots].set(h, mode="drop"),
-                new_slab, kept)
-        return nxt, done, counts, new_slab
-
-    def _fleet_fn(p, slab, toks, pos, rem, eos, active):
-        _count("fleet")
-        return _fleet_core(p, slab, toks, pos, rem, eos, active)
-
-    def _fleet_hold_fn(p, slab, toks, pos, rem, eos, active, hrows, hslots):
-        _count("fleet_hold")
-        return _fleet_core(p, slab, toks, pos, rem, eos, active,
-                           held=(hrows, hslots))
-
-    def _fleet_masked_fn(p, slab, toks, pos, rem, eos, active, rows):
-        """Fleet dispatch where only ``rows`` (F,) advance — other rows keep
-        their cache bit-for-bit (an SSM state must not step twice)."""
-        _count("fleet_masked")
-        return _fleet_core(p, slab, toks, pos, rem, eos, active, rows=rows)
-
-    def _fleet_masked_hold_fn(p, slab, toks, pos, rem, eos, active, rows,
-                              hrows, hslots):
-        _count("fleet_masked_hold")
-        return _fleet_core(p, slab, toks, pos, rem, eos, active, rows=rows,
-                           held=(hrows, hslots))
-
-    # ------------------------------------------------------ async variants
-    def _afleet_core(p, slab, ops, rows=None, held=None):
-        """One async decode micro-step: the operands live on device (``ops``)
-        and advance inside the dispatch — the device twin of
-        ``ReplicaEngine.apply_decode``. Returns the small sync payload
-        (next token, fused retire mask, stepped mask, step counts) plus the
-        advanced slab and operands; nothing blocks on the host."""
-        nxt, done, counts, slab = _fleet_core(
-            p, slab, ops["toks"], ops["pos"], ops["rem"], ops["eos"],
-            ops["active"], rows=rows, held=held)
-        stepped = ops["active"] if rows is None else \
-            ops["active"] & rows[:, None]
+                slab, kept)
         inc = stepped.astype(jnp.int32)
         ops = {
-            "toks": jnp.where(stepped, nxt, ops["toks"]),
-            "pos": ops["pos"] + inc,
+            "toks": jnp.where(stepped, nxt, toks),
+            "pos": pos + inc,
             "rem": ops["rem"] - inc,
             "eos": ops["eos"],
-            "active": ops["active"] & ~done,
+            "active": active & ~done,
         }
         return nxt, done, stepped, counts, slab, ops
 
@@ -415,7 +386,7 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
     def _make_block_fn(K):
         def _afleet_block_fn(p, slab, ops):
             """K fused decode micro-steps in ONE dispatch: ``lax.scan`` over
-            the async core (the retire rule is already the device twin of
+            the decode core (the retire rule is already the device twin of
             the host rule, so EOS/max-tokens/cache-full compose exactly —
             a slot retired at micro-step k is inactive for k+1..K-1). Syncs
             one (K, F, B) token/retire/stepped block and K steps' counts."""
@@ -433,10 +404,10 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
         return _afleet_block_fn
 
     def _ops_admit(ops, rows, slots, first, plen, rems, eoss):
-        """Device twin of ``commit_admit``: register admitted rows in the
-        persistent operands. A request that finishes at prefill time
-        (``rem < 1`` i.e. max_new_tokens <= 1, or first token == EOS) never
-        activates; the host learns the same outcome at reconcile."""
+        """Device twin of ``FleetGroup._apply_admit``: register admitted
+        rows in the persistent operands. A request that finishes at prefill
+        time (``rem < 1`` i.e. max_new_tokens <= 1, or first token == EOS)
+        never activates; the host learns the same outcome at reconcile."""
         return {
             "toks": ops["toks"].at[rows, slots].set(first, mode="drop"),
             "pos": ops["pos"].at[rows, slots].set(plen, mode="drop"),
@@ -448,9 +419,19 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
 
     def _afleet_prefill_fn(p, slab, ops, toks, lens, rows, slots, rems,
                            eoss):
-        """Async twin of ``_fleet_prefill_fn``: same slab scatter, plus the
-        admitted rows activate in the device operands so the same tick's
-        decode dispatch consumes their first token without a host sync."""
+        """ONE admission dispatch for every same-bucket-length admit across
+        the fleet: toks (K, sb) flattens every member's admit rows of the
+        same pow2 length bucket into one batch (K itself pow2-padded), runs
+        the exact same row-independent prefill as the standalone path, and
+        scatters each row's KV/state straight into the donated slab at
+        (fleet row ``rows[k]``, slot ``slots[k]``). Pad rows carry
+        out-of-bounds indices so their writes drop. Keeping the batch flat
+        (rather than vmapping per-member groups) keeps the retrace space at
+        O(log(F·max_batch) · log max_seq) — a stacked (groups, kb, sb)
+        signature would recompile for every fleet-size/group-count combo.
+        The admitted rows activate in the device operands, so the same
+        tick's decode dispatch consumes their first token without a host
+        sync. Returns the greedy first token per row, (K,)."""
         _count("afleet_prefill")
         logits, slab, plen = model.prefill_into(
             p, {"tokens": toks, "lengths": lens}, slab, rows, slots,
@@ -462,9 +443,11 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
 
     def _afleet_chunk_fn(p, slab, ops, toks, offs, lens, fresh, rows, slots,
                          final, rems, eoss):
-        """Async twin of ``_fleet_chunk_fn``: rows finishing their last
-        chunk activate in the device operands (non-final rows' operand
-        writes are parked out of bounds and drop)."""
+        """ONE chunk dispatch for every due chunk row across the fleet:
+        gather (fleet row, slot) state from the donated slab, advance one
+        chunk, scatter back. Rows finishing their last chunk activate in
+        the device operands (non-final rows' operand writes are parked out
+        of bounds and drop)."""
         _count("afleet_chunk")
         sub = jax.tree.map(lambda s: s[rows, :, slots].swapaxes(0, 1), slab)
         first, pos, new_sub = _chunk_core(sub, toks, offs, lens, fresh, p)
@@ -476,26 +459,6 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
         ops = _ops_admit(ops, wrows, slots, first, pos.astype(jnp.int32),
                          rems, eoss)
         return first, slab, ops
-
-    def _fleet_prefill_fn(p, slab, toks, lens, rows, slots):
-        """ONE admission dispatch for every same-bucket-length admit across
-        the fleet: toks (K, sb) flattens every member's admit rows of the
-        same pow2 length bucket into one batch (K itself pow2-padded), runs
-        the exact same row-independent prefill as the standalone path, and
-        scatters each row's KV/state straight into the donated slab at
-        (fleet row ``rows[k]``, slot ``slots[k]``). Pad rows carry
-        out-of-bounds indices so their writes drop. Keeping the batch flat
-        (rather than vmapping per-member groups) keeps the retrace space at
-        O(log(F·max_batch) · log max_seq) — a stacked (groups, kb, sb)
-        signature would recompile for every fleet-size/group-count combo.
-        Returns the greedy first token and per-row prompt length, (K,)
-        each."""
-        _count("fleet_prefill")
-        logits, slab, plen = model.prefill_into(
-            p, {"tokens": toks, "lengths": lens}, slab, rows, slots,
-            cache_len=max_seq, cache_dtype=cache_dtype)
-        first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return first, plen.astype(jnp.int32), slab
 
     def _chunk_core(state, toks, offs, lens, fresh, p):
         """Shared chunk step on gathered per-slot state (leaves (L, K, ...)):
@@ -524,35 +487,15 @@ def get_serve_kernels(model: Model, max_seq: int, cache_dtype,
             cache, new_sub)
         return first, pos, cache
 
-    def _fleet_chunk_fn(p, slab, toks, offs, lens, fresh, rows, slots):
-        """ONE chunk dispatch for every due chunk row across the fleet:
-        gather (fleet row, slot) state from the donated slab, advance one
-        chunk, scatter back."""
-        _count("fleet_chunk")
-        sub = jax.tree.map(lambda s: s[rows, :, slots].swapaxes(0, 1), slab)
-        first, pos, new_sub = _chunk_core(sub, toks, offs, lens, fresh, p)
-        slab = jax.tree.map(
-            lambda s, ns: s.at[rows, :, slots].set(
-                ns.swapaxes(0, 1).astype(s.dtype), mode="drop"),
-            slab, new_sub)
-        return first, pos, slab
-
     k.prefill = jax.jit(_prefill_fn)
     k.decode = jax.jit(_decode_fn)
     k.decode_hold = jax.jit(_decode_hold_fn)
-    # the fleet slab is owned exclusively by the FleetGroup (member engines
-    # hold cache=None), so the input buffer can be donated: XLA updates the
-    # KV slab in place instead of copying it every dispatch. The standalone
-    # chunk kernel donates the engine cache the same way.
-    k.fleet = jax.jit(_fleet_fn, donate_argnums=(1,))
-    k.fleet_hold = jax.jit(_fleet_hold_fn, donate_argnums=(1,))
-    k.fleet_masked = jax.jit(_fleet_masked_fn, donate_argnums=(1,))
-    k.fleet_masked_hold = jax.jit(_fleet_masked_hold_fn, donate_argnums=(1,))
-    k.fleet_prefill = jax.jit(_fleet_prefill_fn, donate_argnums=(1,))
+    # the standalone chunk kernel donates the engine cache: XLA updates it
+    # in place instead of copying it every dispatch
     k.chunk = jax.jit(_chunk_fn, donate_argnums=(1,))
-    k.fleet_chunk = jax.jit(_fleet_chunk_fn, donate_argnums=(1,))
-    # async variants: slab AND operands are donated (both exclusively owned
-    # by the FleetGroup), so consecutive ticks chain in place on device
+    # the fleet slab and its operands are owned exclusively by the
+    # FleetGroup (member engines hold cache=None), so both are donated and
+    # consecutive ticks chain in place on device
     k.afleet = jax.jit(_afleet_fn, donate_argnums=(1, 2))
     k.afleet_hold = jax.jit(_afleet_hold_fn, donate_argnums=(1, 2))
     k.afleet_masked = jax.jit(_afleet_masked_fn, donate_argnums=(1, 2))
@@ -708,7 +651,9 @@ class TieredQueue:
 
 class FleetGroup:
     """Stacks the device state of same-shape replicas along a leading fleet
-    axis and advances every member with one jitted dispatch per tick.
+    axis and advances every member with one jitted dispatch per tick. A
+    member is stepped only by its group; ``ReplicaEngine.step()`` on a
+    standalone engine is the reference the group is tested against.
 
     The slab capacity grows in power-of-two steps (O(log F) retraces as the
     fleet scales 1 -> F); spare rows decode throwaway state and are fully
@@ -718,18 +663,18 @@ class FleetGroup:
 
     ``admit_round`` is the admission twin of ``decode_round``: members'
     bucketed admit rows of the same pow2 length bucket flatten into ONE
-    ``fleet_prefill`` per distinct bucket, and all due chunk rows into ONE
-    ``fleet_chunk`` — each writing KV/state straight into the donated slab.
+    ``afleet_prefill`` per distinct bucket, and all due chunk rows into ONE
+    ``afleet_chunk`` — each writing KV/state straight into the donated slab.
     ``prefill_dispatches`` mirrors ``dispatches``.
 
-    With ``async_mode`` the dispatch methods never block: device results
-    queue on ``pending`` and the deferred host bookkeeping applies at the
-    next ``reconcile()`` — one blocking sync per tick (``syncs``), with the
-    decode operands persistent on device (``ops``). See the module
-    docstring's async tick contract.
+    The dispatch methods never block: device results queue on ``pending``
+    and the deferred host bookkeeping applies at the next ``reconcile()`` —
+    one blocking sync per tick (``syncs``), with the decode operands
+    persistent on device (``ops``). See the module docstring's fleet tick
+    contract.
 
     **Shard contract** (``mesh`` with a ``fleet`` axis, N = shard count).
-    The slab's leading fleet axis (and the async operands') is laid out
+    The slab's leading fleet axis (and the decode operands') is laid out
     ``NamedSharding(mesh, P('fleet'))`` — device d owns the contiguous row
     block [d·cap/N, (d+1)·cap/N) — while ``params`` replicate across the
     fleet axis, so GSPMD partitions the *existing* jitted kernel families
@@ -746,16 +691,15 @@ class FleetGroup:
         append, removals swap-backfill with the last row), so with block
         layout the F live rows spread across shards as evenly as contiguous
         blocks allow; membership changes force-flush pending futures first,
-        exactly like the unsharded async path;
+        exactly like the unsharded path;
       * **bit-identical streams** — the kernels are mesh-agnostic (sharding
         only partitions them), so token streams and finish clocks equal the
-        unsharded oracle across churn/async/chunk/tier (tests/
+        unsharded group across churn/decode-block/chunk/tier (tests/
         test_fleet_shard.py)."""
 
     def __init__(self, model: Model, params, *, max_batch: int, max_seq: int,
-                 cache_dtype=jnp.float32, async_mode: bool = False,
-                 decode_block: int = 1, attn_backend: str = "einsum",
-                 mesh=None):
+                 cache_dtype=jnp.float32, decode_block: int = 1,
+                 attn_backend: str = "einsum", mesh=None):
         self.model = model
         self.mesh = mesh
         if mesh is not None and "fleet" not in mesh.axis_names:
@@ -779,12 +723,11 @@ class FleetGroup:
         self.slab = None            # cache pytree, leaves (cap, *per_replica)
         self.dispatches = 0         # jitted fleet decode dispatches issued
         self.prefill_dispatches = 0  # jitted fleet admission dispatches
-        self.async_mode = bool(async_mode)
         self.decode_block = max(1, int(decode_block))
-        self.ops = None             # device decode operands (async mode)
+        self.ops = None             # device decode operands, (cap, B) each
         self.pending: list = []     # _Pending device results, un-synced
         self._stash: list = []      # finishes from forced flushes (churn)
-        self._admitted = False      # eager single-admit landed this tick
+        self._admitted = False      # an exact-length admit landed this tick
         self.syncs = 0              # blocking host syncs performed
         self.sync_wait = 0.0        # seconds spent blocked on device results
         self._block_credit = 0      # ticks already covered by a decode block
@@ -852,23 +795,20 @@ class FleetGroup:
             slab = self._zero_slab(new_cap)
             if self.slab is None:
                 self.slab = slab
-                if self.async_mode:
-                    self.ops = _init_ops(new_cap, self.max_batch)
+                self.ops = _init_ops(new_cap, self.max_batch)
             else:
                 self.slab = _slab_put(slab, 0, self.slab)
-                if self.async_mode:
-                    grow = lambda s: jnp.concatenate(
-                        [s, jnp.zeros((new_cap - self.cap,) + s.shape[1:],
-                                      s.dtype)])
-                    self.ops = jax.tree.map(grow, self.ops)
+                grow = lambda s: jnp.concatenate(
+                    [s, jnp.zeros((new_cap - self.cap,) + s.shape[1:],
+                                  s.dtype)])
+                self.ops = jax.tree.map(grow, self.ops)
             self.cap = new_cap
             if self.mesh is not None:
                 # re-pin after (re)allocation: the slab update above and the
                 # operands' concatenate need not keep the fleet sharding,
                 # and GSPMD must row-partition every subsequent dispatch
                 self.slab = self._place_slab(self.slab)
-                if self.async_mode:
-                    self.ops = self._place_ops(self.ops)
+                self.ops = self._place_ops(self.ops)
         if eng.cache is None:
             # a fresh replica: its slots are empty, so its row only has to
             # be cleared of a former member's state (never built whole
@@ -880,8 +820,7 @@ class FleetGroup:
                                  self.mesh is None else
                                  self._replicated(eng.cache))
         self.slab = _slab_put(self.slab, row, cache)
-        if self.async_mode:
-            self._seed_ops_row(row, eng)
+        self._seed_ops_row(row, eng)
         eng.cache = None
         eng._fleet, eng._fleet_row = self, row
         self.members.append(eng)
@@ -908,7 +847,7 @@ class FleetGroup:
         """Detach ``eng``; with ``restore`` its cache row is unstacked back
         onto the engine (drain hand-back), otherwise dropped (failure).
         Pending futures flush first (host mirrors must be current before a
-        row unstacks or backfills — the churn half of the async contract)."""
+        row unstacks or backfills — the churn half of the tick contract)."""
         if self.pending:
             self._stash += self.reconcile(force=True)
         row = eng._fleet_row
@@ -926,49 +865,48 @@ class FleetGroup:
             n = len(self.members)
             self.slab = _slab_put(self.slab, row, jax.tree.map(
                 lambda s: s[n:n + 1], self.slab))
-            if self.async_mode:
-                self.ops = jax.tree.map(lambda s: s.at[row].set(s[n]),
-                                        self.ops)
+            self.ops = jax.tree.map(lambda s: s.at[row].set(s[n]), self.ops)
             last._fleet_row = row
             self.members[row] = last
         eng._fleet, eng._fleet_row = None, -1
 
     # -------------------------------------------------------------- slots
     def write_slot(self, f: int, slot: int, small_state, row: int,
-                   req: Optional["Request"] = None, prompt_len: int = 0):
+                   req: "Request", prompt_len: int):
         """Copy prefill output row ``row`` into member ``f``'s slot (the
         exact-length single-admit path; bucketed admits scatter on device
-        inside ``fleet_prefill`` instead). In async mode the slot also
-        registers in the device operands (``req``'s first token was already
-        synced by the eager single-admit path)."""
+        inside ``afleet_prefill`` instead) and register the slot in the
+        device operands (``req``'s first token was already fetched by the
+        single-admit path)."""
         if self.mesh is not None:
             small_state = self._replicated(small_state)
         self.slab = jax.tree.map(
             lambda s, sm: s.at[f, :, slot].set(sm[:, row]),
             self.slab, small_state)
-        if self.async_mode and req is not None:
-            o = self.ops
-            self.ops = {
-                "toks": o["toks"].at[f, slot].set(int(req.output[-1])),
-                "pos": o["pos"].at[f, slot].set(int(prompt_len)),
-                "rem": o["rem"].at[f, slot].set(
-                    req.rem_tokens(self.members[f].clock)),
-                "eos": o["eos"].at[f, slot].set(int(req.eos_id)),
-                "active": o["active"].at[f, slot].set(True),
-            }
-            # single admits bypass ``pending`` (their sync was eager), so
-            # they must veto fused-block engagement separately — a tick
-            # that admitted anything never fuses
-            self._admitted = True
+        o = self.ops
+        self.ops = {
+            "toks": o["toks"].at[f, slot].set(int(req.output[-1])),
+            "pos": o["pos"].at[f, slot].set(int(prompt_len)),
+            "rem": o["rem"].at[f, slot].set(
+                req.rem_tokens(self.members[f].clock)),
+            "eos": o["eos"].at[f, slot].set(int(req.eos_id)),
+            "active": o["active"].at[f, slot].set(True),
+        }
+        # single admits bypass ``pending`` (their fetch is immediate), so
+        # they must veto fused-block engagement separately — a tick that
+        # admitted anything never fuses
+        self._admitted = True
 
     # -------------------------------------------------------------- admit
     def admit_round(self, stepping_ids=None) -> list:
         """One fused admission step for every member (or the ``id(engine)``
         subset in ``stepping_ids``): plan each member's admissions on the
         host, then flatten same-length-bucket admit rows into one
-        ``fleet_prefill`` per distinct bucket and all due chunk rows into
-        one ``fleet_chunk``. Exact-length single admits (extras / moe) keep
-        the per-request path. Returns requests finished at prefill time."""
+        ``afleet_prefill`` per distinct bucket and all due chunk rows into
+        one ``afleet_chunk``. Exact-length single admits (extras / moe) keep
+        the per-request path. Returns requests finished at prefill time
+        (expired queue heads and single admits; the batched admits commit
+        at the next ``reconcile``)."""
         movers = [e for e in self.members
                   if stepping_ids is None or id(e) in stepping_ids]
         finished: list = []
@@ -991,13 +929,12 @@ class FleetGroup:
             for row in e._chunk_rows():
                 chunk_rows.append((e,) + row)
         for sb, entries in sorted(buckets.items()):
-            self._dispatch_fleet_prefill(sb, entries, finished)
+            self._dispatch_fleet_prefill(sb, entries)
         if chunk_rows:
-            self._dispatch_fleet_chunk(chunk_rows, finished)
+            self._dispatch_fleet_chunk(chunk_rows)
         return finished
 
-    def _dispatch_fleet_prefill(self, sb: int, entries: list,
-                                finished: list):
+    def _dispatch_fleet_prefill(self, sb: int, entries: list):
         K = pow2_bucket(len(entries))
         toks = np.zeros((K, sb), np.int32)
         lens = np.ones(K, np.int32)             # pad rows: length-1 dummies
@@ -1011,29 +948,18 @@ class FleetGroup:
             rows[i], slots[i] = e._fleet_row, slot
             rems[i] = req.rem_tokens(e.clock) - 1
             eoss[i] = req.eos_id
-        if self.async_mode:
-            first, self.slab, self.ops = self._kernels.afleet_prefill(
-                self.params, self.slab, self.ops, jnp.asarray(toks),
-                jnp.asarray(lens), jnp.asarray(rows), jnp.asarray(slots),
-                jnp.asarray(rems), jnp.asarray(eoss))
-            self.prefill_dispatches += 1
-            meta = []
-            for i, (e, slot, req, p) in enumerate(entries):
-                e.slots[slot] = req      # reserve now; commit at reconcile
-                meta.append((i, e, slot, req, len(p), e.clock))
-            self.pending.append(_Pending("prefill", first, meta))
-            return
-        first, plen, self.slab = self._kernels.fleet_prefill(
-            self.params, self.slab, jnp.asarray(toks), jnp.asarray(lens),
-            jnp.asarray(rows), jnp.asarray(slots))
+        first, self.slab, self.ops = self._kernels.afleet_prefill(
+            self.params, self.slab, self.ops, jnp.asarray(toks),
+            jnp.asarray(lens), jnp.asarray(rows), jnp.asarray(slots),
+            jnp.asarray(rems), jnp.asarray(eoss))
         self.prefill_dispatches += 1
-        first, plen = timed_get(self, (first, plen))
-        first, plen = np.asarray(first), np.asarray(plen)
+        meta = []
         for i, (e, slot, req, p) in enumerate(entries):
-            e.commit_admit([slot], [req], first[i:i + 1], plen[i:i + 1],
-                           finished)
+            e.slots[slot] = req          # reserve now; commit at reconcile
+            meta.append((i, e, slot, req, len(p), e.clock))
+        self.pending.append(_Pending("prefill", first, meta))
 
-    def _dispatch_fleet_chunk(self, chunk_rows: list, finished: list):
+    def _dispatch_fleet_chunk(self, chunk_rows: list):
         # members may carry different chunk_len settings; each width is its
         # own fixed kernel shape
         by_width: dict = {}
@@ -1046,117 +972,47 @@ class FleetGroup:
             slots = np.full(K, self.max_batch, np.int32)
             for i, (e, slot, *_rest) in enumerate(items):
                 rows[i], slots[i] = e._fleet_row, slot
-            if self.async_mode:
-                final = np.zeros(K, bool)
-                rems = np.zeros(K, np.int32)
-                eoss = np.full(K, -1, np.int32)
-                for i, (e, slot, t, off, ln, fr, fin) in enumerate(items):
-                    req = e._chunks[slot].req
-                    final[i] = fin
-                    rems[i] = req.rem_tokens(e.clock) - 1
-                    eoss[i] = req.eos_id
-                first, self.slab, self.ops = self._kernels.afleet_chunk(
-                    self.params, self.slab, self.ops, jnp.asarray(toks),
-                    jnp.asarray(offs), jnp.asarray(lens), jnp.asarray(fresh),
-                    jnp.asarray(rows), jnp.asarray(slots),
-                    jnp.asarray(final), jnp.asarray(rems), jnp.asarray(eoss))
-                self.prefill_dispatches += 1
-                meta = []
-                for i, (e, slot, t, off, ln, fr, fin) in enumerate(items):
-                    cur = e._chunks[slot]
-                    if not fin:          # cursor advance is host-computable
-                        cur.consumed += e.chunk_len
-                        continue
-                    del e._chunks[slot]  # slot stays reserved (slots[slot])
-                    meta.append((i, e, slot, cur.req, off + ln, e.clock))
-                if meta:
-                    self.pending.append(_Pending("chunk", first, meta))
-                continue
-            first, pos, self.slab = self._kernels.fleet_chunk(
-                self.params, self.slab, jnp.asarray(toks), jnp.asarray(offs),
-                jnp.asarray(lens), jnp.asarray(fresh), jnp.asarray(rows),
-                jnp.asarray(slots))
-            self.prefill_dispatches += 1
-            first, pos = timed_get(self, (first, pos))
-            first, pos = np.asarray(first), np.asarray(pos)
+            final = np.zeros(K, bool)
+            rems = np.zeros(K, np.int32)
+            eoss = np.full(K, -1, np.int32)
             for i, (e, slot, t, off, ln, fr, fin) in enumerate(items):
-                e.commit_chunk(slot, first[i], pos[i], fin, finished)
+                req = e._chunks[slot].req
+                final[i] = fin
+                rems[i] = req.rem_tokens(e.clock) - 1
+                eoss[i] = req.eos_id
+            first, self.slab, self.ops = self._kernels.afleet_chunk(
+                self.params, self.slab, self.ops, jnp.asarray(toks),
+                jnp.asarray(offs), jnp.asarray(lens), jnp.asarray(fresh),
+                jnp.asarray(rows), jnp.asarray(slots),
+                jnp.asarray(final), jnp.asarray(rems), jnp.asarray(eoss))
+            self.prefill_dispatches += 1
+            meta = []
+            for i, (e, slot, t, off, ln, fr, fin) in enumerate(items):
+                cur = e._chunks[slot]
+                if not fin:              # cursor advance is host-computable
+                    cur.consumed += e.chunk_len
+                    continue
+                del e._chunks[slot]      # slot stays reserved (slots[slot])
+                meta.append((i, e, slot, cur.req, off + ln, e.clock))
+            if meta:
+                self.pending.append(_Pending("chunk", first, meta))
 
     # -------------------------------------------------------------- decode
-    def decode_round(self, stepping_ids=None, allow_block: bool = False
-                     ) -> list:
+    def decode_round(self, stepping_ids=None, allow_block: bool = False):
         """One fused decode step for every member (or the ``id(engine)``
-        subset in ``stepping_ids``). Returns finished requests. Eager: one
-        jitted dispatch plus one small (F, B) host sync. Async: one jitted
-        dispatch, NO sync (results commit at the next ``reconcile``), and
-        with ``allow_block`` a K-micro-step fused block may engage on a
-        tick that admitted nothing — covering the next K-1 ticks' decode
-        in this single dispatch."""
+        subset in ``stepping_ids``): one jitted dispatch and NO sync — the
+        results commit at the next ``reconcile``. The operands already live
+        on device, so the dispatch takes only the cheap host-known masks
+        (held chunk slots, stepping rows). With ``allow_block`` a
+        K-micro-step fused block may engage on a tick that admitted nothing,
+        covering the next K-1 ticks' decode in this single dispatch."""
         movers = [e for e in self.members
                   if stepping_ids is None or id(e) in stepping_ids]
-        if self.async_mode:
-            return self._decode_round_async(movers, allow_block)
-        if not movers or not any(e.n_decoding for e in movers):
-            return []
-        cap, B = self.cap, self.max_batch
-        toks = np.zeros((cap, B), np.int32)
-        pos = np.zeros((cap, B), np.int32)
-        rem = np.ones((cap, B), np.int32)
-        eos = np.full((cap, B), -1, np.int32)
-        active = np.zeros((cap, B), bool)
-        rows = np.zeros((cap,), bool)
-        held: list = []              # mid-chunk (row, slot): state must not
-        for e in movers:             # move this round
-            f = e._fleet_row
-            rows[f] = True
-            toks[f] = e.last_tok
-            pos[f] = e.pos
-            held.extend((f, s) for s in e._chunks)
-            for s, req in enumerate(e.slots):
-                if req is not None and s not in e._chunks:
-                    active[f, s] = True
-                    rem[f, s] = req.rem_tokens(e.clock)
-                    eos[f, s] = req.eos_id
-        if held:                     # pow2-padded OOB -> dropped on scatter
-            hk = pow2_bucket(len(held))
-            hrows = np.full(hk, cap, np.int32)
-            hslots = np.full(hk, B, np.int32)
-            for i, (f, s) in enumerate(held):
-                hrows[i], hslots[i] = f, s
-        if len(movers) == len(self.members):
-            if held:
-                nxt, done, counts, self.slab = self._kernels.fleet_hold(
-                    self.params, self.slab, toks, pos, rem, eos, active,
-                    hrows, hslots)
-            else:
-                nxt, done, counts, self.slab = self._kernels.fleet(
-                    self.params, self.slab, toks, pos, rem, eos, active)
-        elif held:
-            nxt, done, counts, self.slab = self._kernels.fleet_masked_hold(
-                self.params, self.slab, toks, pos, rem, eos, active, rows,
-                hrows, hslots)
-        else:
-            nxt, done, counts, self.slab = self._kernels.fleet_masked(
-                self.params, self.slab, toks, pos, rem, eos, active, rows)
-        self.dispatches += 1
-        nxt, done, counts = timed_get(self, (nxt, done, counts))  # ONE sync
-        nxt, done = np.asarray(nxt), np.asarray(done)
-        self._add_counts(counts)
-        finished: list = []
-        for e in movers:
-            f = e._fleet_row
-            finished.extend(e.commit_decode(nxt[f], done[f]))
-        return finished
-
-    def _decode_round_async(self, movers: list, allow_block: bool) -> list:
-        """Sync-free decode round: operands already live on device, so the
-        dispatch takes only the cheap host-known masks (held chunk slots,
-        stepping rows). Results queue on ``pending``."""
         if self._block_credit > 0:      # a fused block covers this tick
             self._block_credit -= 1
-            return []
+            return
         if not movers or not any(e.n_decoding for e in movers):
-            return []
+            return
         cap, B = self.cap, self.max_batch
         held = [(e._fleet_row, s) for e in movers for s in e._chunks]
         full = len(movers) == len(self.members)
@@ -1165,13 +1021,13 @@ class FleetGroup:
         # fused-block engagement: only on ticks with no admissions at all —
         # ``pending`` catches this tick's fleet prefill/chunk dispatches
         # (the tick-start reconcile cleared the previous window) and
-        # ``_admitted`` the eager single-admit path — and no chunk cursors
-        # anywhere. Queued work behind a FULL slab does not block
+        # ``_admitted`` the exact-length single-admit path — and no chunk
+        # cursors anywhere. Queued work behind a FULL slab does not block
         # engagement: any admission landing inside the fused window (a
         # retire freeing a slot, or an arrival finding one) only starts
         # decoding at the window's end, i.e. admission-to-first-decode may
-        # lag up to K-1 ticks (the documented decode_block trade; async
-        # with K=1 keeps the <= 1-tick bound)
+        # lag up to K-1 ticks (the documented decode_block trade; K=1
+        # keeps the <= 1-tick bound)
         admitted, self._admitted = self._admitted, False
         if (allow_block and K > 1 and full and not held and not self.pending
                 and not admitted
@@ -1183,7 +1039,7 @@ class FleetGroup:
             self._block_credit = K - 1
             self.pending.append(_Pending("block",
                                          (nxt, done, stepped, counts), meta))
-            return []
+            return
         if held:                     # pow2-padded OOB -> dropped on scatter
             hk = pow2_bucket(len(held))
             hrows = np.full(hk, cap, np.int32)
@@ -1210,7 +1066,6 @@ class FleetGroup:
         self.dispatches += 1
         self.pending.append(_Pending("decode", (nxt, done, stepped, counts),
                                      meta))
-        return []
 
     # ----------------------------------------------------------- reconcile
     def take_stash(self) -> list:
@@ -1224,7 +1079,7 @@ class FleetGroup:
         """The ONE blocking host sync per tick: fetch every pending device
         result together and apply the deferred host bookkeeping in dispatch
         order (prefill first-tokens before the same tick's decode tokens —
-        the exact replay of the eager host effects, one tick late). Returns
+        the per-replica reference's host effects, one tick late). Returns
         newly finished requests, stamped with their dispatch-time clocks.
         While a decode block still covers upcoming ticks the fetch is
         deferred (that is the < 1 sync/tick regime) unless ``force``d by
@@ -1274,8 +1129,9 @@ class FleetGroup:
                                                stepped[k, row], clock + k))
 
     def _apply_admit(self, first, meta: list, finished: list):
-        """Deferred ``commit_admit``/final-chunk ``commit_chunk``: the slot
-        was reserved at dispatch (and non-final chunk cursor advances were
+        """Deferred admission commit (the fleet twin of ``_admit_batch``'s
+        host half and of a final ``commit_chunk``): the slot was reserved at
+        dispatch (and non-final chunk cursor advances were
         committed host-side there); now the first generated token, the TTFT
         stamp and the finish-at-prefill rule apply. ``pos`` in the meta is
         the host-computed cache frontier (prompt length, or chunk offset +
@@ -1305,8 +1161,8 @@ def total_prefill_traces(engines) -> int:
 
 def total_serve_traces(engines) -> int:
     """Global compile count across *every* serve-kernel variant (prefill,
-    decode, decode_hold, fleet, fleet_masked, fleet_prefill, chunk,
-    fleet_chunk), deduped across replicas sharing kernels."""
+    decode, decode_hold, chunk and the ``afleet*`` fleet variants), deduped
+    across replicas sharing kernels."""
     seen = {id(e._kernels): e._kernels.total_traces for e in engines}
     return sum(seen.values())
 
@@ -1321,7 +1177,7 @@ class Request:
     tier: str = "standard"         # SLO tier name (see workload.trace)
     # deadline (absolute tick, None = no deadline): past it the request is
     # worthless to its client — in-flight slots retire through the existing
-    # fleet/afleet ``rem <= 1`` rule (the host clamps the remaining-token
+    # decode ``rem <= 1`` rule (the host clamps the remaining-token
     # budget, see ``rem_tokens``; no new kernels, no extra dispatches) and
     # queued copies are culled at admission time. Deadlines are denominated
     # in ticks and enforced at one decode step per tick; a speed>1 replica's
@@ -1356,12 +1212,12 @@ class Request:
                 and (not self.output or self.output[-1] != self.eos_id))
 
     def rem_tokens(self, clock: float) -> int:
-        """Remaining-token budget at ``clock`` — the value the fleet/afleet
+        """Remaining-token budget at ``clock`` — the value the fleet decode's
         retire rule consumes as ``rem``. Without a deadline this is exactly
         the historical ``max_new_tokens - len(output)``; with one, it is
         additionally clamped so the slot retires (``rem <= 1``) no later
         than the deadline tick. Both budgets decrement one per decode step,
-        so a value seeded once into the async device operands stays the
+        so a value seeded once into the device decode operands stays the
         exact min at every later micro-step."""
         rem = self.max_new_tokens - len(self.output)
         if self.deadline_tick is not None:
@@ -1567,25 +1423,6 @@ class ReplicaEngine:
                 continue
             self._insert_slot(slot, small, i, int(plen[i]), tok, req)
 
-    def commit_admit(self, slots: list, reqs: list, first, plen,
-                     finished: list):
-        """Apply a fleet-prefill result: the slab rows were already written
-        on device, so only the host bookkeeping (first token, TTFT, retire
-        or register) remains. A request that finishes at prefill time leaves
-        stale state in the slab — harmless, exactly like slot reuse."""
-        for i, (slot, req) in enumerate(zip(slots, reqs)):
-            tok = int(first[i])
-            req.output.append(tok)
-            req.first_token_time = self.clock
-            if len(req.output) >= req.max_new_tokens or tok == req.eos_id \
-                    or req.out_of_time(self.clock):
-                req.finish_time = self.clock
-                finished.append(req)
-                continue
-            self.pos[slot] = int(plen[i])
-            self.last_tok[slot] = tok
-            self.slots[slot] = req
-
     # ------------------------------------------------------------ admission
     def _chunkable(self, req: Request) -> bool:
         return (self.chunk_len > 0
@@ -1595,15 +1432,15 @@ class ReplicaEngine:
     def plan_admission(self) -> _AdmitPlans:
         """Pop admittable queue heads into reserved slots WITHOUT
         dispatching — the shared host half of both the standalone and the
-        fleet-batched admission paths (identical plans keep the two modes in
-        lockstep). Queue heads come out in the tiered weighted-deficit order
-        (see ``TieredQueue``): high-weight tiers admit first, low-weight
-        tiers keep a bounded share. Chunk-eligible prompts just reserve a
-        slot + cursor; their first chunk runs in this step's chunk round —
-        but a lower-tier chunk start *yields* the last free slot while
-        higher-priority work is waiting (a long batch-tier prefill would
-        otherwise hold the slot for ceil(len/chunk) ticks and lock premium
-        traffic out)."""
+        fleet-batched admission paths (identical plans keep the fleet in
+        lockstep with the per-replica reference). Queue heads come out in
+        the tiered weighted-deficit order (see ``TieredQueue``): high-weight
+        tiers admit first, low-weight tiers keep a bounded share.
+        Chunk-eligible prompts just reserve a slot + cursor; their first
+        chunk runs in this step's chunk round — but a lower-tier chunk start
+        *yields* the last free slot while higher-priority work is waiting (a
+        long batch-tier prefill would otherwise hold the slot for
+        ceil(len/chunk) ticks and lock premium traffic out)."""
         plans = _AdmitPlans([], [])
         if self.draining:
             return plans
@@ -1721,13 +1558,9 @@ class ReplicaEngine:
 
     def _chunk_step(self, finished: list):
         """Advance every mid-chunk slot by one chunk in ONE batched
-        dispatch (fleet members route through the fleet slab kernel)."""
+        dispatch."""
         rows = self._chunk_rows()
         if not rows:
-            return
-        if self._fleet is not None:
-            self._fleet._dispatch_fleet_chunk(
-                [(self,) + row for row in rows], finished)
             return
         K, toks, offs, lens, fresh = _pack_chunk_rows(
             [(t, off, ln, fr) for _, t, off, ln, fr, _ in rows],
@@ -1746,24 +1579,24 @@ class ReplicaEngine:
             self.commit_chunk(slot, first[i], pos[i], fin, finished)
 
     # ------------------------------------------------------------- stepping
-    def begin_step(self, dt: float = 1.0, admit: bool = True) -> list:
-        """Tick phase 1: advance the clock and admit from the queue. Returns
-        requests that completed at prefill time. With ``admit=False`` only
-        the clock moves — the caller batches admission across the fleet via
-        ``FleetGroup.admit_round``. The decode phase follows via
-        ``finish_step`` (standalone) or one ``FleetGroup.decode_round``."""
+    def begin_step(self, dt: float = 1.0) -> list:
+        """Tick phase 1: advance the clock and, for a standalone engine,
+        admit from the queue. Returns requests that completed at prefill
+        time. A fleet member only moves its clock: its group batches
+        admission (``FleetGroup.admit_round``) and decode
+        (``FleetGroup.decode_round``) across every member. The standalone
+        decode phase follows via ``finish_step``."""
         self.clock += dt
         finished: list = []
-        if admit:
+        if self._fleet is None:
             self._admit(finished)
             self._chunk_step(finished)
         return finished
 
     def finish_step(self) -> list:
         """Tick phase 2: one decode step for all active (non-chunking)
-        slots."""
-        if self._fleet is not None:    # device state lives in the fleet slab
-            return self._fleet.decode_round({id(self)})
+        slots of a standalone engine."""
+        assert self._fleet is None, "a fleet member is stepped by its group"
         if self.n_decoding == 0:
             return []
         toks = jnp.asarray(self.last_tok[:, None])
@@ -1796,32 +1629,9 @@ class ReplicaEngine:
                 self.slots[slot] = None
         return finished
 
-    def commit_decode(self, next_toks: np.ndarray, done: np.ndarray) -> list:
-        """Apply one fleet decode result to the host-side slot bookkeeping.
-        ``next_toks``/``done`` are this engine's (B,) rows of the batched
-        sync; the retire mask was already computed on device. Mid-chunk
-        slots were held on device and are skipped here."""
-        finished: list = []
-        stepped = False
-        for slot, req in enumerate(self.slots):
-            if req is None or slot in self._chunks:
-                continue
-            stepped = True
-            tok = int(next_toks[slot])
-            req.output.append(tok)
-            self.pos[slot] += 1
-            self.last_tok[slot] = tok
-            if done[slot]:
-                req.finish_time = self.clock
-                finished.append(req)
-                self.slots[slot] = None
-        if stepped:
-            self.steps += 1
-        return finished
-
     def apply_decode(self, nxt: np.ndarray, done: np.ndarray,
                      stepped: np.ndarray, clock: float) -> list:
-        """Apply one *async* fleet decode result at reconcile time: the
+        """Apply one fleet decode result at reconcile time: the
         device's ``stepped`` mask (not the possibly-stale host view) says
         which slots advanced, and ``clock`` is the dispatch-time clock that
         stamps finishes. Host mirrors update vectorized (numpy
@@ -1877,18 +1687,13 @@ def normalize_fractions(fr: np.ndarray, mask: Optional[np.ndarray] = None
 
 
 class ClusterFrontend:
-    """Routes requests to replicas via balancer fractions (or queue depth).
-
-    ``fleet_batch=True`` stacks same-shape replicas into ``FleetGroup``s so a
-    ``step`` issues one decode dispatch per group instead of one per replica
-    (replicas that can't stack — different shapes — keep stepping solo).
-    ``fleet_prefill`` (default: follows ``fleet_batch``) batches admission
-    the same way: one prefill dispatch per distinct bucket shape per group;
-    set it False to keep per-replica admission as the parity oracle."""
+    """Routes requests to standalone replicas via balancer fractions (or
+    queue depth) and steps each one with ``ReplicaEngine.step()`` — the
+    static drain mode. Fleet groups are built by
+    ``repro.serving.elastic.ElasticClusterFrontend``."""
 
     def __init__(self, replicas: list, policy: str = "lc",
-                 fractions_fn=None, seed: int = 0, fleet_batch: bool = False,
-                 fleet_prefill: Optional[bool] = None, mesh=None):
+                 fractions_fn=None, seed: int = 0):
         self.replicas = replicas
         self.policy = policy
         self.fractions_fn = fractions_fn
@@ -1896,19 +1701,6 @@ class ClusterFrontend:
         self.pending: deque = deque()
         self.finished: list = []
         self._rr = itertools.cycle(range(len(replicas)))
-        self.fleets: dict = {}
-        self.mesh = mesh
-        self.fleet_prefill = fleet_batch if fleet_prefill is None \
-            else (fleet_prefill and fleet_batch)
-        if fleet_batch:
-            for eng in replicas:
-                g = self.fleets.get(eng.fleet_key)
-                if g is None:
-                    g = self.fleets[eng.fleet_key] = FleetGroup(
-                        eng.model, eng.params, max_batch=eng.max_batch,
-                        max_seq=eng.max_seq, cache_dtype=eng.cache_dtype,
-                        attn_backend=eng.attn_backend, mesh=mesh)
-                g.add(eng)
 
     def submit(self, req: Request):
         self.pending.append(req)
@@ -1930,21 +1722,8 @@ class ClusterFrontend:
 
     def step(self, dt: float = 1.0):
         self._route()
-        if not self.fleets:
-            for r in self.replicas:
-                self.finished.extend(r.step(dt))
-            return
         for r in self.replicas:
-            self.finished.extend(r.begin_step(
-                dt, admit=r._fleet is None or not self.fleet_prefill))
-        if self.fleet_prefill:
-            for g in self.fleets.values():
-                self.finished.extend(g.admit_round())
-        for g in self.fleets.values():
-            self.finished.extend(g.decode_round())
-        for r in self.replicas:          # replicas outside any fleet
-            if r._fleet is None:
-                self.finished.extend(r.finish_step())
+            self.finished.extend(r.step(dt))
 
     def run_until_drained(self, max_steps: int = 10_000):
         for _ in range(max_steps):

@@ -1,26 +1,28 @@
-"""Overlapped async serving tick: late single sync, fused decode blocks,
+"""Overlapped fleet serving tick: late single sync, fused decode blocks,
 device-resident operands, and the Pallas decode-attention backend.
 
-Acceptance coverage for the async tick contract (see ``serving.engine``
-module docstring):
+Acceptance coverage for the fleet tick contract (see ``serving.engine``
+module docstring), against the per-replica loop (``fleet_batch=False``,
+each replica stepped by ``ReplicaEngine.step()``) as the reference:
 
-  * **bit-parity** — async mode produces identical token streams and finish
-    ticks to the eager oracle (``async_tick=False``) for the dense, ssm and
-    hybrid families, including the churn matrix: failure evacuation,
-    graceful drain, scale-up joins, and continuous arrivals with
-    provisioning — all with device futures pending when membership changes;
+  * **bit-parity** — the fleet tick produces identical token streams and
+    finish ticks to the per-replica loop, including continuous arrivals
+    with provisioning and scale churn, chunked admission, SLO tiers and
+    moe single admits; under ``decode_block=4`` the dense, ssm and hybrid
+    families keep identical streams through failure evacuation, graceful
+    drain and scale-up joins — all with device futures pending when
+    membership changes;
   * **admission-lag bound** — a slot freed by tick t's decode is re-admitted
-    at tick t+1 under a full slab, exactly like the eager path (the host
-    observes device state at most one tick late, admission never lags the
-    oracle);
-  * **sync bound** — steady-state async ticks cost ONE blocking host sync
-    (``metrics()['syncs']``) while the eager path pays one per decode round
-    plus one per admission dispatch;
+    at tick t+1 under a full slab, exactly like the per-replica loop (the
+    host observes device state at most one tick late, admission never lags
+    the reference);
+  * **sync bound** — steady-state ticks cost ONE blocking host sync
+    (``metrics()['syncs']``), admissions included;
   * **decode_block** — K fused micro-steps per dispatch are bit-exact vs K
     single steps, drop syncs/tick below 1, and never engage while work is
     waiting (so admission latency is untouched);
-  * **moe single-admit path** — exact-length admits keep parity in async
-    mode (the eager single-admit sync + device-operand registration);
+  * **moe single-admit path** — exact-length admits keep parity (their
+    immediate prefill fetch + device-operand registration);
   * **pallas backend** — ``attn_backend="pallas"`` decodes through the
     flash-decode kernel (CPU interpret mode) with per-row cache positions,
     matching the dense einsum path stream-for-stream.
@@ -67,9 +69,12 @@ def _snap_fe(fe):
 @pytest.mark.parametrize("arch", ["granite-3-8b", "mamba2-1.3b",
                                   "zamba2-2.7b"])
 def test_async_matches_eager_across_churn(arch):
-    """Async vs eager through the full churn matrix — failure (progress
-    reset with futures in flight), drain, scale-up — must be bit-identical
-    in streams AND finish clocks for dense/ssm/hybrid."""
+    """Fused decode blocks (``decode_block=4``) through the full churn
+    matrix — failure (progress reset with a block in flight), drain,
+    scale-up — against the per-replica loop: identical token streams for
+    dense/ssm/hybrid, first tokens and finishes at most K-1 ticks late, and
+    fewer blocking syncs than ticks."""
+    K = 4
     c = get_config(arch).reduced()
     m = make_model(c, tp=1)
     params = m.init(jax.random.PRNGKey(0), jnp.float32)
@@ -78,10 +83,10 @@ def test_async_matches_eager_across_churn(arch):
         return ReplicaEngine(m, params, max_batch=2, max_seq=MAX_SEQ,
                              rid=rid)
 
-    def run(async_tick):
+    def run(fleet):
         fe = ElasticClusterFrontend(factory, 2, initial_replicas=2, seed=0,
-                                    async_tick=async_tick)
-        reqs = _make_reqs(10)
+                                    fleet_batch=fleet, decode_block=K)
+        reqs = _make_reqs(10, n_new=12)
         for r in reqs:
             fe.submit(r)
         fe.tick(0.0)
@@ -91,16 +96,19 @@ def test_async_matches_eager_across_churn(arch):
         fe.tick(0.0)
         fe.scale_to(np.array([2, 2]))
         fe.run_until_drained()
-        return _snap(reqs), fe
+        return reqs, fe
 
-    eager, fe_e = run(False)
-    async_, fe_a = run(True)
-    assert eager == async_
-    # async mode paid strictly fewer blocking syncs for the same work
-    assert fe_a.sync_count() < fe_e.sync_count()
+    ref, _ = run(False)
+    blocked, fe_b = run(True)
+    for rr, rb in zip(ref, blocked):
+        assert rb.output == rr.output
+        assert 0 <= rb.first_token_time - rr.first_token_time <= K - 1
+        assert 0 <= rb.finish_time - rr.finish_time <= K - 1
+    # the fused windows engaged across the churn
+    assert fe_b.sync_count() < fe_b.t
 
 
-def _arrivals_and_scaling(m, params, async_tick):
+def _arrivals_and_scaling(m, params, fleet):
     """Continuous arrivals, cold-start provisioning and scale-down/up
     churn; returns the finished streams and finish ticks."""
     def factory(rid):
@@ -113,7 +121,7 @@ def _arrivals_and_scaling(m, params, async_tick):
     fe = ElasticClusterFrontend(factory, 2, initial_replicas=1,
                                 provisioning_delay=2,
                                 request_factory=rf, seed=0,
-                                est_tokens=4, async_tick=async_tick)
+                                est_tokens=4, fleet_batch=fleet)
     for t in range(24):
         fe.tick(1.6)
         if t == 5:
@@ -127,9 +135,10 @@ def _arrivals_and_scaling(m, params, async_tick):
 
 
 def test_async_matches_eager_with_arrivals_and_scaling(setup):
-    """Continuous arrivals + cold-start provisioning + scale-down/up churn:
-    the regression scenario where a mid-tick force-flush (drained-replica
-    retirement) must not lose or reorder finishes."""
+    """Continuous arrivals + cold-start provisioning + scale-down/up churn,
+    fleet tick vs the per-replica loop: the regression scenario where a
+    mid-tick force-flush (drained-replica retirement) must not lose or
+    reorder finishes."""
     c, m, params = setup
     assert _arrivals_and_scaling(m, params, True) == \
         _arrivals_and_scaling(m, params, False)
@@ -159,9 +168,10 @@ def test_streams_unchanged_under_profiler(setup, tmp_path):
 
 
 def test_moe_single_admit_async_parity():
-    """moe replicas use exact-length single admits (eager per-request
-    prefill sync + device-operand registration via ``write_slot``) — the
-    async decode around them must still match the eager oracle."""
+    """moe replicas use exact-length single admits (an immediate
+    per-request prefill fetch + device-operand registration via
+    ``write_slot``) — the fleet decode around them must still match the
+    per-replica loop."""
     c = get_config("grok-1-314b").reduced()
     m = make_model(c, tp=1)
     params = m.init(jax.random.PRNGKey(0), jnp.float32)
@@ -170,9 +180,9 @@ def test_moe_single_admit_async_parity():
         return ReplicaEngine(m, params, max_batch=2, max_seq=MAX_SEQ,
                              rid=rid)
 
-    def run(async_tick):
+    def run(fleet):
         fe = ElasticClusterFrontend(factory, 1, initial_replicas=2, seed=0,
-                                    async_tick=async_tick)
+                                    fleet_batch=fleet)
         reqs = _make_reqs(5, n_new=4, seed=11)
         for r in reqs:
             fe.submit(r)
@@ -185,19 +195,19 @@ def test_moe_single_admit_async_parity():
 # -------------------------------------------------------- admission timing
 def test_admission_lag_bound_under_full_slab(setup):
     """A queued request waiting on a full slab admits on the tick right
-    after a slot retires — identical to the eager oracle (retire/slot-free
-    reconciles BEFORE admission planning, so the host's one-tick-stale view
-    never delays an admission)."""
+    after a slot retires — identical to the per-replica loop (retire/slot-
+    free reconciles BEFORE admission planning, so the host's
+    one-tick-stale view never delays an admission)."""
     c, m, params = setup
 
     def factory(rid):
         return ReplicaEngine(m, params, max_batch=2, max_seq=MAX_SEQ,
                              rid=rid)
 
-    def run(async_tick):
+    def run(fleet):
         fe = ElasticClusterFrontend(factory, 1, initial_replicas=1, seed=0,
                                     max_replicas_per_node=1,
-                                    async_tick=async_tick)
+                                    fleet_batch=fleet)
         # 2 slots; first two requests fill the slab, the third waits
         short = [Request(0, [5, 6, 7], max_new_tokens=3),
                  Request(1, [8, 9, 10], max_new_tokens=5),
@@ -210,27 +220,25 @@ def test_admission_lag_bound_under_full_slab(setup):
                 break
         return [(r.first_token_time, r.finish_time) for r in short]
 
-    eager = run(False)
-    async_ = run(True)
-    assert eager == async_
+    ref = run(False)
+    assert run(True) == ref
     # the waiting request admitted exactly one tick after the first retire
-    finish0 = eager[0][1]
-    assert eager[2][0] == finish0 + 1
+    finish0 = ref[0][1]
+    assert ref[2][0] == finish0 + 1
 
 
 # ----------------------------------------------------------- sync accounting
 def test_syncs_per_tick_bound(setup):
-    """Steady-state async ticks cost exactly ONE blocking sync (the
-    reconcile) while keeping one decode dispatch per group; the eager
-    oracle pays more whenever it admits."""
+    """Steady-state ticks cost exactly ONE blocking sync (the reconcile)
+    while keeping one decode dispatch per group; no tick, admissions
+    included, pays more than one."""
     c, m, params = setup
 
     def factory(rid):
         return ReplicaEngine(m, params, max_batch=2, max_seq=MAX_SEQ,
                              rid=rid)
 
-    fe = ElasticClusterFrontend(factory, 1, initial_replicas=2, seed=0,
-                                async_tick=True)
+    fe = ElasticClusterFrontend(factory, 1, initial_replicas=2, seed=0)
     for r in _make_reqs(4, n_new=10):
         fe.submit(r)
     ticks = []
@@ -248,18 +256,18 @@ def test_syncs_per_tick_bound(setup):
 # -------------------------------------------------------------- decode block
 def test_decode_block_exact_vs_single_steps(setup):
     """decode_block=4 (one fused dispatch + one (K,F,B) sync per 4 ticks)
-    must be bit-exact vs single-step async AND the eager oracle, with
-    strictly fewer syncs and dispatches."""
+    must be bit-exact vs single-step fleet ticks AND the per-replica loop,
+    with strictly fewer syncs and dispatches than single steps, which pay
+    at most one sync per tick."""
     c, m, params = setup
 
     def factory(rid):
         return ReplicaEngine(m, params, max_batch=2, max_seq=MAX_SEQ,
                              rid=rid)
 
-    def run(async_tick, block=1):
+    def run(fleet, block=1):
         fe = ElasticClusterFrontend(factory, 1, initial_replicas=2, seed=0,
-                                    async_tick=async_tick,
-                                    decode_block=block)
+                                    fleet_batch=fleet, decode_block=block)
         reqs = _make_reqs(4, n_new=12, seed=5)   # fills 2x2 slots, no queue
         for r in reqs:
             fe.submit(r)
@@ -272,11 +280,11 @@ def test_decode_block_exact_vs_single_steps(setup):
                 break
         return _snap(reqs), fe, ticks
 
-    s_eager, fe_e, _ = run(False)
-    s_async, fe_a, _ = run(True)
+    s_ref, _, _ = run(False)
+    s_async, fe_a, ticks_a = run(True)
     s_block, fe_b, ticks_b = run(True, block=4)
-    assert s_eager == s_async == s_block
-    assert fe_b.sync_count() < fe_a.sync_count() < fe_e.sync_count()
+    assert s_ref == s_async == s_block
+    assert fe_b.sync_count() < fe_a.sync_count() <= ticks_a
     assert fe_b.decode_dispatches() < fe_a.decode_dispatches()
     # block mode averages under one sync AND one dispatch per tick
     assert fe_b.sync_count() / ticks_b < 1.0
@@ -298,7 +306,7 @@ def test_decode_block_admission_lag_bounded(setup):
     def run(block):
         fe = ElasticClusterFrontend(factory, 1, initial_replicas=1, seed=0,
                                     max_replicas_per_node=1,
-                                    async_tick=True, decode_block=block)
+                                    decode_block=block)
         reqs = _make_reqs(6, n_new=6, seed=7)    # 2 slots, 4 queued behind
         for r in reqs:
             fe.submit(r)
@@ -316,7 +324,7 @@ def test_decode_block_admission_lag_bounded(setup):
 
 
 def test_decode_block_vetoed_by_single_admits():
-    """Eager single admits (moe exact-length path) bypass ``pending``; the
+    """Exact-length single admits (the moe path) bypass ``pending``; the
     ``_admitted`` flag must still veto fused-block engagement on the tick
     that admitted, keeping streams identical to decode_block=1 for a
     workload whose every admission tick also decodes."""
@@ -330,7 +338,7 @@ def test_decode_block_vetoed_by_single_admits():
 
     def run(block):
         fe = ElasticClusterFrontend(factory, 1, initial_replicas=2, seed=0,
-                                    async_tick=True, decode_block=block)
+                                    decode_block=block)
         reqs = _make_reqs(4, n_new=10, seed=17)   # fills 2x2, singles-only
         for r in reqs:
             fe.submit(r)
@@ -348,17 +356,17 @@ def test_decode_block_vetoed_by_single_admits():
 # ------------------------------------------------------------ chunked + tiers
 def test_chunked_prefill_async_parity(setup):
     """Chunked admission (cursor advance at dispatch, final-chunk commit at
-    reconcile) keeps chunk-by-chunk == single-shot parity in async mode."""
+    reconcile) keeps the fleet tick in parity with the per-replica loop."""
     c, m, params = setup
 
     def factory(rid):
         return ReplicaEngine(m, params, max_batch=2, max_seq=MAX_SEQ,
                              rid=rid, chunk_len=8)
 
-    def run(async_tick):
+    def run(fleet):
         rng = np.random.default_rng(2)
         fe = ElasticClusterFrontend(factory, 1, initial_replicas=2, seed=0,
-                                    async_tick=async_tick)
+                                    fleet_batch=fleet)
         reqs = [Request(i, rng.integers(1, 400, ln).tolist(),
                         max_new_tokens=4)
                 for i, ln in enumerate([30, 5, 45, 6, 20, 7])]
@@ -371,8 +379,9 @@ def test_chunked_prefill_async_parity(setup):
 
 
 def test_tiered_async_parity(setup):
-    """Weighted-deficit tiered admission reorders identically under async
-    ticks (queue work is host-state, never deferred)."""
+    """Weighted-deficit tiered admission reorders identically under the
+    fleet tick and the per-replica loop (queue work is host-state, never
+    deferred)."""
     from repro.workload import TierSet, TierSpec
 
     c, m, params = setup
@@ -385,9 +394,9 @@ def test_tiered_async_parity(setup):
         return ReplicaEngine(m, params, max_batch=2, max_seq=MAX_SEQ,
                              rid=rid, tiers=tiers)
 
-    def run(async_tick):
+    def run(fleet):
         fe = ElasticClusterFrontend(factory, 1, initial_replicas=2, seed=0,
-                                    async_tick=async_tick, tiers=tiers)
+                                    fleet_batch=fleet, tiers=tiers)
         reqs = _make_reqs(9, n_new=4, seed=13)
         for i, r in enumerate(reqs):
             r.tier = tiers.names[i % 3]

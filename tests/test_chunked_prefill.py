@@ -127,18 +127,18 @@ def test_chunked_ttft_spreads_over_ticks(setup):
 # ------------------------------------------------- fleet-batched admission
 def test_fleet_prefill_parity_and_dispatch_bound(setup):
     """4 same-model replicas across 2 nodes: same-bucket admits collapse to
-    one vmapped prefill dispatch per distinct (kb, sb) shape — never one per
-    replica — with streams and finish ticks identical to per-replica
-    admission."""
+    one fleet prefill dispatch per distinct (kb, sb) shape — never one per
+    replica — with streams and finish ticks identical to the per-replica
+    loop."""
     c, m, params = setup
 
     def factory(rid):
         return ReplicaEngine(m, params, max_batch=2, max_seq=MAX_SEQ,
                              rid=rid)
 
-    def run(fp):
+    def run(fleet):
         fe = ElasticClusterFrontend(factory, 2, initial_replicas=2, seed=0,
-                                    fleet_prefill=fp)
+                                    fleet_batch=fleet)
         # equal lengths -> one (kb, sb) shape once every replica admits a
         # full pair
         reqs = _reqs([6] * 8, n_new=4, seed=2)
@@ -152,7 +152,7 @@ def test_fleet_prefill_parity_and_dispatch_bound(setup):
     s_off, m_off, fe_off = run(False)
     assert s_on == s_off
     # admission tick: <= 2 distinct shapes (kb in {1,2} x one sb bucket);
-    # the per-replica oracle pays one dispatch per admitting replica
+    # the per-replica loop pays one dispatch per admitting replica
     assert 1 <= m_on["prefill_dispatches"] <= 2
     assert m_off["prefill_dispatches"] == 4
     assert fe_on.prefill_dispatches() < fe_off.prefill_dispatches()
@@ -226,7 +226,7 @@ def test_moe_defaults_to_exact_length_admission():
 
 # ----------------------------------------------------- trace accounting
 def test_trace_accounting_counts_fleet_and_chunk_variants(setup):
-    """total_prefill_traces must include the fleet_prefill / chunk kernel
+    """total_prefill_traces must include the fleet prefill / chunk kernel
     compilations (deduped via the shared kernel object), and the full serve
     accounting also covers the decode variants."""
     c, m, params = setup
@@ -242,15 +242,15 @@ def test_trace_accounting_counts_fleet_and_chunk_variants(setup):
     engines = fe.replicas
     counts = engines[0]._kernels.trace_counts
 
-    def n(*variants):        # async mode compiles the afleet_* twins
+    def n(*variants):
         return sum(counts.get(v, 0) for v in variants)
 
-    assert n("fleet_prefill", "afleet_prefill") >= 1
-    assert n("fleet_chunk", "afleet_chunk") >= 1
+    assert n("afleet_prefill") >= 1
+    assert n("afleet_chunk") >= 1
     assert fe.prefill_retraces() == total_prefill_traces(engines)
     assert total_prefill_traces(engines) >= \
-        n("fleet_prefill", "afleet_prefill", "fleet_chunk", "afleet_chunk")
+        n("afleet_prefill", "afleet_chunk")
     # the all-variant accounting additionally covers decode kernels
     assert total_serve_traces(engines) >= \
-        total_prefill_traces(engines) + n("fleet", "afleet")
+        total_prefill_traces(engines) + n("afleet")
     assert fe.serve_kernel_traces() == total_serve_traces(engines)

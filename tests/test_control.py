@@ -249,17 +249,17 @@ def _run_elastic(m, params, cfg, arrivals, scaler, tiers=None):
             req.tier = tiers.names[rid % len(tiers)]
         return req
 
-    # eager (synchronous) ticks: the fluid sim observes its own tick
-    # synchronously, so the apples-to-apples ranking comparison runs the
-    # engine's eager oracle too — the async tick intentionally delays
-    # metric observation by one tick, which shifts WHICH node the scaler
-    # grows first (legit controller divergence, not a serving difference)
+    # synchronous ticks: the fluid sim observes its own tick synchronously,
+    # so the apples-to-apples ranking comparison runs the engine's
+    # per-replica loop too — the fleet tick intentionally delays metric
+    # observation by one tick, which shifts WHICH node the scaler grows
+    # first (legit controller divergence, not a serving difference)
     fe = ElasticClusterFrontend(
         _factory(m, params, max_batch=2), cfg.num_nodes, initial_replicas=1,
         provisioning_delay=cfg.provisioning_delay,
         max_replicas_per_node=cfg.max_replicas_per_node,
         request_factory=request_factory, seed=0, est_tokens=N_NEW,
-        async_tick=False, tiers=tiers)
+        fleet_batch=False, tiers=tiers)
     plane = ControlPlane(cfg, fe, balancer="rr", scaler=scaler,
                          unit_capacity=2.0 / N_NEW, seed=0,
                          init_arrival=float(arrivals[:5].mean()))
@@ -323,11 +323,12 @@ def test_method_ranking_matches_across_backends_3tier(setup):
 
 
 def test_async_observation_shifts_decisions_at_most_one_tick(setup):
-    """Stale-observation contract: the async tick's metrics describe the
+    """Stale-observation contract: the fleet tick's metrics describe the
     device state one tick earlier, so on a fixed trace every rule-based
-    ``scale_to`` decision of the async backend must appear among the eager
-    oracle's decisions within one plan interval (rbas plans every tick,
-    window t-1..t+1) — staleness may DELAY a decision, never diverge it.
+    ``scale_to`` decision of the fleet backend must appear among the
+    per-replica loop's (synchronous) decisions within one plan interval
+    (rbas plans every tick, window t-1..t+1) — staleness may DELAY a
+    decision, never diverge it.
     Pinned on a single-node backend: with several nodes the lag legally
     shifts WHICH node grows first (see ``_run_elastic``), which compounds
     into different per-node trajectories; the total-capacity decision is
@@ -339,7 +340,7 @@ def test_async_observation_shifts_decisions_at_most_one_tick(setup):
         max_replicas_per_node=4, min_replicas_per_node=1, scale_interval=3,
         cooldown=6, straggler_prob=0.0, node_mtbf=1e12)
 
-    def decisions(async_tick):
+    def decisions(fleet):
         def request_factory(rid, tick):
             return Request(rid, [1 + rid % 50, 2, 3, 4],
                            max_new_tokens=N_NEW)
@@ -349,7 +350,7 @@ def test_async_observation_shifts_decisions_at_most_one_tick(setup):
             provisioning_delay=cfg.provisioning_delay,
             max_replicas_per_node=cfg.max_replicas_per_node,
             request_factory=request_factory, seed=0, est_tokens=N_NEW,
-            async_tick=async_tick)
+            fleet_batch=fleet)
         plane = ControlPlane(cfg, fe, balancer="rr", scaler="rbas",
                              unit_capacity=2.0 / N_NEW, seed=0,
                              init_arrival=float(arrivals[:5].mean()))
@@ -365,13 +366,13 @@ def test_async_observation_shifts_decisions_at_most_one_tick(setup):
             plane.step(float(a))
         return out
 
-    eager = decisions(False)
+    sync = decisions(False)
     lagged = decisions(True)
-    assert len(eager) == len(lagged) == len(arrivals)
+    assert len(sync) == len(lagged) == len(arrivals)
     for t, d in enumerate(lagged):
-        lo, hi = max(t - 1, 0), min(t + 1, len(eager) - 1)
-        assert d in eager[lo:hi + 1], (t, d, eager[lo:hi + 1])
-    assert eager[-1] == lagged[-1]       # same steady-state capacity
+        lo, hi = max(t - 1, 0), min(t + 1, len(sync) - 1)
+        assert d in sync[lo:hi + 1], (t, d, sync[lo:hi + 1])
+    assert sync[-1] == lagged[-1]        # same steady-state capacity
 
 
 def test_ours_stack_runs_on_elastic_backend(setup):

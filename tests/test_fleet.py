@@ -8,6 +8,8 @@ Acceptance coverage for the fleet serving path:
     mid-run scale-up, a graceful drain, and a failure evacuation;
   * one jitted decode dispatch per fleet group per tick (4 same-model
     replicas spanning 2 nodes form ONE group);
+  * the frontend refuses ``async_tick=False`` (the fleet tick has one mode;
+    the per-replica loop is ``fleet_batch=False``);
   * slab membership churn (join mid-generation, unstack on leave);
   * the ``_admit_batch`` overflow fix (over-long prompts truncate instead of
     crashing the token-buffer copy);
@@ -22,8 +24,8 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import make_model
-from repro.serving import (ClusterFrontend, ElasticClusterFrontend,
-                           FleetGroup, ReplicaEngine, Request)
+from repro.serving import (ElasticClusterFrontend, FleetGroup,
+                           ReplicaEngine, Request)
 
 MAX_SEQ = 64
 
@@ -84,9 +86,9 @@ def test_fleet_matches_per_replica_across_churn(arch):
 
 def test_one_dispatch_per_group_per_tick(setup):
     """4 same-model replicas across 2 nodes = ONE fleet group = ONE jitted
-    decode dispatch per tick — and, under the async tick (default), at most
-    ONE blocking host sync per tick (the reconcile of the previous tick's
-    futures), even on ticks that also admit."""
+    decode dispatch per tick — and at most ONE blocking host sync per tick
+    (the reconcile of the previous tick's futures), even on ticks that also
+    admit."""
     c, m, params = setup
 
     def factory(rid):
@@ -103,16 +105,21 @@ def test_one_dispatch_per_group_per_tick(setup):
         assert mtr["decode_dispatches"] == 1
         assert mtr["syncs"] == 1         # exactly the one reconcile
     assert len(fe.replicas) == 4
-    # the eager oracle pays >= 1 sync per decode round PLUS admission
-    # syncs: its total must exceed the async run's for the same workload
-    fe_e = ElasticClusterFrontend(factory, 2, initial_replicas=2, seed=0,
-                                  async_tick=False)
-    for r in _make_reqs(16, n_new=8):
-        fe_e.submit(r)
-    for _ in range(4):
-        mtr_e = fe_e.tick(0.0)
-        assert mtr_e["syncs"] >= 1
-    assert fe_e.sync_count() > fe.sync_count()
+
+
+def test_elastic_refuses_the_eager_tick(setup):
+    """The fleet tick has one mode: ``async_tick=False`` is refused with a
+    pointer to the per-replica loop instead of silently changing the
+    tick."""
+    c, m, params = setup
+
+    def factory(rid):
+        return ReplicaEngine(m, params, max_batch=2, max_seq=MAX_SEQ, rid=rid)
+
+    with pytest.raises(ValueError, match="fleet_batch=False"):
+        ElasticClusterFrontend(factory, 1, async_tick=False)
+    fe = ElasticClusterFrontend(factory, 1, async_tick=True)
+    assert len(fe._fleets) == 1
 
 
 def test_fleet_join_and_leave_mid_generation(setup):
@@ -140,6 +147,7 @@ def test_fleet_join_and_leave_mid_generation(setup):
         oracle.step()
         eng.begin_step()
         g.decode_round()
+        g.reconcile()
     g.remove(eng)                            # unstack and finish standalone
     assert eng.cache is not None and eng._fleet is None
     for _ in range(30):
@@ -440,23 +448,6 @@ def test_hetero_speed_masked_rounds_parity(setup):
             fe.submit(r)
         for _ in range(4):
             fe.tick(0.0)
-        fe.run_until_drained()
-        return _snap(reqs)
-
-    assert run(True) == run(False)
-
-
-def test_cluster_frontend_fleet_batch_parity(setup):
-    """The static ClusterFrontend supports fleet batching too."""
-    c, m, params = setup
-
-    def run(fleet):
-        engines = [ReplicaEngine(m, params, max_batch=2, max_seq=MAX_SEQ,
-                                 rid=i) for i in range(2)]
-        fe = ClusterFrontend(engines, policy="rr", fleet_batch=fleet)
-        reqs = _make_reqs(6, n_new=4)
-        for r in reqs:
-            fe.submit(r)
         fe.run_until_drained()
         return _snap(reqs)
 

@@ -43,8 +43,8 @@ import numpy as np
 from repro.configs import get_config
 from repro.launch.mesh import make_fleet_mesh
 from repro.models import make_model
-from repro.serving import (ClusterFrontend, ElasticClusterFrontend,
-                           FleetGroup, ReplicaEngine, Request)
+from repro.serving import (ElasticClusterFrontend, FleetGroup,
+                           ReplicaEngine, Request)
 from repro.workload.trace import DEFAULT_TIERS
 
 MAX_SEQ = 64
@@ -175,16 +175,13 @@ out["growth_divisible"] = all(c % 4 == 0 for _, c in caps)
 # a 3-member fleet (1 pad row on the 4-wide slab) must match the
 # unsharded 3-member fleet stream-for-stream and dispatch-for-dispatch
 def run3(use_mesh):
-    engines = [ReplicaEngine(m, params, max_batch=2, max_seq=MAX_SEQ, rid=i)
-               for i in range(3)]
-    fe = ClusterFrontend(engines, policy="rr", fleet_batch=True,
-                         mesh=mesh if use_mesh else None)
+    fe = ElasticClusterFrontend(factory, 1, initial_replicas=3, seed=0,
+                                mesh=mesh if use_mesh else None)
     reqs = make_reqs(9, n_new=5, seed=11)
     for r in reqs:
         fe.submit(r)
     fe.run_until_drained()
-    disp = sum(gr.dispatches for gr in fe.fleets.values())
-    return snap(reqs), disp
+    return snap(reqs), fe.decode_dispatches()
 
 (s0, d0), (s1, d1) = run3(False), run3(True)
 out["growth_parity"] = s0 == s1 and d0 == d1
