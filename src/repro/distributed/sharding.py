@@ -89,6 +89,9 @@ def _trailing_rules(plan: ShardPlan, path_names: tuple) -> Optional[tuple]:
         "wq": (fsdp, tp, None),         # (d, nq, hd)
         "wk": (fsdp, tp, None),
         "wv": (fsdp, tp, None),
+        "wq_served": (tp, None, None, fsdp),  # (G, qpg, hd, d)
+        "wk_served": (tp, None, fsdp),  # (G, hd, d)
+        "wv_served": (tp, None, fsdp),
         "wo": (tp, None, fsdp),         # (nq, hd, d)
         "bq": (tp, None),
         "bk": (tp, None),

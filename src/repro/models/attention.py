@@ -43,10 +43,60 @@ def init_attention(key, d_model: int, dims: PaddedDims, head_dim: int,
     return p
 
 
+# The served layout of the q/k/v projections: heads, head_dim, d_model,
+# the order the TPU's matmul reads, with ``wq``'s heads split into its kv
+# groups as the grouped q is, (G, qpg, head_dim, d_model). In the
+# published (d_model, heads, head_dim) order a layer scan materialises
+# each layer's slice and relays it d_model-minor before every projection,
+# in every decode and prefill call; with ``wq``'s heads as one axis it
+# still materialises the ``wq`` slice to regroup them. In this order it
+# reads every slice in place.
+SERVED_QKV = {"wq": "wq_served", "wk": "wk_served", "wv": "wv_served"}
+
+
+def serve_layout(params):
+    """``params`` with every attention's ``wq``/``wk``/``wv``, kept in the
+    published (d_model, heads, head_dim) order (after any stacked layer
+    axes) as training, checkpoints and ``init`` make them, replaced by
+    ``wq_served`` (G, qpg, head_dim, d_model), G the kv heads of ``wk``,
+    and ``wk_served``/``wv_served`` (G, head_dim, d_model). Every other
+    leaf is the same object as in ``params``, so a tree already in the
+    served layout keeps every leaf. The serving engine calls this once per
+    weights object (``serving.engine.served_weights``)."""
+    if not isinstance(params, dict):
+        return params
+    if all(n in params for n in SERVED_QKV):
+        out = {k: v for k, v in params.items() if k not in SERVED_QKV}
+        for n, served in SERVED_QKV.items():
+            out[served] = jnp.moveaxis(params[n], -3, -1)
+        wq, g = out["wq_served"], params["wk"].shape[-2]
+        out["wq_served"] = wq.reshape(wq.shape[:-3] + (g, -1)
+                                      + wq.shape[-2:])
+        return out
+    return {k: serve_layout(v) for k, v in params.items()}
+
+
+def project(params, name: str, x):
+    """x (B,S,d) through projection ``name`` (``wq``, ``wk`` or ``wv``) to
+    (B,S,heads,head_dim): from its served leaf when ``params`` holds one
+    (``serve_layout``), else from the published (d_model, heads, head_dim)
+    leaf."""
+    w = params.get(SERVED_QKV[name])
+    if w is None:
+        return jnp.einsum("bsd,dnh->bsnh", x, params[name])
+    # wq gives (B,S,G,qpg,hd); ``_project_qkv`` regroups what this merges,
+    # and the two reshapes fold away, leaving the weight's slice in place
+    y = jnp.einsum("bsd,...hd->bs...h", x, w)
+    return y.reshape(y.shape[:2] + (-1, y.shape[-1]))
+
+
 def _project_qkv(params, x, kv_x, dims: PaddedDims):
-    q = jnp.einsum("bsd,dnh->bsnh", x, params["wq"])
-    k = jnp.einsum("bsd,dgh->bsgh", kv_x, params["wk"])
-    v = jnp.einsum("bsd,dgh->bsgh", kv_x, params["wv"])
+    """q grouped (B,S,G,qpg,hd), k and v (B,S,G,hd). The weights may be in
+    either order: the served leaves are taken when present (see
+    ``serve_layout``), detected from the tree's keys."""
+    q = project(params, "wq", x)
+    k = project(params, "wk", kv_x)
+    v = project(params, "wv", kv_x)
     if "bq" in params:
         q = q + params["bq"]
         k = k + params["bk"]
@@ -199,7 +249,10 @@ def chunk_prefill_attention(params, x, dims: PaddedDims, cache, positions,
 
 
 def project_decode_qkv(params, x, dims: PaddedDims, pos, rope_theta):
-    """Project the new token's q/k/v with RoPE at `pos` (scalar or (B,))."""
+    """Project the new token's q/k/v with RoPE at `pos` (scalar or (B,)).
+    The serving engine hands the served q/k/v leaves (heads, head_dim,
+    d_model; ``serve_layout``), converted once when it takes its weights;
+    the published order works too (``_project_qkv``)."""
     pos = jnp.asarray(pos, jnp.int32)
     per_seq = pos.ndim == 1
     q, k_new, v_new = _project_qkv(params, x, x, dims)

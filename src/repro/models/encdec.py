@@ -156,10 +156,8 @@ def encdec_prefill(params, batch, cfg, dims, *, cache_len: int,
                                                       idx, 0)
         h = h + y
         x = _ln(h, lp["cross_norm"], cfg.norm_eps)
-        ck = jnp.einsum("btd,dgh->btgh", enc_out, lp["cross"]["wk"]) \
-            + lp["cross"]["bk"]
-        cv = jnp.einsum("btd,dgh->btgh", enc_out, lp["cross"]["wv"]) \
-            + lp["cross"]["bv"]
+        ck = attn.project(lp["cross"], "wk", enc_out) + lp["cross"]["bk"]
+        cv = attn.project(lp["cross"], "wv", enc_out) + lp["cross"]["bv"]
         h = h + attn.attention(lp["cross"], x, dims, rope_theta=0.0,
                                causal=False, kv_x=enc_out)
         x = _ln(h, lp["ffn_norm"], cfg.norm_eps)
@@ -177,7 +175,7 @@ def encdec_prefill(params, batch, cfg, dims, *, cache_len: int,
 
 def _cross_decode(lp, x, dims, ck, cv):
     """Cross-attn for one query token against cached encoder k/v."""
-    q = jnp.einsum("bsd,dnh->bsnh", x, lp["cross"]["wq"]) + lp["cross"]["bq"]
+    q = attn.project(lp["cross"], "wq", x) + lp["cross"]["bq"]
     B = x.shape[0]
     q = q.reshape(B, 1, dims.n_kv, dims.q_per_group, -1)
     scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))

@@ -157,6 +157,7 @@ import dataclasses
 import functools
 import itertools
 import time
+import weakref
 from collections import deque
 from typing import Optional
 
@@ -167,6 +168,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import fleet_slab_shardings
+from repro.models.attention import serve_layout
 from repro.models.model import Model
 from repro.obs import timed_get
 from repro.workload.trace import DEFAULT_TIERS, TierSet
@@ -258,6 +260,49 @@ def _init_ops(cap: int, batch: int) -> dict:
         "eos": jnp.full((cap, batch), -1, jnp.int32),
         "active": jnp.zeros((cap, batch), bool),
     }
+
+
+def _published_wq(params):
+    for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+        if getattr(path[-1], "key", None) == "wq":
+            return leaf
+    return None
+
+
+def served_weights(model: Model, params):
+    """The tree the engine serves ``params`` from: every attention's q/k/v
+    in (heads, head_dim, d_model) order, ``wq``'s heads split by kv group
+    (``attention.serve_layout``), the layout the TPU's matmul reads, so no
+    decode or prefill call slices or copies them layer by layer. Weights arrive from training, checkpoints and
+    ``Model.init`` in the published (d_model, heads, head_dim) order; this
+    converts them once per weights object, not per call or per replica: a
+    replica built later from the same object, at scale-up, gets the same
+    served tree, so ``fleet_key`` still puts it in the one fleet group. The
+    served tree holds no published q/k/v leaf; every other leaf is shared
+    with ``params``. A tree with nothing to convert comes back as is."""
+    wq = _published_wq(params)
+    if wq is None:
+        return params
+    # id(params) -> (weak reference to its first published ``wq``, served
+    # tree), kept on the Model like its serve kernels. The entry goes when
+    # that ``wq`` does, so a later tree given the same id is never taken
+    # for it.
+    served = getattr(model, "_served_weights", None)
+    if served is None:
+        served = {}
+        object.__setattr__(model, "_served_weights", served)  # frozen
+    key = id(params)
+    hit = served.get(key)
+    if hit is not None and hit[0]() is wq:
+        return hit[1]
+
+    def forget(ref):
+        if served.get(key, (None,))[0] is ref:
+            del served[key]
+
+    tree = serve_layout(params)
+    served[key] = (weakref.ref(wq, forget), tree)
+    return tree
 
 
 @dataclasses.dataclass
@@ -667,6 +712,12 @@ class FleetGroup:
     ``afleet_chunk`` — each writing KV/state straight into the donated slab.
     ``prefill_dispatches`` mirrors ``dispatches``.
 
+    ``params`` is the served tree (``served_weights``): q/k/v in (heads,
+    head_dim, d_model) order, ``wq``'s heads split by kv group, converted
+    once per weights object where a replica or a group first takes them,
+    so every dispatch reads each layer's projection weights in place. The members' ``params`` are that
+    same tree, which is what makes their ``fleet_key`` equal.
+
     The dispatch methods never block: device results queue on ``pending``
     and the deferred host bookkeeping applies at the next ``reconcile()`` —
     one blocking sync per tick (``syncs``), with the decode operands
@@ -707,6 +758,7 @@ class FleetGroup:
                 f"FleetGroup mesh needs a 'fleet' axis, got "
                 f"{mesh.axis_names}")
         self.shards = int(mesh.shape["fleet"]) if mesh is not None else 1
+        params = served_weights(model, params)
         if mesh is not None:
             # replicate the weights across the fleet axis once: every shard
             # decodes its own slab rows against the full params (serve-mode
@@ -1260,7 +1312,7 @@ class ReplicaEngine:
                 f"family={model.cfg.family!r} decodes through ssm/encdec "
                 "state")
         self.model = model
-        self.params = params
+        self.params = served_weights(model, params)
         self.max_batch = max_batch
         self.max_seq = max_seq
         self.cache_dtype = cache_dtype
